@@ -11,54 +11,6 @@ import json
 
 from repro.models.config import SHAPES_BY_NAME
 
-# per-chip constants (TPU v5e) — keep in sync with launch/dryrun.py
-PEAK_FLOPS = 197e12
-N_CHIPS = {"single": 256, "multi": 512}
-
-# ---------------------------------------------------------------------------
-# kernel-level peak model — used by the schedule autotuner (repro.tune) to
-# report achieved vs peak FLOPs/bytes per candidate schedule.
-# ---------------------------------------------------------------------------
-
-# (peak FLOP/s, peak HBM/DRAM bytes/s) per normalized device kind.  The CPU
-# row is a deliberately conservative host estimate: interpret-mode numbers
-# are only meaningful relative to each other, not against silicon peaks.
-DEVICE_PEAKS = {
-    "tpu-v5e": {"flops": PEAK_FLOPS, "bytes": 819e9},
-    "cpu": {"flops": 5e10, "bytes": 2e10},
-}
-
-
-def device_peaks(kind: str | None = None) -> dict:
-    """Peak {flops, bytes}/s for a device kind (default: current backend).
-    Unknown TPU generations fall back to the v5e row, anything else to the
-    CPU row — the autotuner only needs a consistent yardstick."""
-    if kind is None:
-        from repro.tune.cache import device_kind
-        kind = device_kind()
-    if kind in DEVICE_PEAKS:
-        return DEVICE_PEAKS[kind]
-    return DEVICE_PEAKS["tpu-v5e" if kind.startswith("tpu") else "cpu"]
-
-
-def kernel_roofline(flops: float, bytes_moved: float, wall_s: float,
-                    kind: str | None = None) -> dict:
-    """Achieved vs peak for one timed kernel call.  Returns ``gflops`` /
-    ``gbs`` (achieved rates), ``frac_peak_flops`` / ``frac_peak_bytes``
-    (fraction of the device roofline), and the ``dominant`` bottleneck
-    (whichever peak-time term is larger)."""
-    peaks = device_peaks(kind)
-    wall_s = max(float(wall_s), 1e-12)
-    t_comp = flops / peaks["flops"]
-    t_mem = bytes_moved / peaks["bytes"]
-    return {
-        "gflops": round(flops / wall_s / 1e9, 2),
-        "gbs": round(bytes_moved / wall_s / 1e9, 2),
-        "frac_peak_flops": round(flops / wall_s / peaks["flops"], 4),
-        "frac_peak_bytes": round(bytes_moved / wall_s / peaks["bytes"], 4),
-        "dominant": "compute" if t_comp >= t_mem else "memory",
-    }
-
 
 def model_flops(rec: dict) -> float:
     """6*N*D (dense) or 6*N_active*D (MoE) for train; 2*N*D for one

@@ -67,6 +67,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.cluster import SpectralClustering, ari
 from repro.core import kmeans as km
 from repro.core import lanczos as lz
@@ -533,88 +534,30 @@ def fused_sweep(ns=(1024, 2048, 8192), k: int = 8,
 
 def _async_problem(n: int, k: int):
     """The async_sweep problem: n blob points + the spill-forcing plan
-    kwargs shared by every run (including the pr7 subprocess)."""
+    kwargs shared by every run."""
     pts, _ = synthetic.blobs(n, k, dim=4, spread=0.6, seed=0)
     return pts.astype(np.float32), dict(
         n=n, chunk_size=512, t=16, k=k, sigma=1.0, memory_budget=1 << 19,
         lanczos_steps=96, seed=0, path="ooc")
 
 
-def _pr7_child(out_path: str, n: int = 4096, k: int = 3) -> None:
-    """Subprocess body for the async_sweep baseline: the PR-7 pipeline,
-    stage by stage — sequential build (workers=1), synchronous spills,
-    per-column bincount scatter, no prewarm, and the eigensolve traced
-    through the ``pure_callback`` matmat — exactly how the engine shipped
-    before the async rework (the host-stepped driver and the single-pass
-    scatter are both PR 8 optimizations, so the baseline must not borrow
-    them).  Runs the pipeline twice (cold compiles, warm is the reported
-    wall) and writes labels + both walls to ``out_path``.
-
-    This runs in its OWN process because the callback eigensolve is the
-    deadlock PR 8 fixed: on single-thread CPU runtimes it terminates only
-    some of the time (the parent retries on timeout), and a hang must not
-    take the whole sweep down with it."""
-    from repro import engine
-    from repro.data.chunked import ArrayChunks
-    from repro.engine import kmeans as skm
-
-    pts, common = _async_problem(n, k)
-    plan = engine.JobPlan(**common, workers=1, prefetch_depth=1,
-                          async_spill=False)
-
-    def pipeline():
-        t0 = time.perf_counter()
-        graph, _sigma = engine.build_graph(ArrayChunks(pts, 512), plan,
-                                           prewarm=False)
-        graph.matmat_impl = "loop"
-        op = engine.make_normalized_operator(graph)
-        key = jax.random.PRNGKey(plan.seed)
-        _, k_lan, _k_km = jax.random.split(key, 3)
-        state = lz.block_lanczos(op.matmat, plan.n, plan.num_block_steps(),
-                                 k_lan, block_size=plan.eff_block_size())
-        evals, Z = lz.block_topk_of_shifted(state, plan.k)
-        jax.block_until_ready(Z)
-        Y = np.asarray(km.normalize_rows(Z))
-        ranges = plan.ranges
-        labels, _centers = skm.streaming_kmeans(
-            lambda c: Y[ranges[c][0]:ranges[c][1]], plan.nchunks, plan.k,
-            rounds=plan.kmeans_rounds, seed=plan.seed)
-        wall = time.perf_counter() - t0
-        graph.close()
-        return labels, wall
-
-    _labels, cold = pipeline()
-    labels, warm = pipeline()
-    np.savez(out_path, labels=labels, cold_wall=cold, warm_wall=warm)
-
-
 def async_sweep(n: int = 4096, k: int = 3,
                 out_json: str = "BENCH_async.json"):
-    """The fully-async engine against its own sequential ancestor.
+    """The fully-async engine against the same engine at width 1.
 
     One problem (n=4096 blobs, spill-forcing 512 KiB shard-store budget),
-    three runs of the identical math:
+    two runs of the identical math in this process:
 
-      pr7        the pre-async engine exactly as it shipped (see
-                 :func:`_pr7_child`), measured WARM in a fresh subprocess
-                 with timeout+retry — its callback eigensolve is the
-                 self-deadlock PR 8 fixed, so it cannot be trusted inside
-                 the sweep process (or to terminate at all)
       seq        the async engine at width 1 (workers=1, depth=1, sync
                  spills) — the bitwise-parity reference
       async      workers=4, prefetch_depth=4, async spills, single-pass
                  scatter, warm-started eigensolve
 
-    Acceptance (asserted): async wall <= 0.75x the pr7 wall; prefetch
-    hit rate > 0.90; async labels BITWISE-identical to seq labels; ooc
-    ARI vs the dense eigh oracle == 1.0; and the streaming ooc matmat
-    stays within 2x of the fused in-memory matmat at equal n.
+    Acceptance (asserted): prefetch hit rate > 0.90; async labels
+    BITWISE-identical to seq labels; ooc ARI vs the dense eigh oracle ==
+    1.0; and the streaming ooc matmat stays within 2x of the fused
+    in-memory matmat at equal n.
     """
-    import os
-    import subprocess
-    import sys
-    import tempfile
-
     from repro import engine
     from repro.cluster import ari
     from repro.cluster.affinity import AFFINITIES
@@ -637,31 +580,8 @@ def async_sweep(n: int = 4096, k: int = 3,
     seq_s = time.perf_counter() - t0
     row("async_sweep/seq_w1", seq_s * 1e6, "async engine at width 1")
 
-    # PR-7 baseline in a fresh subprocess (see _pr7_child): retry on
-    # deadlock-timeout, record how many attempts the callback path needed
-    pr7_out = os.path.join(tempfile.mkdtemp(prefix="repro-bench-"),
-                           "pr7.npz")
-    attempts = 0
-    while True:
-        attempts += 1
-        try:
-            subprocess.run([sys.executable, os.path.abspath(__file__),
-                            "_pr7_child", pr7_out], timeout=120, check=True)
-            break
-        except subprocess.TimeoutExpired:
-            if attempts >= 8:
-                raise RuntimeError(
-                    "PR-7 callback baseline deadlocked in all 8 attempts")
-    with np.load(pr7_out) as z:
-        labels_pr7 = np.asarray(z["labels"])
-        pr7_s = float(z["warm_wall"])
-        pr7_cold_s = float(z["cold_wall"])
-    row("async_sweep/pr7_baseline", pr7_s * 1e6,
-        f"sequential schedule + sync spills + loop scatter + callback "
-        f"eigensolve (fresh-process warm wall, attempts={attempts})")
-
-    # best of 2, mirroring the baseline's cold+warm structure (the seq_w1
-    # run above already compiled everything, so both runs here are warm)
+    # best of 2 (the seq_w1 run above already compiled everything, so
+    # both runs here are warm)
     runs = []
     for _ in range(2):
         t0 = time.perf_counter()
@@ -671,17 +591,15 @@ def async_sweep(n: int = 4096, k: int = 3,
     st = res_async.stats
     hits, misses = st["prefetch_hits"], st["prefetch_misses"]
     hit_rate = hits / max(hits + misses, 1)
-    speedup = pr7_s / async_s
+    speedup = seq_s / async_s
     row("async_sweep/async_w4", async_s * 1e6,
-        f"speedup={speedup:.2f}x hit_rate={hit_rate:.3f} "
+        f"speedup_vs_w1={speedup:.2f}x hit_rate={hit_rate:.3f} "
         f"overlap_s={st['overlap_s']} build_wall_s={st['build_wall_s']} "
         f"spills={st['store_spills']} spill_joins={st['store_spill_joins']}")
     assert st["store_bytes_spilled"] > 0, "budget was meant to force spills"
 
     bitwise = bool(np.array_equal(res_seq.labels, res_async.labels))
-    a_pr7 = float(ari(labels_pr7, res_async.labels))
-    row("async_sweep/scheduler_parity", 0.0,
-        f"bitwise_w1={bitwise} ari_vs_pr7={a_pr7:.3f}")
+    row("async_sweep/scheduler_parity", 0.0, f"bitwise_w1={bitwise}")
 
     # dense eigh oracle on the same points
     eigh_est = SpectralClustering(k=k, affinity="dense", eigensolver="eigh",
@@ -713,9 +631,8 @@ def async_sweep(n: int = 4096, k: int = 3,
     graph.close()
 
     results.update(
-        pr7_wall_s=round(pr7_s, 3), pr7_cold_wall_s=round(pr7_cold_s, 3),
-        pr7_subprocess_attempts=attempts, seq_wall_s=round(seq_s, 3),
-        async_wall_s=round(async_s, 3), speedup_vs_pr7=round(speedup, 3),
+        seq_wall_s=round(seq_s, 3), async_wall_s=round(async_s, 3),
+        speedup_vs_w1=round(speedup, 3),
         prefetch_hits=int(hits), prefetch_misses=int(misses),
         prefetch_hit_rate=round(hit_rate, 4),
         overlap_s=st["overlap_s"], build_wall_s=st["build_wall_s"],
@@ -723,15 +640,14 @@ def async_sweep(n: int = 4096, k: int = 3,
         store_spill_joins=int(st["store_spill_joins"]),
         bytes_spilled=int(st["store_bytes_spilled"]),
         labels_bitwise_identical_w1=bitwise,
-        ari_vs_pr7=a_pr7, ari_vs_dense_oracle=a_dense,
+        ari_vs_dense_oracle=a_dense,
         matmat_ooc_us=round(ooc_us, 1), matmat_fused_us=round(fused_us, 1),
         matmat_ooc_vs_fused=round(matmat_ratio, 3))
 
     row("async_sweep/acceptance", 0.0,
-        f"speedup={speedup:.2f}x (need >=1.33) hit_rate={hit_rate:.3f} "
+        f"speedup_vs_w1={speedup:.2f}x hit_rate={hit_rate:.3f} "
         f"(need >0.90) bitwise={bitwise} ari_dense={a_dense:.3f} "
         f"matmat_ratio={matmat_ratio:.2f}x (need <=2)")
-    assert async_s <= 0.75 * pr7_s, (async_s, pr7_s)
     assert hit_rate > 0.90, hit_rate
     assert bitwise, "workers=4 labels diverged from workers=1"
     assert a_dense == 1.0, a_dense
@@ -1176,11 +1092,6 @@ def main(argv=None) -> None:
     if argv is None:
         import sys
         argv = sys.argv[1:]
-    if argv and argv[0] == "_pr7_child":
-        # async_sweep subprocess entry point (see _pr7_child): the PR-7
-        # callback baseline must run in its own process
-        _pr7_child(argv[1])
-        return
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("modes", nargs="*", choices=[[], *MODES],
                     help="benchmark modes to run (default: full suite "
@@ -1189,6 +1100,7 @@ def main(argv=None) -> None:
                     help="tune_sweep only: small n + reduced candidate "
                          "grid (the CI autotune smoke configuration)")
     args = ap.parse_args(argv)
+    compile_cache.enable()
     print("name,us_per_call,derived")
     for mode in (args.modes or DEFAULT_MODES):
         if mode == "tune_sweep":

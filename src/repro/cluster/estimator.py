@@ -33,6 +33,7 @@ from repro.cluster.eigensolvers import EIGENSOLVERS
 from repro.cluster.operator import SpectralResult
 from repro.core import kmeans as km, laplacian as lp, similarity as sim
 from repro.distrib import mesh_utils
+from repro.precision import matmul
 
 # on-disk model layout version (est.save / SpectralClustering.load)
 MODEL_FORMAT = 1
@@ -388,7 +389,7 @@ class SpectralClustering:
         with obs.span("transform", path=path, m=m, n=n):
             if path == "dense":
                 K = sim.rbf_kernel(x, self._train_x, self.sigma_)
-                O = K @ (self._inv_sqrt[:, None] * self._eigvecs)
+                O = matmul(K, self._inv_sqrt[:, None] * self._eigvecs)
                 emb = serving.extension_from_product(O, jnp.sum(K, axis=1),
                                                      mu)
                 peak = m * n * 4

@@ -209,10 +209,12 @@ def fused_transform(x: jax.Array, train_x: jax.Array, eigvecs: jax.Array,
                 bm=sched.bm, bn=sched.bn, compute_dtype=cdtype,
                 acc=sched.acc, interpret=sched.interpret)
 
-        fn = jax.jit(mesh_utils.shard_map(
+        # check_vma off: the Pallas kernel's outputs carry no
+        # varying-axes annotation for the checker to verify
+        fn = jax.jit(jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(axes, None), P(), P(), P(), P(), P()),
-            out_specs=(P(axes, None), P(axes, None))))
+            out_specs=(P(axes, None), P(axes, None)), check_vma=False))
         if _cache is not None:
             _cache[key] = fn
 

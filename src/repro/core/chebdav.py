@@ -32,6 +32,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.precision import matmul
+
 
 @dataclass
 class ChebDavResult:
@@ -72,7 +74,7 @@ def _orthonormalize_against(basis: jax.Array, W: jax.Array,
     """CGS2 against ``basis`` then QR within ``W``; (near-)dependent
     columns are dropped, so the returned block may be narrower than W."""
     for _ in range(2):
-        W = W - basis @ (basis.T @ W)
+        W = W - matmul(basis, matmul(basis.T, W))
     Q, R = jnp.linalg.qr(W)
     keep = np.asarray(jnp.abs(jnp.diagonal(R))) > eps
     if not keep.any():
@@ -118,15 +120,15 @@ def chebdav(matmat: Callable, n: int, k: int, key: jax.Array, *,
     max_res = float("inf")
     best_res, stale = float("inf"), 0
     for it in range(1, max_iters + 1):
-        H = V.T @ AV
+        H = matmul(V.T, AV)
         H = 0.5 * (H + H.T)
         evals, U = jnp.linalg.eigh(H)            # ascending
         m = int(H.shape[0])
         kw = min(k, m)                           # wanted pairs available
         Uw = U[:, m - kw:][:, ::-1]              # wanted, descending
         theta = evals[m - kw:][::-1]
-        Rw = V @ Uw                              # wanted Ritz vectors
-        ARw = AV @ Uw
+        Rw = matmul(V, Uw)                       # wanted Ritz vectors
+        ARw = matmul(AV, Uw)
         res = jnp.linalg.norm(ARw - Rw * theta[None, :], axis=0)
         res_np = np.asarray(res)
         max_res = float(res_np.max()) if kw else float("inf")
@@ -170,8 +172,8 @@ def chebdav(matmat: Callable, n: int, k: int, key: jax.Array, *,
         if m + Y.shape[1] > m_max:               # thick restart first:
             keep = max(kw, min(m_max - int(Y.shape[1]), m))
             Uk = U[:, m - keep:]                 # top Ritz directions of
-            V = V @ Uk                           # the current basis (Y is
-            AV = AV @ Uk                         # orthogonal to any subspan)
+            V = matmul(V, Uk)                    # the current basis (Y is
+            AV = matmul(AV, Uk)                  # orthogonal to any subspan)
         V = jnp.concatenate([V, Y], axis=1)
         AV = jnp.concatenate([AV, apply(Y)], axis=1)
 

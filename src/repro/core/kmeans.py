@@ -21,6 +21,7 @@ from repro.core.seeding import kmeans_plusplus_init  # noqa: F401  (shared
 # D^2-sampling seeder, re-exported: callers historically import it from here)
 from repro.core.similarity import pairwise_sq_dists
 from repro.distrib import mesh_utils
+from repro.precision import matmul
 
 
 @jax.tree_util.register_pytree_node_class
@@ -56,7 +57,7 @@ def _update(y, valid, centers):
     d2 = pairwise_sq_dists(y, centers)
     idx = jnp.argmin(d2, axis=1)
     onehot = jax.nn.one_hot(idx, k, dtype=y.dtype) * valid[:, None]
-    sums = onehot.T @ y                       # (k, dim)
+    sums = matmul(onehot.T, y)                # (k, dim)
     counts = jnp.sum(onehot, axis=0)          # (k,)
     inertia = jnp.sum(jnp.min(d2, axis=1) * valid)
     return sums, counts, inertia
@@ -112,7 +113,7 @@ def minibatch_kmeans(y: jax.Array, valid: jax.Array, k: int, key: jax.Array,
         a = jnp.argmin(pairwise_sq_dists(yb, centers), axis=1)
         onehot = jax.nn.one_hot(a, k, dtype=y.dtype)
         bc = jnp.sum(onehot, axis=0)                 # (k,) batch counts
-        bmean = (onehot.T @ yb) / jnp.maximum(bc[:, None], 1.0)
+        bmean = matmul(onehot.T, yb) / jnp.maximum(bc[:, None], 1.0)
         counts = counts + bc
         lr = bc / jnp.maximum(counts, 1.0)
         centers = jnp.where(bc[:, None] > 0,
@@ -136,7 +137,7 @@ def distributed_lloyd_step(y_sharded: jax.Array, valid: jax.Array,
         counts = lax.psum(counts, axis)
         return sums, counts
 
-    shard = mesh_utils.shard_map(
+    shard = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axes, None), P(axes), P()),
         out_specs=(P(), P()),

@@ -36,6 +36,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro.precision import matmul
+
 
 # ---------------------------------------------------------------------------
 # Block Lanczos: the canonical recurrence
@@ -118,16 +120,16 @@ def _block_step_update(state: BlockLanczosState,
     Vp = jnp.where(j > 0, 1.0, 0.0).astype(Vp.dtype) * Vp
     Bj = lax.dynamic_slice(state.B, (j, 0, 0), (1, b, b))[0]     # (b, b)
 
-    W = W.astype(state.V.dtype) - Vp.T @ Bj.T
-    Aj = Vj @ W                                                  # (b, b)
+    W = W.astype(state.V.dtype) - matmul(Vp.T, Bj.T)
+    Aj = matmul(Vj, W)                                           # (b, b)
     Aj = 0.5 * (Aj + Aj.T)          # symmetric operator -> symmetric block
-    W = W - Vj.T @ Aj
+    W = W - matmul(Vj.T, Aj)
     # Full reorthogonalization against the whole block basis, "twice is
     # enough" (CGS2); the row mask limits it to the filled blocks.
     mask = (jnp.arange(rows) < (j + 1) * b).astype(W.dtype)
     for _ in range(2):
-        C = (state.V @ W) * mask[:, None]
-        W = W - state.V.T @ C
+        C = matmul(state.V, W) * mask[:, None]
+        W = W - matmul(state.V.T, C)
     Qn, R = _qr_pos(W)
     return BlockLanczosState(
         step=j + 1,
@@ -231,7 +233,7 @@ def block_ritz_pairs(state: BlockLanczosState) -> tuple[jax.Array, jax.Array]:
     T = block_tridiagonal(state)
     evals, evecs = jnp.linalg.eigh(T)            # ascending
     s, b, _ = state.A.shape
-    ritz_vecs = state.V[: s * b].T @ evecs       # (n, s*b)
+    ritz_vecs = matmul(state.V[: s * b].T, evecs)   # (n, s*b)
     return evals, ritz_vecs
 
 
@@ -339,7 +341,7 @@ def ritz_pairs(state: LanczosState) -> tuple[jax.Array, jax.Array]:
     T = tridiagonal(state)
     evals, evecs = jnp.linalg.eigh(T)           # ascending
     m = state.alpha.shape[0]
-    ritz_vecs = state.V[:m].T @ evecs           # (n, m)
+    ritz_vecs = matmul(state.V[:m].T, evecs)    # (n, m)
     return evals, ritz_vecs
 
 

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.similarity import UpperSim, sym_matmat, sym_matvec
+from repro.precision import matmul
 
 
 def dense_degrees(S: jax.Array) -> jax.Array:
@@ -37,12 +38,12 @@ def make_dense_operator(S: jax.Array, valid: jax.Array):
     for out-of-sample extension.  The width-1 matvec view is derived by
     :class:`~repro.cluster.operator.NormalizedOperator`.
     """
-    deg = S @ valid  # padded cols are zero already
+    deg = matmul(S, valid)  # padded cols are zero already
     inv_sqrt = masked_inv_sqrt(deg)
 
     def matmat(V: jax.Array) -> jax.Array:
         return valid[:, None] * V + inv_sqrt[:, None] * (
-            S @ (inv_sqrt[:, None] * V))
+            matmul(S, inv_sqrt[:, None] * V))
 
     return matmat, inv_sqrt
 
@@ -54,7 +55,7 @@ def dense_shifted_matrix(S: jax.Array, valid: jax.Array,
     Pass the operator build's ``inv_sqrt`` when you have it — recomputing
     it here costs a redundant full pass over S."""
     if inv_sqrt is None:
-        inv_sqrt = masked_inv_sqrt(S @ valid)
+        inv_sqrt = masked_inv_sqrt(matmul(S, valid))
     return jnp.diag(valid) + S * (inv_sqrt[:, None] * inv_sqrt[None, :])
 
 
@@ -113,7 +114,7 @@ def make_dense_shifted_matmat(
     inv_sqrt = masked_inv_sqrt(dense_degrees(S) if deg is None else deg)
 
     def matmat(V: jax.Array) -> jax.Array:
-        return V + inv_sqrt[:, None] * (S @ (inv_sqrt[:, None] * V))
+        return V + inv_sqrt[:, None] * matmul(S, inv_sqrt[:, None] * V)
 
     return matmat
 

@@ -34,6 +34,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.distrib import mesh_utils
+from repro.precision import matmul
 
 
 # ---------------------------------------------------------------------------
@@ -44,7 +45,7 @@ def pairwise_sq_dists(x: jax.Array, y: jax.Array) -> jax.Array:
     """||x_i - y_j||^2 via the MXU-friendly decomposition."""
     xx = jnp.sum(x * x, axis=-1)[:, None]
     yy = jnp.sum(y * y, axis=-1)[None, :]
-    xy = x @ y.T
+    xy = matmul(x, y.T)
     return jnp.maximum(xx + yy - 2.0 * xy, 0.0)
 
 
@@ -206,11 +207,11 @@ def similarity_upper_blocks(
             return lax.dynamic_update_slice(U, tile, (p_local * b, q * b))
 
         U_local = jnp.zeros((2 * b, n_pad), x.dtype)
-        U_local = mesh_utils.pvary(U_local, tuple(axes))  # mark carry device-varying
+        U_local = lax.pcast(U_local, tuple(axes), to="varying")  # mark carry device-varying
         U_local = lax.fori_loop(0, n_tiles, tile_step, U_local)
         return U_local
 
-    shard = mesh_utils.shard_map(
+    shard = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axes, None), P(axes, None, None), P(axes)),
@@ -243,13 +244,14 @@ def sym_matmat(upper: UpperSim, V: jax.Array) -> jax.Array:
         r0 = idx * b2
         V_rows = lax.dynamic_slice(V_full, (r0, 0), (b2, width))
         part = jnp.zeros_like(V_full)
-        part = lax.dynamic_update_slice(part, U_local @ V_full, (r0, 0))
-        part = part + U_local.T @ V_rows
+        part = lax.dynamic_update_slice(part, matmul(U_local, V_full),
+                                        (r0, 0))
+        part = part + matmul(U_local.T, V_rows)
         part = part - lax.dynamic_update_slice(
             jnp.zeros_like(V_full), diag_local[:, None] * V_rows, (r0, 0))
         return lax.psum(part, axis)
 
-    shard = mesh_utils.shard_map(
+    shard = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axes, None), P(axes), P()),
@@ -344,7 +346,7 @@ def similarity_upper_blocks_compact(
         _, tiles = lax.scan(one_tile, None, jnp.arange(n_tiles))
         return tiles
 
-    shard = mesh_utils.shard_map(
+    shard = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axes, None), P(axes, None, None), P(axes)),
         out_specs=P(axes, None, None),
@@ -380,16 +382,16 @@ def sym_matmat_compact(upper: UpperSimCompact, V: jax.Array) -> jax.Array:
             Vc = lax.dynamic_slice(V_full, (c0, 0), (b, width))
             # rows += tile @ V[cols]
             cur = lax.dynamic_slice(partial, (r0, 0), (b, width))
-            partial = lax.dynamic_update_slice(partial, cur + tile @ Vc,
-                                               (r0, 0))
+            partial = lax.dynamic_update_slice(
+                partial, cur + matmul(tile, Vc), (r0, 0))
             # cols += tile^T @ V[rows]  (the mirror, never materialized)
             cur = lax.dynamic_slice(partial, (c0, 0), (b, width))
-            partial = lax.dynamic_update_slice(partial, cur + tile.T @ Vr,
-                                               (c0, 0))
+            partial = lax.dynamic_update_slice(
+                partial, cur + matmul(tile.T, Vr), (c0, 0))
             return partial
 
         partial = jnp.zeros_like(V_full)
-        partial = mesh_utils.pvary(partial, tuple(axes))
+        partial = lax.pcast(partial, tuple(axes), to="varying")
         partial = lax.fori_loop(0, n_tiles, one, partial)
         # diagonal tiles contribute their diagonal twice via the mirror
         Vr2 = lax.dynamic_slice(V_full, (dev_r0, 0), (2 * b, width))
@@ -397,7 +399,7 @@ def sym_matmat_compact(upper: UpperSimCompact, V: jax.Array) -> jax.Array:
             jnp.zeros_like(V_full), diag_local[:, None] * Vr2, (dev_r0, 0))
         return lax.psum(partial - corr, axis)
 
-    shard = mesh_utils.shard_map(
+    shard = jax.shard_map(
         body, mesh=upper.mesh,
         in_specs=(P(axes, None, None), P(axes, None, None), P(axes), P()),
         out_specs=P(),
@@ -455,7 +457,7 @@ def distributed_similarity_full(
         S_local = S_local * valid_full[None, :].astype(S_local.dtype)
         return S_local
 
-    shard = mesh_utils.shard_map(
+    shard = jax.shard_map(
         body, mesh=mesh, in_specs=(P(axes, None), P(axes)), out_specs=P(axes, None)
     )
     return shard(xp, valid)

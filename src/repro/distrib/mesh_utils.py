@@ -10,56 +10,13 @@ import math
 from typing import Sequence
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.5 has explicit axis types; 0.4.x predates them.
-    from jax.sharding import AxisType
-    HAS_AXIS_TYPES = True
-except ImportError:  # pragma: no cover - exercised only on old jax
-    class AxisType:  # minimal stand-in so `AxisType.Auto` stays importable
-        Auto = "auto"
-
-    HAS_AXIS_TYPES = False
-
-try:
-    _shard_map_impl = jax.shard_map
-    _SHARD_MAP_KW: dict = {}
-except AttributeError:  # jax 0.4.x: experimental namespace, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-    _SHARD_MAP_KW = {"check_rep": False}
-
-
-def shard_map(f, *, mesh: Mesh, in_specs, out_specs, check_vma: bool | None = None):
-    """``jax.shard_map`` across jax versions (0.4 experimental -> 0.9 public).
-
-    ``check_vma`` maps to 0.4's ``check_rep``; ``None`` means the caller's
-    default (which on 0.4 must be off — its replication checker predates the
-    varying-marker semantics the kernels rely on)."""
-    kwargs = dict(_SHARD_MAP_KW)
-    if check_vma is not None:
-        if "check_rep" in kwargs:
-            kwargs["check_rep"] = check_vma
-        else:
-            kwargs["check_vma"] = check_vma
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kwargs)
-
-
-def pvary(x, axes):
-    """``lax.pvary`` where it exists (varying-marker for shard_map carries);
-    a no-op on jax versions without per-axis varying tracking."""
-    fn = getattr(jax.lax, "pvary", None)
-    return fn(x, axes) if fn is not None else x
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 
 def make_mesh(shape: Sequence[int], axis_names: Sequence[str], devices=None) -> Mesh:
-    """jax.make_mesh pinned to Auto axis types (stable across jax 0.4/0.8/0.9)."""
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if HAS_AXIS_TYPES:
-        kwargs["axis_types"] = (AxisType.Auto,) * len(axis_names)
-    return jax.make_mesh(tuple(shape), tuple(axis_names), **kwargs)
+    """jax.make_mesh pinned to Auto axis types."""
+    return jax.make_mesh(tuple(shape), tuple(axis_names), devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def local_mesh(axis_name: str = "rows", n_devices: int | None = None) -> Mesh:

@@ -537,6 +537,16 @@ def _run_fused(plan: JobPlan, reader) -> JobResult:
                      sigma=sigma, graph=None, stats=stats)
 
 
+def _out_of_memory(e: BaseException) -> bool:
+    """Host RAM or device HBM ran out.  Mosaic reports a kernel whose
+    tiles overflow VMEM as RESOURCE_EXHAUSTED too, at compile time: that
+    is a schedule fault, not a job too large for the chip."""
+    if isinstance(e, MemoryError):
+        return True
+    msg = str(e)
+    return "RESOURCE_EXHAUSTED" in msg and "vmem" not in msg.lower()
+
+
 def run_job(plan: JobPlan, reader) -> JobResult:
     """Full out-of-core pipeline: dependency-scheduled graph build,
     shard-streaming block Lanczos, chunked mini-batch k-means.
@@ -564,10 +574,15 @@ def run_job(plan: JobPlan, reader) -> JobResult:
         if route_path(plan, d) == "fused":
             try:
                 return _run_fused(plan, reader)
-            except Exception as e:
-                # graceful degradation: an auto-routed fused job that
-                # fails falls back to the ooc pipeline (an explicitly
-                # forced path propagates its error instead)
+            except (MemoryError, jax.errors.JaxRuntimeError) as e:
+                # graceful degradation: an auto-routed fused job that runs
+                # out of host or device memory falls back to the ooc
+                # pipeline.  Any other error propagates, as on an
+                # explicitly forced path: a kernel that fails to lower or
+                # compile is a fault, and rerouting would hide it behind a
+                # slow, correct run
+                if not _out_of_memory(e):
+                    raise
                 obs.counter("engine.path_fallbacks").inc()
                 fallback = f"fused->ooc ({type(e).__name__})"
 

@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.precision import mxu_precision
+
 
 def interpret_default() -> bool:
     """Interpret only off-TPU (CPU/GPU run the kernel body in Python for
@@ -61,7 +63,7 @@ def _matmat_kernel(a_ref, v_ref, o_ref):
     a = a_ref[...]                       # (bm, bn)
     v = v_ref[...]                       # (bn, b)
     acc = jax.lax.dot_general(
-        a, v, (((1,), (0,)), ((), ())),
+        a, v, (((1,), (0,)), ((), ())), precision=mxu_precision(a.dtype),
         preferred_element_type=jnp.float32)  # (bm, b)
     o_ref[...] += acc.astype(o_ref.dtype)
 
@@ -78,6 +80,7 @@ def _matmat_kernel_scratch(a_ref, v_ref, o_ref, acc_ref):
 
     acc_ref[...] += jax.lax.dot_general(
         a_ref[...], v_ref[...], (((1,), (0,)), ((), ())),
+        precision=mxu_precision(a_ref.dtype),
         preferred_element_type=jnp.float32)
 
     @pl.when(j == pl.num_programs(1) - 1)

@@ -46,12 +46,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, bq, bk, seq_k, scale,
     def body(ti, acc):
         m, l, o = acc
         t0 = ti * bk
-        # size-1 dslice, not int 0: jax 0.4's interpret-mode discharge rule
-        # cannot handle raw scalar indices
-        k = pl.load(k_ref, (pl.dslice(0, 1), pl.dslice(t0, bk),
-                            slice(None)))[0]                      # (bk, hd)
-        v = pl.load(v_ref, (pl.dslice(0, 1), pl.dslice(t0, bk),
-                            slice(None)))[0]
+        k = k_ref[0, pl.ds(t0, bk), :]                            # (bk, hd)
+        v = v_ref[0, pl.ds(t0, bk), :]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)

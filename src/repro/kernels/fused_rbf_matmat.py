@@ -19,7 +19,8 @@ run in — bf16 operands double MXU throughput on TPU (the cast happens in
 register, so HBM traffic is unchanged); the squared-norm terms, the exp,
 and BOTH accumulations always stay in f32
 (``preferred_element_type=jnp.float32``), so bf16 only perturbs the tile
-entries, not the reduction.
+entries, not the reduction.  A float32 product asks for HIGHEST
+precision (``repro.precision.mxu_precision``).
 
 Tile/grid conventions follow ``kernels/rbf_similarity`` (points short and
 wide: feature dim kept whole in VMEM) and ``kernels/block_matmat`` (output
@@ -39,6 +40,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.block_matvec import check_tiles, interpret_default
+from repro.precision import mxu_precision
 
 # names accepted by the public ``compute_dtype`` knob (estimator kwarg /
 # --compute-dtype CLI flag); None means full f32
@@ -87,16 +89,17 @@ def _fused_tile_product(x_ref, y_ref, v_ref, cs_ref, inv2s2_ref,
     # makes bf16 perturb only the cross term, not the distance scale)
     xx = jnp.sum(x * x, axis=-1)[:, None]
     yy = jnp.sum(y * y, axis=-1)[None, :]
+    precision = mxu_precision(compute_dtype)
     xy = jax.lax.dot_general(
         x.astype(compute_dtype), y.astype(compute_dtype),
-        (((1,), (1,)), ((), ())),
+        (((1,), (1,)), ((), ())), precision=precision,
         preferred_element_type=jnp.float32)     # MXU, f32 accumulate
     d2 = jnp.maximum(xx + yy - 2.0 * xy, 0.0)
     tile = jnp.exp(-d2 * inv2s2_ref[0])         # RBF tile, in-register only
     w = cs_ref[...] * v_ref[...]                # (bn, b): D^{-1/2} V tile
     acc = jax.lax.dot_general(
         tile.astype(compute_dtype), w.astype(compute_dtype),
-        (((1,), (0,)), ((), ())),
+        (((1,), (0,)), ((), ())), precision=precision,
         preferred_element_type=jnp.float32)     # (bm, b), f32 accumulate
     return tile, acc
 

@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.precision import mxu_precision
+
 
 def _rbf_kernel(x_ref, y_ref, inv2s2_ref, o_ref):
     x = x_ref[...]                    # (bm, d)
@@ -24,7 +26,7 @@ def _rbf_kernel(x_ref, y_ref, inv2s2_ref, o_ref):
     xx = jnp.sum(x * x, axis=-1)[:, None]
     yy = jnp.sum(y * y, axis=-1)[None, :]
     xy = jax.lax.dot_general(
-        x, y, (((1,), (1,)), ((), ())),
+        x, y, (((1,), (1,)), ((), ())), precision=mxu_precision(x.dtype),
         preferred_element_type=jnp.float32)   # MXU matmul, f32 accumulate
     d2 = jnp.maximum(xx + yy - 2.0 * xy, 0.0)
     o_ref[...] = jnp.exp(-d2 * inv2s2_ref[0]).astype(o_ref.dtype)

@@ -34,6 +34,7 @@ registry.
 from __future__ import annotations
 
 import argparse
+import copy
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -42,8 +43,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import obs
+from repro import compile_cache, obs
 from repro.cluster.serving import DeadlineExceededError, QueueFullError
+
+# the fitted arrays est.predict reads
+_MODEL_STATE = ("_train_x", "_eigvecs", "_inv_sqrt", "eigenvalues_",
+                "sigma_", "centers_")
 
 
 @dataclass
@@ -98,8 +103,21 @@ class ClusterServer:
         self.request_ms = obs.histogram("serve.request_ms")
         # one compiled predict for the one static shape the service runs;
         # est.predict routes (dense/fused) on static metadata, so the
-        # whole embed+assign pipeline traces into a single computation
-        self._predict = jax.jit(lambda xb: est.predict(xb))
+        # whole embed+assign pipeline traces into a single computation.
+        # The model's arrays are arguments, not constants folded into the
+        # program: it stays small, compiles without constant folding over
+        # the training set, and its compile-cache key depends on shapes
+        # only, so any model of the same shape loads it
+        self._model = tuple(getattr(est, name) for name in _MODEL_STATE)
+
+        def predict(xb, model):
+            view = copy.copy(est)      # shares info_ and the kernel cache
+            for name, value in zip(_MODEL_STATE, model):
+                setattr(view, name, value)
+            return view.predict(xb)
+
+        jitted = jax.jit(predict)
+        self._predict = lambda xb: jitted(xb, self._model)
 
     # -- admission control ---------------------------------------------------
 
@@ -294,6 +312,7 @@ def main(argv=None):
     ap.add_argument("--metrics-out", default=None, metavar="FILE.json",
                     help="write the metrics registry snapshot as JSON")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     if args.fit_blobs:
         pts, _ = synthetic.blobs(args.fit_blobs, args.k, dim=8, spread=0.6,

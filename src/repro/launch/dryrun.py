@@ -30,13 +30,16 @@ from repro.launch.mesh import make_production_mesh, make_spectral_mesh
 from repro.models import api
 from repro.models import params as pp
 from repro.models.config import SHAPES_BY_NAME
+from repro.precision import matmul
 from repro.train import optimizer as opt_lib
 from repro.train.step import make_train_step
+from repro.tune.peaks import DEVICE_PEAKS
 
-# TPU v5e hardware constants (per chip)
-PEAK_FLOPS = 197e12          # bf16
-HBM_BW = 819e9               # bytes/s
-ICI_BW = 50e9                # bytes/s/link
+# TPU v5e per-chip peaks from the one table
+_V5E = DEVICE_PEAKS["tpu-v5-lite"]
+PEAK_FLOPS = _V5E["flops"]   # bf16
+HBM_BW = _V5E["bytes"]       # bytes/s
+ICI_BW = _V5E["ici_link"]    # bytes/s/link
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3": 1, "f8e5m2": 1,
@@ -254,11 +257,11 @@ def lower_spectral_cell(phase: str, multi_pod: bool, n: int | None = None):
 
         def fn(S, state):
             valid = (jnp.arange(n_pad) < n).astype(jnp.float32)
-            deg = S @ valid
+            deg = matmul(S, valid)
             inv = jnp.where(deg > 0, 1.0 / jnp.sqrt(jnp.maximum(deg, 1e-12)), 0.0)
 
             def mv(v):
-                return valid * v + inv * (S @ (inv * v))
+                return valid * v + inv * matmul(S, inv * v)
 
             return lz.run(mv, state, 1)
 

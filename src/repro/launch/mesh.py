@@ -2,7 +2,7 @@
 importing this module never touches jax device state).
 
 Mesh construction goes through :func:`repro.distrib.mesh_utils.make_mesh`,
-which version-guards the ``AxisType`` kwarg (jax 0.4.x predates axis types).
+which pins every axis to ``AxisType.Auto``.
 """
 from __future__ import annotations
 

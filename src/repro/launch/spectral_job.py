@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import obs
+from repro import compile_cache, obs
 from repro.checkpoint import CheckpointManager
 from repro.cluster import AFFINITIES, ASSIGNERS, EIGENSOLVERS, SpectralClustering
 from repro.data import graph_file, synthetic
@@ -104,6 +104,7 @@ def main(argv=None):
     ap.add_argument("--metrics-out", default=None, metavar="FILE.json",
                     help="write the metrics registry snapshot as JSON")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     affinity = args.affinity
     if args.mode is not None:

@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.distrib import mesh_utils
 from repro.models.config import ModelConfig
 from repro.models.layers import _act
 from repro.models.params import Spec
@@ -116,7 +115,7 @@ def moe_ffn_ep_shard_map(x: jax.Array, p: dict, cfg: ModelConfig):
         counts = jnp.bincount(expert_idx.reshape(-1), length=E)
         return out, lb, lax.psum(counts, ba) if ba else counts
 
-    shard = mesh_utils.shard_map(
+    shard = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(ba, None), P(None, None), P("model", None, None, None),
                   P("model", None, None)),

@@ -79,16 +79,16 @@ def pipeline_apply(layer_fn: Callable, stacked_params, x: jax.Array,
             return (buf, out), None
 
         buf0 = jnp.zeros((M,) + xs.shape[1:], x.dtype)
-        buf0 = mesh_utils.pvary(buf0, (axis,) + tuple(other))
+        buf0 = lax.pcast(buf0, (axis,) + tuple(other), to="varying")
         prev0 = jnp.zeros(xs.shape[1:], x.dtype)
-        prev0 = mesh_utils.pvary(prev0, (axis,) + tuple(other))
+        prev0 = lax.pcast(prev0, (axis,) + tuple(other), to="varying")
         (buf, _), _ = lax.scan(tick, (buf0, prev0), jnp.arange(T))
         # broadcast the last stage's buffer to every stage (masked psum)
         buf = lax.psum(jnp.where(idx == n_stage - 1, buf, 0.0), axis)
         return buf
 
     xs = x.reshape((M, mb) + x.shape[1:])
-    fn = mesh_utils.shard_map(
+    fn = jax.shard_map(
         stage_body, mesh=mesh,
         in_specs=(p_specs, P()),
         out_specs=P(),

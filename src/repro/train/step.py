@@ -11,7 +11,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distrib import mesh_utils
 from repro.models.api import Model
 from repro.train import optimizer as opt_lib
 
@@ -118,7 +117,7 @@ def make_compressed_train_step(model: Model, optimizer: opt_lib.Optimizer,
         rep_o = jax.tree.map(lambda _: P(), opt_state)
         efp = jax.tree.map(lambda _: P(), ef)
         bspec = jax.tree.map(lambda _: P(axis), batch)
-        fn = mesh_utils.shard_map(
+        fn = jax.shard_map(
             inner, mesh=mesh,
             in_specs=(rep, rep_o, efp, bspec),
             out_specs=(rep, rep_o, efp, P()),

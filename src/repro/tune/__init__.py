@@ -6,7 +6,7 @@ interpret flag, with per-kernel legality checks (``KERNELS`` specs).
 ``ScheduleCache`` persists the best-known schedule per (kernel, shape
 bucket, device kind, dtype) as one JSON file; ``autotune`` /
 ``tune_all`` fill it by timing real kernel calls and scoring them
-against the roofline peak model (``benchmarks/roofline.py``).
+against the roofline peak model (``repro.tune.peaks``).
 
 Entry points:
   * ``ops.<kernel>(..., schedule=...)`` — None (defaults), "auto"

@@ -11,7 +11,7 @@ default schedule is always among the candidates, so the tuned pick can
 never regress it (up to timing noise — winners are best-of-``iters``).
 
 ``autotune`` returns a full report (every candidate with wall time and
-achieved-vs-peak FLOPs/bytes via ``benchmarks/roofline.py``); ``tune_all``
+achieved-vs-peak FLOPs/bytes via ``repro.tune.peaks``); ``tune_all``
 sweeps the standard kernel set.  A cache hit short-circuits the sweep
 unless ``force=True`` — re-running a sweep is free once tuned.
 """
@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.tune.cache import ScheduleCache, bucket, default_cache
+from repro.tune.peaks import kernel_roofline
 from repro.tune.schedule import Schedule, ScheduleError, spec
 
 # tile-edge ladder: MXU/lane multiples only (every entry legal compiled)
@@ -128,27 +129,6 @@ def _time(fn, s: Schedule, *, warmup: int, iters: int) -> float:
     return best * 1e6
 
 
-def _roofline_mod():
-    try:
-        from benchmarks import roofline
-        return roofline
-    except ImportError:
-        pass
-    try:  # repo-layout fallback: src/repro/tune -> repo root/benchmarks
-        import importlib.util
-        import os
-        here = os.path.dirname(os.path.abspath(__file__))
-        path = os.path.join(here, "..", "..", "..", "benchmarks",
-                            "roofline.py")
-        s = importlib.util.spec_from_file_location("_repro_roofline",
-                                                   os.path.normpath(path))
-        mod = importlib.util.module_from_spec(s)
-        s.loader.exec_module(mod)
-        return mod
-    except Exception:
-        return None
-
-
 def autotune(kernel: str, n: int, *, d: int = 8, b: int = 8, k: int = 8,
              compute_dtype: Optional[str] = None,
              cache: Optional[ScheduleCache] = None, quick: bool = False,
@@ -186,15 +166,14 @@ def autotune(kernel: str, n: int, *, d: int = 8, b: int = 8, k: int = 8,
         fn = _bench_fn(kernel, **shape)
         cands = candidates(kernel, quick=quick, compute_dtype=compute_dtype,
                            **shape)
-        roofline = _roofline_mod()
         if quick:
             iters = 1
         rows, default_us = [], None
         for s in cands:
             wall_us = _time(fn, s, warmup=warmup, iters=iters)
             rec = {"schedule": s.to_dict(), "wall_us": round(wall_us, 1)}
-            if roofline is not None and sp.flops_model and sp.bytes_model:
-                rec.update(roofline.kernel_roofline(
+            if sp.flops_model and sp.bytes_model:
+                rec.update(kernel_roofline(
                     sp.flops_model(s, **shape), sp.bytes_model(s, **shape),
                     wall_us * 1e-6))
             rows.append(rec)

@@ -399,4 +399,8 @@ def resolve(kernel: str, schedule: Any = None, *, bm: Optional[int] = None,
         from repro.kernels.block_matvec import interpret_default
         s = s.replace(interpret=interpret_default())
     sp.check(s, **{k: v for k, v in shape.items() if k in sp.shape_dims})
+    # one count per resolution: a chip run asserts none was interpreted
+    from repro import obs
+    obs.counter("tune.resolved", kernel=kernel,
+                interpret=bool(s.interpret)).inc()
     return s, source

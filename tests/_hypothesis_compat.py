@@ -1,63 +1,2 @@
-"""Fallback for the ``hypothesis`` property-testing library.
-
-The CI image doesn't always ship hypothesis (and the repo must not add
-dependencies), so the property tests import ``given``/``settings``/``st``
-from here.  When hypothesis is available it is used unchanged; otherwise a
-minimal deterministic sampler runs each property on a fixed number of
-pseudo-random examples drawn from the declared ranges — weaker than real
-shrinking/search, but it keeps the invariants exercised.
-"""
-from __future__ import annotations
-
-try:  # pragma: no cover - exercised when hypothesis is installed
-    from hypothesis import given, settings, strategies as st  # noqa: F401
-    HAVE_HYPOTHESIS = True
-except ImportError:
-    import random
-
-    HAVE_HYPOTHESIS = False
-
-    class _Strategy:
-        def __init__(self, sample):
-            self._sample = sample
-
-        def sample(self, rng: random.Random):
-            return self._sample(rng)
-
-    class _St:
-        @staticmethod
-        def integers(min_value: int, max_value: int) -> _Strategy:
-            return _Strategy(lambda rng: rng.randint(min_value, max_value))
-
-        @staticmethod
-        def floats(min_value: float, max_value: float) -> _Strategy:
-            return _Strategy(lambda rng: rng.uniform(min_value, max_value))
-
-    st = _St()
-
-    _MAX_EXAMPLES = 20
-
-    def given(*strategies: _Strategy):
-        def deco(fn):
-            def wrapper():
-                # zero-arg on purpose: pytest must not see the property's
-                # parameters (it would try to resolve them as fixtures)
-                rng = random.Random(0)
-                n = min(getattr(wrapper, "_max_examples", _MAX_EXAMPLES),
-                        _MAX_EXAMPLES)
-                for _ in range(n):
-                    ex = tuple(s.sample(rng) for s in strategies)
-                    fn(*ex)
-            wrapper.__name__ = fn.__name__
-            wrapper.__module__ = fn.__module__
-            wrapper.__doc__ = fn.__doc__
-            wrapper.__dict__.update(fn.__dict__)
-            return wrapper
-        return deco
-
-    def settings(max_examples: int | None = None, **_ignored):
-        def deco(fn):
-            if max_examples is not None:
-                fn._max_examples = max_examples
-            return fn
-        return deco
+"""The property tests' one import point for ``hypothesis``."""
+from hypothesis import given, settings, strategies as st  # noqa: F401

@@ -1,0 +1,121 @@
+"""The main-path Pallas kernels compile for a TPU v5e at the widths
+``chip_smoke.py`` runs them.
+
+Nothing here runs a kernel: each test lowers one kernel for the first chip
+of a described (not attached) ``v5e:2x2`` topology and asserts that the
+compiled program holds the Mosaic kernel (``tpu_custom_call``).  This
+catches what interpret mode cannot: block shapes the TPU tiling refuses,
+and VMEM overuse.
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU compiler library, and the test
+workers each import this file.
+"""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import (
+    block_matvec,
+    fused_rbf_matmat as frm,
+    kmeans_assign,
+    rbf_similarity,
+)
+from repro.tune.schedule import KERNELS
+
+N = 262_144      # the fused fit's n (configs/spectral_paper.PRODUCTION_N)
+D = 8            # blob width of the fit and serve phases
+B = 8            # block-Lanczos width, and k for the serve embedding
+TILE = 256       # fused_rbf_matmat.default_tile at this n
+QUERIES = 1024   # ClusterServer batch_rows in the serve phase
+CHUNK = 4096     # the out-of-core phase's --chunk-size (one map tile)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # any failure means there is no compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip can be written to the persistent
+    # cache but never read back without the chip: keep it out (the reset
+    # drops a cache an earlier test of this process may have opened)
+    from jax.experimental.compilation_cache import compilation_cache
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def test_topology_is_v5e(topo):
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+
+
+@pytest.mark.parametrize("compute_dtype,acc", [("float32", "inplace"),
+                                               ("bfloat16", "scratch")])
+def test_fused_rbf_matmat_compiles(one_chip, compute_dtype, acc):
+    fn = functools.partial(
+        frm.fused_rbf_matmat, bm=TILE, bn=TILE, compute_dtype=compute_dtype,
+        acc=acc, interpret=False)
+    f32 = jnp.float32
+    hlo = _compile(lambda x, V, s, r: fn(x, x, V, s, r, r), one_chip,
+                   ((N, D), f32), ((N, B), f32), ((), f32), ((N,), f32))
+    assert "tpu_custom_call" in hlo
+
+
+def test_fused_nystrom_matmat_compiles(one_chip):
+    fn = functools.partial(frm.fused_nystrom_matmat, bm=TILE, bn=TILE,
+                           interpret=False)
+    f32 = jnp.float32
+    hlo = _compile(lambda q, y, Z, s, cs, cv: fn(q, y, Z, s, cs, cv),
+                   one_chip, ((QUERIES, D), f32), ((N, D), f32),
+                   ((N, B), f32), ((), f32), ((N,), f32), ((N,), f32))
+    assert "tpu_custom_call" in hlo
+
+
+def test_rbf_similarity_compiles(one_chip):
+    sched = KERNELS["rbf_similarity"].default
+    fn = functools.partial(rbf_similarity.rbf_similarity, bm=sched.bm,
+                           bn=sched.bn, interpret=False)
+    f32 = jnp.float32
+    hlo = _compile(fn, one_chip, ((CHUNK, 2), f32), ((CHUNK, 2), f32),
+                   ((), f32))
+    assert "tpu_custom_call" in hlo
+
+
+def test_block_matmat_compiles(one_chip):
+    sched = KERNELS["block_matmat"].default
+    fn = functools.partial(block_matvec.block_matmat, bm=sched.bm,
+                           bn=sched.bn, interpret=False)
+    f32 = jnp.float32
+    hlo = _compile(fn, one_chip, ((8192, 8192), f32), ((8192, B), f32))
+    assert "tpu_custom_call" in hlo
+
+
+def test_kmeans_assign_compiles_at_schedule_default(one_chip):
+    bm = KERNELS["kmeans_assign"].default.bm
+    fn = functools.partial(kmeans_assign.kmeans_assign, bm=bm,
+                           interpret=False)
+    f32 = jnp.float32
+    hlo = _compile(fn, one_chip, ((N, D), f32), ((B, D), f32))
+    assert "tpu_custom_call" in hlo

@@ -161,6 +161,36 @@ def test_precomputed_topology_graph_roundtrip(tmp_path):
                                   np.asarray(est2.labels_))
 
 
+@pytest.mark.parametrize("n_edges,components", [(900, 1), (60, None)])
+def test_graph_reference_counts_zero_eigenvalues(n_edges, components):
+    """The float64 graph witness: one zero L_sym eigenvalue per connected
+    component, as a dense float64 eigh of the same graph shows."""
+    from repro.cluster.reference import graph_components
+    edges, _ = synthetic.synthetic_graph(n=160, n_edges=n_edges, k=3, seed=0)
+    S = adjacency_dense(160, edges, np.float64)
+    inv = 1.0 / np.sqrt(S.sum(axis=1))
+    L = np.eye(160) - S * inv[:, None] * inv[None, :]
+    zeros = int(np.sum(np.abs(np.linalg.eigvalsh(L)) < 1e-9))
+    assert graph_components(160, edges) == zeros
+    if components is not None:
+        assert zeros == components
+
+
+def test_graph_lanczos_reference_matches_the_lanczos_solver():
+    """The float64 recurrence and the estimator's float32 "lanczos" solver
+    agree where 48 steps converge (a connected 160-vertex graph)."""
+    from repro.cluster.reference import graph_lanczos_reference
+    edges, _ = synthetic.synthetic_graph(n=160, n_edges=900, k=3, seed=0)
+    est = SpectralClustering(3, affinity="precomputed", lanczos_steps=48,
+                             seed=0).fit(jnp.asarray(adjacency_dense(160,
+                                                                     edges)))
+    _, k_lan, _ = jax.random.split(jax.random.PRNGKey(0), 3)
+    v0 = np.asarray(jax.random.normal(k_lan, (est.info_["n_pad"],),
+                                      jnp.float32), np.float64)
+    ref = graph_lanczos_reference(160, edges, v0, 48, 3)
+    np.testing.assert_allclose(np.asarray(est.eigenvalues_), ref, atol=1e-5)
+
+
 # ---------------------------------------------------------------------------
 # out-of-sample transform / predict
 # ---------------------------------------------------------------------------
@@ -185,6 +215,19 @@ def test_predict_heldout_points():
     emb = np.asarray(est.transform(jnp.asarray(held)))
     assert emb.shape == (30, 3)
     np.testing.assert_allclose(np.linalg.norm(emb, axis=1), 1.0, atol=1e-4)
+
+
+def test_predict_jitted_by_a_caller_multiplies_in_float32():
+    """The estimator's float32 matrix products reach a program a caller
+    jits around ``predict`` (as ClusterServer does): on a TPU the default
+    would be one bfloat16 pass."""
+    pts, _ = synthetic.blobs(64, 2, seed=3)
+    x = jnp.asarray(pts)
+    est = SpectralClustering(2, affinity="dense", eigensolver="eigh").fit(x)
+    text = jax.jit(lambda xb: est.predict(xb)).lower(x[:16]).as_text()
+    dots = [line for line in text.splitlines() if "dot_general" in line]
+    assert dots
+    assert all("precision = [HIGHEST, HIGHEST]" in line for line in dots)
 
 
 def test_precomputed_fit_cannot_predict():

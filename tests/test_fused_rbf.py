@@ -159,6 +159,22 @@ def test_estimator_fused_matches_dense_labels():
     assert bf16.info_["engine"]["compute_dtype"] == "bfloat16"
 
 
+@pytest.mark.parametrize("affinity", ["fused-rbf", "dense"])
+def test_estimator_matches_float64_numpy_reference(affinity):
+    """The system against the plain float64 reference (dense RBF, eigh,
+    Lloyd) on the blob shape the chip smoke runs at n=262,144."""
+    from repro.cluster.reference import spectral_reference
+    pts, truth = synthetic.blobs(512, 8, dim=8, spread=0.6, seed=0)
+    est = SpectralClustering(8, affinity=affinity,
+                             eigensolver="block-lanczos").fit(
+        jnp.asarray(pts))
+    labels, evals = spectral_reference(pts, 8, float(est.sigma_))
+    assert ari(truth, labels) == 1.0
+    assert ari(labels, np.asarray(est.labels_)) >= 0.99
+    np.testing.assert_allclose(np.asarray(est.eigenvalues_), evals,
+                               atol=1e-4)
+
+
 def test_eigh_reports_matrix_passes():
     pts, _ = synthetic.blobs(48, 2, dim=3, seed=1)
     est = SpectralClustering(2, affinity="dense", eigensolver="eigh",
@@ -220,6 +236,47 @@ def test_run_job_routes_to_fused_and_clusters():
     assert res.stats["matrix_passes"] > 0
     assert res.stats["affinity_peak_bytes"] <= budget
     assert ari(reader.all_labels(), res.labels) >= 0.95
+
+
+@pytest.mark.parametrize("error,reroutes", [
+    (jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: out of HBM"), True),
+    (MemoryError(), True),
+    (jax.errors.JaxRuntimeError(
+        "INVALID_ARGUMENT: Mosaic failed to compile TPU kernel"), False),
+    (jax.errors.JaxRuntimeError(
+        "RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem while "
+        "allocating on stack for %fused_rbf_matmat"), False),
+    (NotImplementedError("unsupported lowering"), False),
+])
+def test_auto_fused_reroutes_only_out_of_memory(monkeypatch, error,
+                                                reroutes):
+    """An auto-routed fused job that runs out of host or device memory
+    degrades to the ooc pipeline; a kernel that fails to lower or
+    compile, VMEM overflow included, propagates."""
+    from repro import engine, obs
+    from repro.data.chunked import BlobChunks
+    from repro.engine import runner
+
+    def fail(plan, reader):
+        raise error
+
+    monkeypatch.setattr(runner, "_run_fused", fail)
+    n = 256
+    reader = BlobChunks(n, 3, chunk_size=64, dim=4, spread=0.8, seed=0)
+    plan = engine.JobPlan(n=n, chunk_size=64, t=8, k=3, sigma=1.0, seed=0,
+                          path="auto", memory_budget=64 * 1024,
+                          lanczos_steps=48, kmeans_rounds=10)
+    assert engine.plan.route_path(plan, 4) == "fused"
+    before = obs.counter("engine.path_fallbacks").value
+    if reroutes:
+        res = engine.run_job(plan, reader)
+        assert res.stats["path"] == "ooc"
+        assert res.stats["path_fallback"].startswith("fused->ooc")
+        assert obs.counter("engine.path_fallbacks").value == before + 1
+    else:
+        with pytest.raises(type(error)):
+            engine.run_job(plan, reader)
+        assert obs.counter("engine.path_fallbacks").value == before
 
 
 def test_shard_prefetch_hits_and_stats(tmp_path):

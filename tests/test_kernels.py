@@ -68,6 +68,17 @@ def test_block_kernels_interpret_autodetect():
                                np.asarray(ref.block_matmat(A, V)), atol=1e-4)
 
 
+@pytest.mark.parametrize("dtype,precision", [
+    (jnp.float32, jax.lax.Precision.HIGHEST),
+    (jnp.bfloat16, jax.lax.Precision.DEFAULT)])
+def test_mxu_precision_keeps_float32_products_float32(dtype, precision):
+    """Kernels ask Mosaic for float32 products on float32 operands (its
+    default is one bfloat16 pass) and for the default on bfloat16, the
+    only precision Mosaic takes for those."""
+    from repro.precision import mxu_precision
+    assert mxu_precision(dtype) == precision
+
+
 @pytest.mark.parametrize("n,d,k", [(512, 8, 7), (513, 16, 3), (1000, 4, 11),
                                    (64, 32, 2)])
 def test_kmeans_assign(n, d, k):

@@ -125,6 +125,19 @@ def test_kmeans_assign_has_no_bn():
             Schedule(bm=512, bn=64, interpret=True))
 
 
+def test_resolve_counts_interpret_mode():
+    """Every resolution is counted by kernel and interpret mode, so a chip
+    run can assert that no kernel fell back to the interpreter."""
+    from repro import obs
+    key = "tune.resolved{interpret=%s,kernel=block_matmat}"
+    before = {m: obs.counter(key % m).value for m in (True, False)}
+    resolve("block_matmat", None, interpret=True, n=256, m=512, b=8)
+    resolve("block_matmat", None, interpret=False, n=256, m=512, b=8)
+    resolve("block_matmat", None, interpret=False, n=256, m=512, b=8)
+    assert obs.counter(key % True).value - before[True] == 1
+    assert obs.counter(key % False).value - before[False] == 2
+
+
 # ---------------------------------------------------------------------------
 # Schedule-aware entry points: default equivalence
 
