@@ -41,9 +41,6 @@ Prints ``name,us_per_call,derived`` CSV rows.
                     m queries vs an n=8192 model, save/load round-trip
                     bitwise predict parity, and the batched predict
                     service's throughput.  Writes BENCH_serve.json.
-  obs_overhead      the observability tax: the fused fit path with the
-                    obs layer on vs off (best-of-3 each), asserting the
-                    <= 2% overhead contract.  Writes BENCH_obs.json.
   chaos_sweep       fault-tolerance acceptance: injected task failures,
                     spill corruption and stragglers at n=4096 must
                     recover to labels bitwise-equal to the fault-free
@@ -1004,66 +1001,6 @@ def tune_sweep(ns=(1024, 4096), quick: bool = False,
     print(f"# wrote {out_json} (cache: {cache.path})")
 
 
-def obs_overhead(n: int = 4096, k: int = 8, iters: int = 3,
-                 out_json: str = "BENCH_obs.json"):
-    """The observability tax (ISSUE 7 acceptance): the fused fit path at
-    n=4096 with the obs layer ON vs OFF (``obs.set_enabled(False)`` turns
-    spans and stat absorption into no-ops).  Each config takes the best of
-    ``iters`` full fits (fits retrace, so min-of-k beats the noise), and
-    the gate is overhead <= 2% of the disabled-path wall.
-    """
-    from repro import obs
-
-    pts, _ = synthetic.blobs(n, k, dim=8, spread=0.6, seed=0)
-    x = jnp.asarray(pts)
-
-    def one_fit():
-        est = SpectralClustering(k=k, affinity="fused-rbf",
-                                 eigensolver="block-lanczos", block_size=8,
-                                 sigma=1.0, seed=0, lanczos_steps=64)
-        t0 = time.perf_counter()
-        est.fit(x)
-        return time.perf_counter() - t0, est
-
-    def best_of(iters):
-        walls = []
-        for _ in range(iters):
-            wall, est = one_fit()
-            walls.append(wall)
-        return min(walls), walls, est
-
-    one_fit()                                        # shared warmup
-    obs.set_enabled(False)
-    try:
-        off_s, off_walls, _ = best_of(iters)
-    finally:
-        obs.set_enabled(True)
-    obs.reset()
-    on_s, on_walls, est = best_of(iters)
-
-    overhead = on_s / off_s - 1.0
-    o = est.info_["obs"]
-    results = {
-        "n": n, "k": k, "affinity": "fused-rbf", "iters": iters,
-        "enabled_wall_s": round(on_s, 4),
-        "disabled_wall_s": round(off_s, 4),
-        "enabled_walls_s": [round(w, 4) for w in on_walls],
-        "disabled_walls_s": [round(w, 4) for w in off_walls],
-        "overhead_frac": round(overhead, 5),
-        "coverage": o["coverage"],
-        "spans_recorded": len(obs.spans()),
-        "phases": o["phases"],
-    }
-    row("obs_overhead/fused_fit", on_s * 1e6,
-        f"disabled={off_s * 1e6:.0f}us overhead={overhead:+.2%} "
-        f"coverage={o['coverage']:.0%}")
-    assert o["coverage"] >= 0.95, o
-    assert overhead <= 0.02, f"obs overhead {overhead:.2%} > 2%"
-    with open(out_json, "w") as f:
-        json.dump(results, f, indent=2)
-    print(f"# wrote {out_json}")
-
-
 MODES = {
     "table1_phases": table1_phases,
     "fig5_speedup": fig5_speedup,
@@ -1077,7 +1014,6 @@ MODES = {
     "async_sweep": async_sweep,
     "serve_sweep": serve_sweep,
     "tune_sweep": tune_sweep,
-    "obs_overhead": obs_overhead,
     "chaos_sweep": chaos_sweep,
 }
 

@@ -1,0 +1,11 @@
+"""Mean seconds per window job of the program's graph-input spans:
+``job.parse`` + ``job.adjacency`` + ``job.to_device``."""
+from perfbench import program_spans
+
+PHASES = ("job.parse", "job.adjacency", "job.to_device")
+
+
+def read(ctx):
+    return program_spans.mean(
+        sum(secs[p] for p in PHASES) if all(p in secs for p in PHASES)
+        else None for _, secs in program_spans.window_jobs(ctx))

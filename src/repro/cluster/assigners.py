@@ -10,7 +10,10 @@ mesh and still in the affinity backend's row order; ``labels_pad`` must
 match that order (the estimator unpermutes).
 
 Backends:
-  lloyd      full distributed Lloyd (paper §4.3.3 MapReduce rounds).
+  lloyd      full distributed Lloyd (paper §4.3.3 MapReduce rounds), in
+             the spans ``fit.assign.seed`` (k-means++ seeding) and
+             ``fit.assign.lloyd`` (the rounds), each ending when its
+             device work has.
   minibatch  Sculley-style mini-batch Lloyd — O(batch) per round instead
              of O(n); the large-n assigner.
   streaming  the engine's chunked mini-batch Lloyd: consumes embedding
@@ -19,21 +22,28 @@ Backends:
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.cluster.registry import Registry
 from repro.core import kmeans as km
+from repro.core.seeding import kmeans_plusplus_init
 
 ASSIGNERS = Registry("assigner")
 
 
 @ASSIGNERS.register("lloyd")
 def lloyd_assigner(est, Y, valid, key, mesh):
-    # seeding happens inside distributed_kmeans via the one shared
-    # D^2 sampler, core.seeding.kmeans_plusplus_init
-    labels_pad, state = km.distributed_kmeans(
-        Y, valid, est.k, key, mesh, iters=est.kmeans_iters)
+    # the seeding distributed_kmeans would do itself, with the same key
+    with obs.span("fit.assign.seed"):
+        centers0 = jax.block_until_ready(kmeans_plusplus_init(
+            jnp.asarray(Y), est.k, key, weights=valid))
+    with obs.span("fit.assign.lloyd"):
+        labels_pad, state = jax.block_until_ready(km.distributed_kmeans(
+            Y, valid, est.k, key, mesh, iters=est.kmeans_iters,
+            centers0=centers0))
     return labels_pad, state.centers
 
 

@@ -10,7 +10,10 @@ matching (n_pad, k) eigenvector columns (unit norm), still in the
 operator's (possibly permuted) row order.  Every backend reports
 ``info["matrix_passes"]`` — full sweeps over the similarity matrix
 (one ``matmat`` of any width = one pass), the distributed cost unit of
-the paper's §4.3 hot spot.
+the paper's §4.3 hot spot.  The Lanczos backends split their time into
+the spans ``fit.eigensolve.krylov`` (the recurrence) and
+``fit.eigensolve.ritz`` (the tridiagonal eigenproblem and top-k), each
+ending when its device work has.
 
 Backends:
   lanczos        shifted single-vector Lanczos with full
@@ -29,8 +32,10 @@ Backends:
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.cluster.registry import Registry
 from repro.core import chebdav as cd, lanczos as lz
 
@@ -42,9 +47,13 @@ _SHIFT = 2.0  # A = shift*I - L_sym; see core.laplacian docstring
 @EIGENSOLVERS.register("lanczos")
 def lanczos_solver(est, op, key):
     steps = est.num_lanczos_steps(op.n)
-    state = lz.lanczos(op.matvec, op.n_pad, steps, key, dtype=est.dtype,
-                       host_matmat=getattr(op, "host_matmat", None))
-    evals, Z = lz.topk_of_shifted(state, est.k, shift=_SHIFT)
+    with obs.span("fit.eigensolve.krylov"):
+        state = jax.block_until_ready(lz.lanczos(
+            op.matvec, op.n_pad, steps, key, dtype=est.dtype,
+            host_matmat=getattr(op, "host_matmat", None)))
+    with obs.span("fit.eigensolve.ritz"):
+        evals, Z = jax.block_until_ready(
+            lz.topk_of_shifted(state, est.k, shift=_SHIFT))
     return evals, Z, {"lanczos_steps": steps, "matrix_passes": steps}
 
 
@@ -52,10 +61,13 @@ def lanczos_solver(est, op, key):
 def block_lanczos_solver(est, op, key):
     b = est.num_block_size(op.n)       # same n as the step count below,
     steps = est.num_block_steps(op.n)  # so width and steps stay consistent
-    state = lz.block_lanczos(op.matmat, op.n_pad, steps, key,
-                             block_size=b, dtype=est.dtype,
-                             host_matmat=getattr(op, "host_matmat", None))
-    evals, Z = lz.block_topk_of_shifted(state, est.k, shift=_SHIFT)
+    with obs.span("fit.eigensolve.krylov"):
+        state = jax.block_until_ready(lz.block_lanczos(
+            op.matmat, op.n_pad, steps, key, block_size=b, dtype=est.dtype,
+            host_matmat=getattr(op, "host_matmat", None)))
+    with obs.span("fit.eigensolve.ritz"):
+        evals, Z = jax.block_until_ready(
+            lz.block_topk_of_shifted(state, est.k, shift=_SHIFT))
     return evals, Z, {"block_size": b, "block_steps": steps,
                       "matrix_passes": steps}
 

@@ -238,6 +238,7 @@ class SpectralClustering:
                 sigma = jnp.asarray(self.sigma, self.dtype) \
                     if self.sigma is not None else sim.median_sigma(x)
                 op = self._affinity_fn(self, x, sigma, mesh)
+                jax.block_until_ready((op.inv_sqrt, op.valid))
             phases["affinity"] = sp_aff
             if checkpointer is not None:
                 checkpointer.save_phase("similarity", {"sigma": sigma})
@@ -260,6 +261,7 @@ class SpectralClustering:
                 key = jax.random.PRNGKey(self.seed)
                 _k_eig, k_lan, k_km = jax.random.split(key, 3)
                 op = AFFINITIES.get("precomputed")(self, S, None, mesh)
+                jax.block_until_ready((op.inv_sqrt, op.valid))
             phases["affinity"] = sp_aff
             self._finish(op, jnp.asarray(0.0, self.dtype), k_lan, k_km,
                          mesh, checkpointer, train_x=None,
@@ -357,7 +359,6 @@ class SpectralClustering:
             counters.setdefault(k, v)
         self.info_["obs"] = obs.fit_obs(fit_span, phases, counters=counters)
         obs.absorb_stats("fit", counters)
-        obs.gauge("fit.coverage").set(self.info_["obs"]["coverage"])
 
     # -- out-of-sample extension ----------------------------------------------
 

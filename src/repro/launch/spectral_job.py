@@ -142,21 +142,29 @@ def main(argv=None):
         mesh=mesh)
 
     t0 = time.perf_counter()
-    if args.graph:
-        n, edges = graph_file.parse_topology(args.graph)
-        S = graph_file.adjacency_dense(n, edges)
-        est.fit_affinity(jnp.asarray(S), checkpointer=mgr)
-        truth = None
-    else:
-        if args.rings:
-            pts, truth = synthetic.rings(args.rings, args.k)
+    # the job from input to labels on the host; the estimator's ``fit``
+    # span nests under it, beside the input phases
+    with obs.span("job"):
+        if args.graph:
+            with obs.span("job.parse"):
+                n, edges = graph_file.parse_topology(args.graph)
+            with obs.span("job.adjacency"):
+                S = graph_file.adjacency_dense(n, edges)
+            with obs.span("job.to_device"):
+                S = jax.block_until_ready(jnp.asarray(S))
+            est.fit_affinity(S, checkpointer=mgr)
+            truth = None
         else:
-            n = args.blobs or 600
-            pts, truth = synthetic.blobs(n, args.k)
-        est.fit(jnp.asarray(pts), checkpointer=mgr)
+            with obs.span("job.data"):
+                if args.rings:
+                    pts, truth = synthetic.rings(args.rings, args.k)
+                else:
+                    pts, truth = synthetic.blobs(args.blobs or 600, args.k)
+                x = jax.block_until_ready(jnp.asarray(pts))
+            est.fit(x, checkpointer=mgr)
+        labels = np.asarray(est.labels_)
     dt = time.perf_counter() - t0
 
-    labels = np.asarray(est.labels_)
     sizes = np.bincount(labels, minlength=args.k)
     print(f"[spectral] n={len(labels)} k={args.k} "
           f"affinity={est.info_['affinity']} eigensolver={est.eigensolver} "

@@ -14,19 +14,25 @@ autotuner).  See API.md "Observability".
     obs.export_trace("trace.json")              # chrome://tracing
     obs.metrics.to_json("metrics.json")
 
-``obs.set_enabled(False)`` turns both spans and stat absorption into
-no-ops (the overhead benchmark's baseline).
+JAX's trace, lower and compile events are charged to the spans open when
+they happen (``repro.obs.jit``): ``jit.*{span=...}`` counters, and
+``jit_s`` / ``jit_programs`` attrs on each open span.
+
+``obs.set_enabled(False)`` turns spans, compile accounting and stat
+absorption into no-ops.
 """
 from __future__ import annotations
 
+from repro.obs import jit as _jit
 from repro.obs.metrics import (DEFAULT_BUCKETS_MS, Counter, Gauge, Histogram,
                                MetricsRegistry, nearest_rank)
 from repro.obs.report import fit_obs, phase_summary, write_artifacts
-from repro.obs.trace import Span, Tracer
+from repro.obs.trace import SPAN_RING, Span, Tracer
 
 # the process-wide instances every subsystem shares
-tracer = Tracer()
 metrics = MetricsRegistry()
+tracer = Tracer(on_drop=lambda: metrics.counter("obs.spans_dropped").inc())
+_jit.install(tracer, metrics)
 
 # bound module-level helpers (the common call sites)
 span = tracer.span
@@ -42,8 +48,9 @@ snapshot = metrics.snapshot
 
 
 def set_enabled(on: bool) -> None:
-    """Toggle span recording AND stat absorption process-wide (direct
-    metric objects already held by callers keep working either way)."""
+    """Toggle span recording, compile accounting AND stat absorption
+    process-wide (direct metric objects already held by callers keep
+    working either way)."""
     tracer.enabled = on
     metrics.enabled = on
 
@@ -59,9 +66,10 @@ def reset() -> None:
 
 
 __all__ = [
-    "Counter", "Gauge", "Histogram", "MetricsRegistry", "Span", "Tracer",
-    "DEFAULT_BUCKETS_MS", "absorb_stats", "counter", "current_span",
-    "enabled", "export_trace", "fit_obs", "gauge", "histogram", "metrics",
+    "Counter", "Gauge", "Histogram", "MetricsRegistry", "SPAN_RING", "Span",
+    "Tracer", "DEFAULT_BUCKETS_MS", "absorb_stats", "counter",
+    "current_span", "enabled", "export_trace", "fit_obs", "gauge",
+    "histogram", "metrics",
     "nearest_rank", "phase_summary", "reset", "set_enabled", "snapshot",
     "span", "spans", "traced", "tracer", "write_artifacts",
 ]

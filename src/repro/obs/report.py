@@ -6,9 +6,12 @@ fit publishes (see API.md "Observability")::
 
     {"wall_s": 1.23,
      "coverage": 0.98,                     # phase wall / total wall
-     "phases": {"affinity":   {"wall_s": 0.45, "frac": 0.37},
-                "eigensolve": {"wall_s": 0.61, "frac": 0.50},
-                "assign":     {"wall_s": 0.12, "frac": 0.10}},
+     "phases": {"affinity":   {"wall_s": 0.45, "frac": 0.37,
+                               "jit_s": 0.0, "jit_programs": 0},
+                "eigensolve": {"wall_s": 0.61, "frac": 0.50,
+                               "jit_s": 0.09, "jit_programs": 1},
+                "assign":     {"wall_s": 0.12, "frac": 0.10,
+                               "jit_s": 0.14, "jit_programs": 2}},
      "counters": {"matrix_passes": 17, ...}}
 
 ``phase_summary`` renders that dict as the end-of-run ``[obs]`` line the
@@ -24,14 +27,19 @@ def fit_obs(total_span, phase_spans: Dict[str, Any],
     """Assemble ``info_["obs"]`` from one finished parent span and its
     finished phase spans.  Coverage is the fraction of the parent's wall
     the (non-overlapping) phases account for — the acceptance gate is
-    >= 0.95 on every fit path."""
+    >= 0.95 on every fit path.  ``jit_s`` / ``jit_programs`` are the
+    seconds JAX spent tracing, lowering and compiling or loading inside
+    each phase, and the programs it compiled or loaded there
+    (``repro.obs.jit``)."""
     total = max(total_span.duration_s, 1e-12)
     phases = {}
     covered = 0.0
     for name, sp in phase_spans.items():
         d = sp.duration_s
         covered += d
-        phases[name] = {"wall_s": round(d, 6), "frac": round(d / total, 4)}
+        phases[name] = {"wall_s": round(d, 6), "frac": round(d / total, 4),
+                        "jit_s": round(sp.attrs.get("jit_s", 0.0), 6),
+                        "jit_programs": sp.attrs.get("jit_programs", 0)}
     out: Dict[str, Any] = {
         "wall_s": round(total_span.duration_s, 6),
         "coverage": round(min(covered / total, 1.0), 4),

@@ -13,18 +13,25 @@ property.  A :class:`Span` is one named, timed region::
     def run_map_task(...): ...
 
 Spans nest through a thread-local stack (each thread has its own), use
-monotonic clocks (``time.perf_counter``), and carry arbitrary JSON-able
-attributes.  Finished spans accumulate in a process-wide :class:`Tracer`
-and export as Chrome-trace / Perfetto JSON (``obs.export_trace(path)``)
-viewable at ``chrome://tracing`` or https://ui.perfetto.dev.
+monotonic clocks (``time.perf_counter``), carry arbitrary JSON-able
+attributes and the id of the span that was open around them.  The newest
+:data:`SPAN_RING` finished spans are kept in a process-wide
+:class:`Tracer` and export as Chrome-trace / Perfetto JSON
+(``obs.export_trace(path)``) viewable at ``chrome://tracing`` or
+https://ui.perfetto.dev.
 
 When ``jax.profiler`` is importable, every span also enters a
-``TraceAnnotation`` so the same region names appear inside XLA/perfetto
-device profiles — purely best-effort, the module has NO required
-dependencies beyond the stdlib.
+``TraceAnnotation`` of the same name, so the region appears on the host
+plane of a device profile — purely best-effort, the module has NO
+required dependencies beyond the stdlib.  The export's ``metadata`` holds
+the tracer's epoch on both ``time.perf_counter_ns()`` and
+``time.time_ns()``; a profile's times count from the start of its
+trace, so shift the export onto it by one span that both hold.
 """
 from __future__ import annotations
 
+import collections
+import itertools
 import json
 import os
 import threading
@@ -36,21 +43,32 @@ try:  # optional pass-through into XLA profiles; never required
 except Exception:  # pragma: no cover - jax absent or too old
     _JaxAnnotation = None
 
+# finished spans kept per tracer, oldest dropped first: a long-running
+# process (the predict service spans every step) stays bounded, and a
+# 51 s window of back-to-back graph jobs (about 12 spans a job) fits many
+# times over
+SPAN_RING = 1 << 15
+
 
 class Span:
     """One named, timed region.  ``t0``/``t1`` are perf_counter seconds
-    relative to the owning tracer's epoch; ``t1`` is None while open."""
+    relative to the owning tracer's epoch; ``t1`` is None while open.
+    ``sid`` is unique within the tracer; ``parent`` is the ``sid`` of the
+    span open around this one on its thread (None at top level)."""
 
-    __slots__ = ("name", "attrs", "t0", "t1", "tid", "depth", "_ann")
+    __slots__ = ("name", "attrs", "t0", "t1", "tid", "depth", "sid",
+                 "parent", "_ann")
 
     def __init__(self, name: str, attrs: Dict[str, Any], t0: float,
-                 tid: int, depth: int):
+                 tid: int, depth: int, sid: int, parent: Optional[int]):
         self.name = name
         self.attrs = attrs
         self.t0 = t0
         self.t1: Optional[float] = None
         self.tid = tid
         self.depth = depth
+        self.sid = sid
+        self.parent = parent
         self._ann = None
 
     @property
@@ -70,7 +88,7 @@ class Span:
 
 class _NullSpan:
     """Returned while tracing is disabled: accepts the same calls, records
-    nothing (the <=2% overhead contract of BENCH_obs.json)."""
+    nothing."""
 
     name = ""
     t0 = t1 = 0.0
@@ -115,21 +133,32 @@ class _SpanCtx:
 
 
 class Tracer:
-    """Thread-safe collector of finished spans.
+    """Thread-safe collector of the newest :data:`SPAN_RING` finished
+    spans.
 
     One process-wide instance (``repro.obs.tracer``) backs the module-level
     ``span``/``traced``/``export_trace`` helpers; tests may build private
     tracers.  The epoch is captured at construction (and on ``reset``), so
-    exported timestamps always start near zero.
+    exported timestamps always start near zero.  ``on_drop`` is called
+    once for each finished span the ring pushes out.
     """
 
-    def __init__(self, enabled: bool = True, jax_annotations: bool = True):
+    def __init__(self, enabled: bool = True, jax_annotations: bool = True,
+                 on_drop: Optional[Callable[[], None]] = None):
         self.enabled = enabled
         self.jax_annotations = jax_annotations
+        self.on_drop = on_drop
         self._lock = threading.Lock()
-        self._events: List[Span] = []
+        self._events: "collections.deque[Span]" = collections.deque(
+            maxlen=SPAN_RING)
         self._tls = threading.local()
-        self.epoch = time.perf_counter()
+        self._ids = itertools.count(1)
+        self._set_epoch()
+
+    def _set_epoch(self) -> None:
+        self.epoch_ns = time.perf_counter_ns()
+        self.epoch_time_ns = time.time_ns()
+        self.epoch = self.epoch_ns * 1e-9
 
     # -- span lifecycle -----------------------------------------------------
 
@@ -168,10 +197,16 @@ class Tracer:
         st = self._stack()
         return st[-1] if st else None
 
+    def open_spans(self) -> List[Span]:
+        """The spans open on THIS thread, outermost first (the live stack:
+        read it, never change it)."""
+        return self._stack()
+
     def _push(self, name: str, attrs: Dict[str, Any]) -> Span:
         st = self._stack()
         sp = Span(name, attrs, time.perf_counter() - self.epoch,
-                  threading.get_ident(), len(st))
+                  threading.get_ident(), len(st), next(self._ids),
+                  st[-1].sid if st else None)
         st.append(sp)
         if self.jax_annotations and _JaxAnnotation is not None:
             try:
@@ -197,7 +232,10 @@ class Tracer:
         if st:
             st.pop()
         with self._lock:
+            full = len(self._events) == SPAN_RING
             self._events.append(sp)
+        if full and self.on_drop is not None:
+            self.on_drop()
 
     # -- inspection / export ------------------------------------------------
 
@@ -209,12 +247,14 @@ class Tracer:
     def reset(self) -> None:
         with self._lock:
             self._events.clear()
-        self.epoch = time.perf_counter()
+        self._set_epoch()
 
     def to_chrome_trace(self) -> Dict[str, Any]:
         """The Chrome-trace JSON object: complete ("ph": "X") events with
-        microsecond ``ts``/``dur``, one row per thread.  Nesting is implied
-        by containment on a tid, which the span stack guarantees."""
+        microsecond ``ts``/``dur`` from the epoch, one row per thread.
+        Nesting is containment on a tid, which the span stack guarantees;
+        each event's ``args`` also give ``span_id`` and ``parent_id``.
+        ``metadata`` gives the epoch on the perf_counter and wall clocks."""
         pid = os.getpid()
         events: List[Dict[str, Any]] = []
         tids = {}
@@ -225,10 +265,11 @@ class Tracer:
                   "ts": round(sp.t0 * 1e6, 3),
                   "dur": round(max(sp.duration_s, 0.0) * 1e6, 3),
                   "cat": sp.name.split(".", 1)[0]}
-            if sp.attrs:
-                ev["args"] = {k: v if isinstance(v, (int, float, bool,
-                                                     str, type(None)))
-                              else str(v) for k, v in sp.attrs.items()}
+            args = {k: v if isinstance(v, (int, float, bool, str,
+                                           type(None)))
+                    else str(v) for k, v in sp.attrs.items()}
+            args.update(span_id=sp.sid, parent_id=sp.parent)
+            ev["args"] = args
             events.append(ev)
         events.sort(key=lambda e: e["ts"])
         meta = [{"name": "process_name", "ph": "M", "pid": pid,
@@ -236,7 +277,9 @@ class Tracer:
         meta += [{"name": "thread_name", "ph": "M", "pid": pid, "tid": t,
                   "args": {"name": "main" if t == 0 else f"thread-{t}"}}
                  for t in sorted(tids.values())]
-        return {"traceEvents": meta + events, "displayTimeUnit": "ms"}
+        return {"traceEvents": meta + events, "displayTimeUnit": "ms",
+                "metadata": {"epoch_perf_counter_ns": self.epoch_ns,
+                             "epoch_time_ns": self.epoch_time_ns}}
 
     def export(self, path: str) -> str:
         """Write the Chrome-trace JSON; open it in ``chrome://tracing`` or

@@ -14,13 +14,22 @@ Contracts under test (ISSUE 7):
     info_["obs"] with the three phase keys and coverage >= 0.95;
   * refitting the same estimator does NOT accumulate fused-rbf pass
     counters, and a REUSED operator resets to its post-build baseline;
-  * summarize() reports correct nearest-rank p50/p95/p99 on small n.
+  * summarize() reports correct nearest-rank p50/p95/p99 on small n;
+  * JAX's compiles are charged to the spans open on the calling thread
+    (inclusive attrs, ``jit.*{span=...}`` counters), to no other thread's,
+    and not at all while obs is disabled;
+  * ``spectral_job`` nests its job phases and the estimator's spans;
+  * the export lines up with the profiler's host plane after one shift;
+  * the tracer keeps the newest SPAN_RING spans and counts the dropped.
 """
 from __future__ import annotations
 
+import glob
 import json
 import threading
+import time
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -178,6 +187,138 @@ def test_chrome_trace_schema(tmp_path):
     assert child["ts"] + child["dur"] <= parent["ts"] + parent["dur"] + 1e-3
     assert parent["args"]["n"] == 64
     assert parent["cat"] == "fit"
+    # the span that caused each one, and the epoch on both clocks
+    assert parent["args"]["parent_id"] is None
+    assert child["args"]["parent_id"] == parent["args"]["span_id"]
+    assert doc["metadata"] == {"epoch_perf_counter_ns": tr.epoch_ns,
+                               "epoch_time_ns": tr.epoch_time_ns}
+
+
+def test_export_lines_up_with_the_profilers_host_plane(tmp_path):
+    """Each span's TraceAnnotation twin is on the profile's host plane;
+    shifted by the first span's offset, every start and duration of the
+    export agrees with the profile within 1 ms."""
+    from jax.profiler import ProfileData
+
+    names = ("align.a", "align.a.b", "align.a.c", "align.a.c.d")
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.span(names[0]):
+            with obs.span(names[1]):
+                time.sleep(0.02)
+            with obs.span(names[2]):
+                time.sleep(0.01)
+                with obs.span(names[3]):
+                    time.sleep(0.005)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    host = {}
+    for pl in ProfileData.from_file(path).planes:
+        if pl.name.startswith("/host:"):
+            for ln in pl.lines:
+                for e in ln.events:
+                    if e.name in names:
+                        host[e.name] = (e.start_ns / 1e3, e.duration_ns / 1e3)
+    xs = {e["name"]: e for e in obs.tracer.to_chrome_trace()["traceEvents"]
+          if e["ph"] == "X"}
+    assert set(host) == set(xs) == set(names)
+    shift = host[names[0]][0] - xs[names[0]]["ts"]
+    for n in names:
+        start, dur = host[n]
+        assert abs(start - shift - xs[n]["ts"]) <= 1000.0, n
+        assert abs(dur - xs[n]["dur"]) <= 1000.0, n
+
+
+def test_tracer_keeps_the_newest_spans_and_counts_the_dropped():
+    obs.tracer.jax_annotations = False
+    try:
+        for i in range(obs.SPAN_RING + 3):
+            with obs.span("ring", i=i):
+                pass
+    finally:
+        obs.tracer.jax_annotations = True
+    kept = obs.spans()
+    assert len(kept) == obs.SPAN_RING
+    assert kept[0].attrs["i"] == 3 and kept[-1].attrs["i"] == obs.SPAN_RING + 2
+    assert obs.metrics.get("obs.spans_dropped").value == 3
+
+
+# -- compile accounting -------------------------------------------------------
+
+def test_first_jit_call_is_charged_to_its_spans():
+    def scaled(x):
+        return x * 3.0 + 1.0
+
+    f = jax.jit(scaled)
+    x = jnp.ones(7)
+    with obs.span("outer") as so:
+        with obs.span("outer.inner") as si:
+            f(x).block_until_ready()
+    n, secs = si.attrs["jit_programs"], si.attrs["jit_s"]
+    assert n >= 1 and secs > 0
+    assert "jit(scaled)" in si.attrs["jit_funs"]
+    # inclusive: the parent carries the same amounts
+    assert so.attrs["jit_programs"] == n
+    assert so.attrs["jit_s"] == pytest.approx(secs)
+    assert obs.metrics.get("jit.programs{span=outer.inner}").value == n
+    assert obs.metrics.get("jit.traces{span=outer.inner}").value >= 1
+    assert obs.metrics.get("jit.programs{span=outer}") is None
+    # a cached call compiles nothing
+    with obs.span("again") as sa:
+        f(x).block_until_ready()
+    assert "jit_programs" not in sa.attrs
+    assert obs.metrics.get("jit.programs{span=again}") is None
+
+
+def test_compiles_outside_spans_land_under_span_none():
+    def outside(x):
+        return x - 2.0
+
+    jax.jit(outside)(jnp.ones(5)).block_until_ready()
+    assert obs.metrics.get("jit.programs{span=none}").value >= 1
+    assert obs.metrics.get("jit.compile_s{span=none}").value > 0
+
+
+def test_threads_do_not_charge_each_others_spans():
+    started, done = threading.Event(), threading.Event()
+    seen = {}
+
+    def worker():
+        def in_worker(x):
+            return x * x
+
+        started.wait(timeout=30)
+        with obs.span("worker") as sp:
+            jax.jit(in_worker)(jnp.ones(3)).block_until_ready()
+        seen["worker"] = sp
+        done.set()
+
+    t = threading.Thread(target=worker)
+    t.start()
+    with obs.span("main.waiting") as main:
+        started.set()
+        assert done.wait(timeout=60)
+    t.join(timeout=30)
+    assert not t.is_alive()
+    assert "jit_programs" not in main.attrs
+    assert seen["worker"].attrs["jit_programs"] >= 1
+    assert obs.metrics.get("jit.programs{span=worker}").value >= 1
+    assert obs.metrics.get("jit.programs{span=main.waiting}") is None
+
+
+def test_compile_accounting_off_while_disabled():
+    def quiet(x):
+        return x + 4.0
+
+    obs.set_enabled(False)
+    try:
+        with obs.span("quiet"):
+            jax.jit(quiet)(jnp.ones(2)).block_until_ready()
+        jax.jit(lambda x: x / 3.0)(jnp.ones(2)).block_until_ready()
+    finally:
+        obs.set_enabled(True)
+    assert obs.metrics.snapshot("jit") == {}
 
 
 # -- metrics registry ---------------------------------------------------------
@@ -272,6 +413,56 @@ def test_reused_operator_resets_to_post_build_baseline():
     assert op.stats_snapshot()["matrix_passes"] == base + 1
     op.reset_stats()
     assert op.stats_snapshot()["matrix_passes"] == base
+
+
+def _tree(spans):
+    """{name: span} of one job's spans, and each span's parent span."""
+    by_id = {s.sid: s for s in spans}
+    return ({s.name: s for s in spans},
+            {s.name: by_id[s.parent].name for s in spans
+             if s.parent is not None})
+
+
+@pytest.mark.parametrize("source", ["graph", "points"])
+def test_spectral_job_nests_its_phases(source, tmp_path):
+    from repro.data import graph_file
+    from repro.launch import spectral_job
+
+    if source == "graph":
+        edges, _ = synthetic.synthetic_graph(120, 300, k=3, seed=2)
+        path = str(tmp_path / "topo.txt")
+        graph_file.write_topology(path, 120, edges)
+        argv = ["--graph", path, "--k", "3"]
+        inputs = {"job.parse", "job.adjacency", "job.to_device"}
+        eig = "lanczos"
+    else:
+        argv = ["--blobs", "96", "--k", "3", "--eigensolver",
+                "block-lanczos", "--lanczos-steps", "24"]
+        inputs = {"job.data"}
+        eig = "block-lanczos"
+    est = spectral_job.main(argv)
+    assert est.eigensolver == eig
+    spans, parent = _tree(obs.spans())
+    assert est.info_["obs"]["coverage"] >= 0.95
+    for name in inputs | {"fit"}:
+        assert parent[name] == "job"
+    for name in ("fit.affinity", "fit.eigensolve", "fit.assign"):
+        assert parent[name] == "fit"
+    for name in ("fit.eigensolve.krylov", "fit.eigensolve.ritz"):
+        assert parent[name] == "fit.eigensolve"
+    for name in ("fit.assign.seed", "fit.assign.lloyd"):
+        assert parent[name] == "fit.assign"
+    # children lie inside their parents, on the job's thread
+    for name, p in parent.items():
+        c, q = spans[name], spans[p]
+        assert q.t0 <= c.t0 and c.t1 <= q.t1 and c.tid == q.tid
+    # compiles are charged inclusively up to the job, and per phase
+    job = spans["job"]
+    assert job.attrs["jit_programs"] >= spans["fit"].attrs["jit_programs"] > 0
+    phases = est.info_["obs"]["phases"]
+    assert sum(p["jit_programs"] for p in phases.values()) \
+        <= spans["fit"].attrs["jit_programs"]
+    assert all(p["jit_s"] >= 0.0 for p in phases.values())
 
 
 # -- serving summarize --------------------------------------------------------
