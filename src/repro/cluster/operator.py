@@ -32,6 +32,10 @@ class SpectralResult:
     info: dict = field(default_factory=dict)
 
 
+def _width1_matvec(matmat: Callable, v: jax.Array) -> jax.Array:
+    return matmat(v[:, None])[:, 0]
+
+
 @dataclass
 class NormalizedOperator:
     """Shifted normalized-similarity operator plus its padding/permutation
@@ -45,6 +49,12 @@ class NormalizedOperator:
                matrix pass per column — see API.md's migration note).
     matvec:    (n_pad,) -> (n_pad,) replicated; derived width-1 view of
                ``matmat`` unless the backend supplied its own.
+               A ``matmat`` given as a ``jax.tree_util.Partial`` of a
+               module-level function over arrays (the dense operator of
+               ``core.laplacian.make_dense_operator``) is data, and so is
+               the derived ``matvec``: the Lanczos eigensolvers then reuse
+               one compiled loop across fits of the same shapes.  A plain
+               closure is traced again on every fit.
     valid:     (n_pad,) 1/0 mask — 0 on padding rows.
     inv_sqrt:  (n_pad,) D^{-1/2} of the (padded) similarity; kept so the
                estimator can Nystrom-extend the embedding to new points.
@@ -110,7 +120,11 @@ class NormalizedOperator:
             self.matmat = matmat
         if self.matvec is None:
             mm = self.matmat
-            self.matvec = lambda v: mm(v[:, None])[:, 0]
+            if isinstance(mm, jax.tree_util.Partial):
+                # stays data, so the eigensolver's compiled loop is reused
+                self.matvec = jax.tree_util.Partial(_width1_matvec, mm)
+            else:
+                self.matvec = lambda v: _width1_matvec(mm, v)
 
     def stats_snapshot(self) -> dict:
         return dict(self.stats() if callable(self.stats) else self.stats)

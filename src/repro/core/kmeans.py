@@ -11,6 +11,7 @@ practice; plain random init frequently collapses on spectral embeddings).
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -149,13 +150,18 @@ def distributed_lloyd_step(y_sharded: jax.Array, valid: jax.Array,
                        shift=jnp.linalg.norm(new - state.centers))
 
 
+@partial(jax.jit, static_argnames=("k", "mesh", "iters", "tol"))
 def distributed_kmeans(y_sharded: jax.Array, valid: jax.Array, k: int,
                        key: jax.Array, mesh: Mesh, iters: int = 50,
                        centers0: jax.Array | None = None,
                        tol: float = 1e-6) -> tuple[jax.Array, KMeansState]:
     """Paper §4.3.3 on a device mesh. ``y_sharded`` is (n_pad, dim) row-sharded,
     ``valid`` the padding mask. Runs a fixed ``iters`` rounds with early-exit
-    semantics folded into the state (shift < tol keeps centers fixed)."""
+    semantics folded into the state (shift < tol keeps centers fixed).
+
+    Jitted with ``k``, ``mesh``, ``iters`` and ``tol`` static (a ``Mesh``
+    hashes by value), so every later fit of the same shapes reuses one
+    program: the seeding, the rounds and the final assignment."""
     if centers0 is None:
         # ++-init needs a global view; the embedding (n, k) is small (the
         # paper also keeps centers in a single HBase "center file").

@@ -2,10 +2,12 @@
 (Alg. 4.3).
 
 The mat-vec ``L @ v`` is the distributed hot spot — the caller passes a
-``matvec``/``matmat`` closure (row-sharded symmetric operator from
+``matvec``/``matmat`` operator (row-sharded symmetric operator from
 ``core.similarity`` / ``core.laplacian``), and the recurrence itself runs
 on replicated vectors/blocks, exactly the paper's "move the vector to the
-data" split.
+data" split.  An operator given as a ``jax.tree_util.Partial`` over
+arrays runs through one compiled loop per shape; a closure is traced
+again on every call (:func:`block_run`).
 
 The canonical recurrence is **block** Lanczos: a block-tridiagonal
 three-term recurrence on ``b`` vectors at once, so every eigensolver step
@@ -148,10 +150,44 @@ def _block_step_body(matmat: Callable,
     return _block_step_update(state, W)
 
 
+def _block_steps(matmat: Callable, state: BlockLanczosState,
+                 num_iters: int) -> BlockLanczosState:
+    def body(_, s):
+        return _block_step_body(matmat, s)
+    return lax.fori_loop(0, num_iters, body, state)
+
+
+# The recurrence with the operator as an ARGUMENT: a
+# ``jax.tree_util.Partial`` operator flattens to its arrays, so every
+# operator of the same shapes shares one compiled loop, and the cache keys
+# on shapes alone (never on the operator's arrays, which it must not keep
+# alive).  A plain closure cannot go through here: as a static argument it
+# would key the cache on each fit's new closure and pin its matrix.
+_block_steps_jit = jax.jit(_block_steps, static_argnums=2)
+
+
+def _advance(matmat: Callable, state: BlockLanczosState,
+             num_iters: int) -> BlockLanczosState:
+    """``num_iters`` block steps, synchronized: through the shared jitted
+    loop for a :class:`jax.tree_util.Partial` operator, else an eager
+    ``fori_loop`` traced again on every call."""
+    if isinstance(matmat, jax.tree_util.Partial):
+        out = _block_steps_jit(matmat, state, num_iters)
+    else:
+        out = _block_steps(matmat, state, num_iters)
+    return jax.block_until_ready(out)
+
+
 def block_run(matmat: Callable, state: BlockLanczosState,
               num_iters: int) -> BlockLanczosState:
     """Advance the block recurrence ``num_iters`` block steps — each step
     is ONE matrix pass (one matmat of width b).  Checkpoint-friendly.
+
+    A ``matmat`` given as a :class:`jax.tree_util.Partial` of a
+    module-level function over arrays (what
+    :func:`~repro.core.laplacian.make_dense_operator` returns) runs
+    through one compiled loop per shape that every later fit reuses; a
+    plain closure is traced again on every call.
 
     The returned state is synchronized (``block_until_ready``): ``matmat``
     may embed a host callback, and returning while that computation is
@@ -161,9 +197,7 @@ def block_run(matmat: Callable, state: BlockLanczosState,
     costs nothing.  (Host-streaming operators should prefer
     :func:`block_run_host`, which keeps the matrix pass out of the traced
     computation entirely.)"""
-    def body(_, s):
-        return _block_step_body(matmat, s)
-    return jax.block_until_ready(lax.fori_loop(0, num_iters, body, state))
+    return _advance(matmat, state, num_iters)
 
 
 def _block_step_advance(state: BlockLanczosState, W: jax.Array
@@ -301,18 +335,24 @@ def init_state(n: int, num_steps: int, key: jax.Array,
     )
 
 
+def _width1_matmat(matvec: Callable, V: jax.Array) -> jax.Array:
+    return matvec(V[:, 0])[:, None]
+
+
 def run(matvec: Callable, state: LanczosState, num_iters: int) -> LanczosState:
     """Advance the recurrence ``num_iters`` steps (checkpoint-friendly) —
     the width-1 view of :func:`block_run`, synchronized for the same
-    host-callback reason."""
-    def matmat(V):
-        return matvec(V[:, 0])[:, None]
-
-    def body(_, s):
-        return _block_step_body(matmat, s)
-
-    out = lax.fori_loop(0, num_iters, body, _as_block(state))
-    return _from_block(jax.block_until_ready(out))
+    host-callback reason.  As there, a ``matvec`` given as a
+    :class:`jax.tree_util.Partial` of a module-level function over arrays
+    (``NormalizedOperator.matvec`` of a dense operator) reuses one
+    compiled loop across fits; a plain closure is traced again on every
+    call."""
+    if isinstance(matvec, jax.tree_util.Partial):
+        matmat = jax.tree_util.Partial(_width1_matmat, matvec)
+    else:
+        def matmat(V):
+            return _width1_matmat(matvec, V)
+    return _from_block(_advance(matmat, _as_block(state), num_iters))
 
 
 def lanczos(matvec: Callable, n: int, num_steps: int, key: jax.Array,

@@ -26,6 +26,12 @@ def masked_inv_sqrt(deg: jax.Array) -> jax.Array:
     return jnp.where(deg > 0, 1.0 / jnp.sqrt(jnp.maximum(deg, 1e-12)), 0.0)
 
 
+def _dense_matmat(S: jax.Array, inv_sqrt: jax.Array, valid: jax.Array,
+                  V: jax.Array) -> jax.Array:
+    return valid[:, None] * V + inv_sqrt[:, None] * (
+        matmul(S, inv_sqrt[:, None] * V))
+
+
 def make_dense_operator(S: jax.Array, valid: jax.Array):
     """Shifted normalized operator from a dense padded similarity matrix.
 
@@ -37,15 +43,15 @@ def make_dense_operator(S: jax.Array, valid: jax.Array):
     the block replicated, ``S @ .`` is the one collective) plus D^{-1/2}
     for out-of-sample extension.  The width-1 matvec view is derived by
     :class:`~repro.cluster.operator.NormalizedOperator`.
+
+    ``matmat`` is a :class:`jax.tree_util.Partial` of a module-level
+    function over ``(S, inv_sqrt, valid)``: data, not a closure, so the
+    Lanczos recurrence takes it as an argument and every fit of the same
+    shapes reuses one compiled loop (``core.lanczos.block_run``).
     """
     deg = matmul(S, valid)  # padded cols are zero already
     inv_sqrt = masked_inv_sqrt(deg)
-
-    def matmat(V: jax.Array) -> jax.Array:
-        return valid[:, None] * V + inv_sqrt[:, None] * (
-            matmul(S, inv_sqrt[:, None] * V))
-
-    return matmat, inv_sqrt
+    return jax.tree_util.Partial(_dense_matmat, S, inv_sqrt, valid), inv_sqrt
 
 
 def dense_shifted_matrix(S: jax.Array, valid: jax.Array,

@@ -6,7 +6,7 @@ frequently collapses on spectral embeddings, so every assigner here seeds
 with D^2 sampling.  Two substrate twins of the same algorithm live in
 this module so it is written (and fixed) exactly once per substrate:
 
-  * :func:`kmeans_plusplus_init` — jax, jit-traceable (``lax.fori_loop``),
+  * :func:`kmeans_plusplus_init` — jax, jitted (``lax.fori_loop``),
     used by ``core.kmeans`` (reference/distributed/mini-batch Lloyd) and
     by the registry assigners in ``cluster.assigners``;
   * :func:`kmeans_plusplus_np` — host numpy over a seeded
@@ -19,6 +19,7 @@ center; ``weights`` masks padding rows out of the draw.
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Optional
 
 import jax
@@ -27,9 +28,11 @@ import numpy as np
 from jax import lax
 
 
+@partial(jax.jit, static_argnames=("k",))
 def kmeans_plusplus_init(y: jax.Array, k: int, key: jax.Array,
                          weights: jax.Array | None = None) -> jax.Array:
-    """k-means++ seeding (D^2 sampling), jax substrate."""
+    """k-means++ seeding (D^2 sampling), jax substrate.  Jitted with ``k``
+    static: one program per shape, reused by every later fit."""
     n = y.shape[0]
     w = weights if weights is not None else jnp.ones((n,), y.dtype)
     key, sub = jax.random.split(key)

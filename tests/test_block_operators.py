@@ -9,7 +9,6 @@
 * estimator/CLI: the new backends are selectable end-to-end;
 * seeding: the jax and numpy D^2-sampling twins agree statistically.
 """
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -30,15 +29,30 @@ BACKENDS = ("dense", "triangular", "compact", "precomputed", "knn-topt",
             "ooc-topt", "fused-rbf")
 
 
-@functools.lru_cache(maxsize=None)
+_OPERATORS: dict = {}
+
+
 def _operator(backend: str):
-    pts, _ = synthetic.blobs(42, 3, dim=3, seed=11)
-    x = jnp.asarray(pts)
-    est = SpectralClustering(3, sigma=1.0, sparsify_t=8, chunk_size=16,
-                             seed=0)
-    mesh = mesh_utils.local_mesh("rows")
-    arg = sim.dense_similarity(x, 1.0) if backend == "precomputed" else x
-    return AFFINITIES.get(backend)(est, arg, jnp.asarray(1.0), mesh)
+    if backend not in _OPERATORS:
+        pts, _ = synthetic.blobs(42, 3, dim=3, seed=11)
+        x = jnp.asarray(pts)
+        est = SpectralClustering(3, sigma=1.0, sparsify_t=8, chunk_size=16,
+                                 seed=0)
+        mesh = mesh_utils.local_mesh("rows")
+        arg = sim.dense_similarity(x, 1.0) if backend == "precomputed" else x
+        _OPERATORS[backend] = AFFINITIES.get(backend)(
+            est, arg, jnp.asarray(1.0), mesh)
+    return _OPERATORS[backend]
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _close_cached_operators():
+    """The cached ``ooc-topt`` operator owns a shard-prefetch pool: close
+    it when the module ends, so no worker thread outlives these tests."""
+    yield
+    for op in _OPERATORS.values():
+        if op.close is not None:
+            op.close()
 
 
 @settings(max_examples=12, deadline=None)

@@ -465,6 +465,32 @@ def test_spectral_job_nests_its_phases(source, tmp_path):
     assert all(p["jit_s"] >= 0.0 for p in phases.values())
 
 
+def test_second_graph_job_compiles_nothing(tmp_path):
+    """The graph job's three loops (the Lanczos recurrence, k-means++ and
+    Lloyd) compile once per process: a second job of the same shapes
+    traces, lowers and loads no program, and gives the same answers."""
+    from repro.data import graph_file
+    from repro.launch import spectral_job
+
+    edges, _ = synthetic.synthetic_graph(90, 220, k=3, seed=5)
+    path = str(tmp_path / "topo.txt")
+    graph_file.write_topology(path, 90, edges)
+    argv = ["--graph", path, "--k", "3"]
+    first = spectral_job.main(argv)
+    obs.reset()
+    second = spectral_job.main(argv)
+    spans, _ = _tree(obs.spans())
+    for name in ("job", "fit.eigensolve.krylov", "fit.assign.seed",
+                 "fit.assign.lloyd"):
+        assert spans[name].attrs.get("jit_programs", 0) == 0, \
+            (name, spans[name].attrs.get("jit_funs"))
+    for got, want in ((second.eigenvalues_, first.eigenvalues_),
+                      (second._eigvecs, first._eigvecs),
+                      (second.labels_, first.labels_),
+                      (second.centers_, first.centers_)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 # -- serving summarize --------------------------------------------------------
 
 def test_summarize_percentiles_small_n():

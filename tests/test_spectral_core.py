@@ -112,6 +112,82 @@ def test_lanczos_checkpoint_resume_identical():
     np.testing.assert_allclose(np.asarray(full.V), np.asarray(resumed.V), atol=1e-5)
 
 
+def _dense_similarity(n, seed):
+    x, _ = synthetic.blobs(n, 3, spread=0.1, seed=seed)
+    return sim.dense_similarity(jnp.asarray(x), 1.0)
+
+
+@pytest.mark.parametrize("width", [1, 3])
+def test_dense_operators_share_one_compiled_recurrence(width):
+    """Two dense operators over different matrices of one shape advance
+    through ONE compiled loop, and that cache does not keep a dropped
+    operator's matrix alive."""
+    import gc
+    import weakref
+
+    from repro.cluster.operator import NormalizedOperator
+
+    n, steps = 44 + width, 6 + width      # shapes no other test compiles
+    valid = jnp.ones((n,), jnp.float32)
+    key = jax.random.PRNGKey(0)
+
+    def advance(S):
+        mm, inv = lp.make_dense_operator(S, valid)
+        assert isinstance(mm, jax.tree_util.Partial)
+        op = NormalizedOperator(matmat=mm, valid=valid, inv_sqrt=inv, n=n,
+                                n_pad=n, mesh=None)
+        if width == 1:
+            assert isinstance(op.matvec, jax.tree_util.Partial)
+            return lz.run(op.matvec, lz.init_state(n, steps, key), steps)
+        return lz.block_run(op.matmat, lz.init_block_state(
+            n, steps, key, width), steps)
+
+    before = lz._block_steps_jit._cache_size()
+    S1, S2 = _dense_similarity(n, 1), _dense_similarity(n, 2)
+    out1, out2 = advance(S1), advance(S2)
+    assert lz._block_steps_jit._cache_size() == before + 1
+    assert not np.array_equal(np.asarray(out1.V), np.asarray(out2.V))
+
+    ref = weakref.ref(S1)
+    del S1
+    gc.collect()
+    assert ref() is None, "the jit cache kept a dropped operator's matrix"
+
+
+@pytest.mark.parametrize("width", [1, 3])
+def test_closure_operator_keeps_the_eager_recurrence(width):
+    """A plain callable is never handed to the shared jitted loop (its
+    cache does not grow), and gives the state the same operator gives as
+    data."""
+    from repro.cluster.operator import NormalizedOperator
+
+    n, steps = 40 + width, 5 + width
+    S = _dense_similarity(n, 3)
+    valid = jnp.ones((n,), jnp.float32)
+    key = jax.random.PRNGKey(1)
+    mm, inv = lp.make_dense_operator(S, valid)
+
+    before = lz._block_steps_jit._cache_size()
+    if width == 1:
+        state0 = lz.init_state(n, steps, key)
+        eager = lz.run(lambda v: mm(v[:, None])[:, 0], state0, steps)
+        assert lz._block_steps_jit._cache_size() == before
+        op = NormalizedOperator(matmat=mm, valid=valid, inv_sqrt=inv, n=n,
+                                n_pad=n, mesh=None)
+        jitted = lz.run(op.matvec, state0, steps)
+        fields = ("step", "V", "alpha", "beta")
+    else:
+        state0 = lz.init_block_state(n, steps, key, width)
+        eager = lz.block_run(lambda V: mm(V), state0, steps)
+        assert lz._block_steps_jit._cache_size() == before
+        jitted = lz.block_run(mm, state0, steps)
+        fields = ("step", "V", "A", "B")
+    assert lz._block_steps_jit._cache_size() == before + 1
+    for f in fields:
+        np.testing.assert_array_equal(np.asarray(getattr(eager, f)),
+                                      np.asarray(getattr(jitted, f)))
+
+
 # ---------------------------------------------------------------------------
 # k-means
 # ---------------------------------------------------------------------------
