@@ -37,7 +37,10 @@ operator derive the width-1 matvec view (see operator.py).
 """
 from __future__ import annotations
 
+import dataclasses
+import functools
 import math
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -155,9 +158,100 @@ def knn_topt_affinity(est, x, sigma, mesh) -> NormalizedOperator:
     return operator_from_dense(St, n, mesh)
 
 
-def _fused_tile(n: int) -> int:
+def _fused_tile(n: int, d: int = 0, itemsize: int = 4) -> int:
     from repro.kernels.fused_rbf_matmat import default_tile
-    return default_tile(n)
+    return default_tile(n, d, itemsize)
+
+
+class _PassCount:
+    """One fused operator's pass and traffic counters, mirrored into
+    ``fused.passes{width=<b>}``: fed on the host by the degree pass, by
+    each eager ``matmat`` and by the eigensolvers for the passes their
+    compiled loops made (``NormalizedOperator.record_passes``)."""
+
+    def __init__(self, pass_bytes):
+        self.pass_bytes = pass_bytes
+        self.counters = {"matrix_passes": 0, "bytes_streamed": 0}
+
+    def __call__(self, passes: int, width: int) -> None:
+        from repro import obs
+        self.counters["matrix_passes"] += passes
+        self.counters["bytes_streamed"] += passes * self.pass_bytes(width)
+        obs.counter("fused.passes", width=str(width)).inc(passes)
+
+
+@dataclasses.dataclass(frozen=True)
+class _FusedPass:
+    """The static half of the fused operator: tiles, dtypes and mesh.
+
+    It is the function of the operator's ``matmat``, a
+    :class:`jax.tree_util.Partial` over the points, sigma and the scales.
+    Being hashable and compared by value (its pass counter left out), it
+    lets the Lanczos recurrence take the operator as an argument
+    (``core.lanczos.block_run``): every fit of the same shapes and
+    schedule reuses one compiled loop, and the persistent compile cache
+    can serve it, as it holds no host callback.  A pass traced into a
+    compiled program cannot count itself; the program's caller reports
+    it (``NormalizedOperator.record_passes``)."""
+    bm: int
+    bn: int
+    bd: int
+    compute_dtype: Any
+    acc: str
+    interpret: bool
+    mesh: Any
+    count: _PassCount = dataclasses.field(compare=False, repr=False)
+
+    def product(self, xp, sigma, V, row_scale, col_scale):
+        """``diag(row_scale) S diag(col_scale) V`` over the padded points,
+        row-sharded over the mesh."""
+        V = V.astype(jnp.float32)
+        if mesh_utils.mesh_size(self.mesh) == 1:   # the kernel IS the pass
+            return self.kernel(xp, xp, V, sigma, row_scale, col_scale)
+        return _sharded_pass(self, int(V.shape[1]))(
+            xp, row_scale[:, None].astype(jnp.float32), V,
+            col_scale[:, None].astype(jnp.float32), sigma)
+
+    def kernel(self, x, y, V, sigma, row_scale, col_scale):
+        from repro.kernels import fused_rbf_matmat as frm
+        return frm.fused_rbf_matmat(
+            x, y, V, sigma, row_scale, col_scale, bm=self.bm, bn=self.bn,
+            bd=self.bd, compute_dtype=self.compute_dtype, acc=self.acc,
+            interpret=self.interpret)
+
+    def __call__(self, xp, sigma, inv_sqrt, valid, V):
+        SV = self.product(xp, sigma, V, inv_sqrt, inv_sqrt)
+        if not isinstance(V, jax.core.Tracer):      # one eager pass
+            self.count(1, int(V.shape[1]))
+        return valid[:, None] * V + SV.astype(V.dtype)
+
+
+@functools.lru_cache(maxsize=None)
+def _sharded_pass(fp: _FusedPass, width: int):
+    """Row-sharded fused pass for one block width: each device computes
+    its (local, b) output stripe from its point rows vs the all-gathered
+    columns, then one psum assembles the replicated (n_pad, b) block.
+    One jitted pass per (schedule, width), so the shard_map (and the
+    interpret-mode kernel on CPU) traces once, not per call."""
+    axes = mesh_utils.flat_axes(fp.mesh)
+    axis = axes[0] if len(axes) == 1 else axes
+
+    def body(x_local, rs_local, V_full, cs_full, sigma):
+        rows_local = x_local.shape[0]
+        x_full = lax.all_gather(x_local, axis, tiled=True)
+        O_local = fp.kernel(x_local, x_full, V_full, sigma, rs_local[:, 0],
+                            cs_full[:, 0])
+        out = jnp.zeros((x_full.shape[0], width), jnp.float32)
+        out = lax.dynamic_update_slice(
+            out, O_local, (lax.axis_index(axis) * rows_local, 0))
+        return lax.psum(out, axis)
+
+    # check_vma off: the Pallas kernel's outputs carry no varying-axes
+    # annotation for the checker to verify
+    return jax.jit(jax.shard_map(
+        body, mesh=fp.mesh,
+        in_specs=(P(axes, None), P(axes, None), P(), P(), P()),
+        out_specs=P(), check_vma=False))
 
 
 def build_fused_rbf_operator(x, sigma, mesh, *, compute_dtype=None,
@@ -170,7 +264,16 @@ def build_fused_rbf_operator(x, sigma, mesh, *, compute_dtype=None,
     valid rows) and then, per matmat call, the normalized product
     ``D^{-1/2} S D^{-1/2} V`` with both scales applied inside the kernel.
     The (n, n) similarity never exists anywhere — points, scales and the
-    (n_pad, b) block are the whole working set.
+    (n_pad, b) block are the whole working set.  The points stay on the
+    device in their own dtype: bfloat16 rows are not widened (the kernel
+    multiplies them exactly, see ``kernels.fused_rbf_matmat``), anything
+    else is float32.
+
+    Observability: the degree pass runs under the span
+    ``fit.affinity.degree`` (ending when its device work has), every
+    executed pass counts once in ``fused.passes{width=<b>}`` (on the
+    host: see :class:`_FusedPass`), and the gauge ``fused.d_tiles``
+    holds the feature tiles per grid cell.
 
     Exposed directly (besides ``affinity="fused-rbf"``) so the engine's
     planner can route beyond-dense-memory jobs here without an estimator.
@@ -182,128 +285,99 @@ def build_fused_rbf_operator(x, sigma, mesh, *, compute_dtype=None,
     (shape bucket, device) and the chosen schedule + source land in the
     operator's ``stats()`` -> estimator ``info_["engine"]``.
     """
+    from repro import obs
     from repro.kernels import fused_rbf_matmat as frm
-    from repro.tune.schedule import resolve
+    from repro.tune.schedule import resolve, spec
 
     n, d = int(x.shape[0]), int(x.shape[1])
+    rows = frm.row_dtype(x)
     m = mesh_utils.mesh_size(mesh)
     axes = mesh_utils.flat_axes(mesh)
-    axis = axes[0] if len(axes) == 1 else axes
-    tile = _fused_tile(n)
+    tile = _fused_tile(n, d, rows.itemsize)
     sched, sched_src = resolve("fused_rbf_matmat", schedule, bm=tile,
                                bn=tile, compute_dtype=compute_dtype,
-                               n=n, m=n, d=d, b=8)
-    bm, bn = sched.bm, sched.bn
+                               n=n, m=n, d=d, b=8, itemsize=rows.itemsize)
+    bm, bn, bd = sched.bm, sched.bn, sched.bd
     # local row count must divide the row-tile side AND the mesh; padding
     # also covers the column tile (x serves as both sides of the kernel)
     lcm = bm * bn // math.gcd(bm, bn)
     n_pad = mesh_utils.pad_to_multiple(n, m * lcm)
-    rows_local = n_pad // m
-    xp = jnp.zeros((n_pad, d), jnp.float32).at[:n].set(
-        jnp.asarray(x, jnp.float32))
+    d_pad = frm.padded_width(d, bd)
+    xp = jnp.asarray(x, rows)
+    if (n_pad, d_pad) != (n, d):    # zero rows and feature columns
+        xp = jnp.zeros((n_pad, d_pad), rows).at[:n, :d].set(xp)
     if m > 1:   # place each device's point rows once, not on every pass
         xp = jax.device_put(xp, NamedSharding(mesh, P(axes, None)))
     valid = (jnp.arange(n_pad) < n).astype(dtype)
     sigma32 = jnp.asarray(sigma, jnp.float32)
-    cdtype = frm.resolve_compute_dtype(sched.compute_dtype or compute_dtype)
-
-    def _sharded_pass(width: int):
-        """Row-sharded fused pass for one block width: each device
-        computes its (local, b) output stripe from its point rows vs the
-        all-gathered columns, then one psum assembles the replicated
-        (n_pad, b) block."""
-
-        def body(x_local, rs_local, V_full, cs_full):
-            x_full = lax.all_gather(x_local, axis, tiled=True)
-            O_local = frm.fused_rbf_matmat(
-                x_local, x_full, V_full, sigma32, rs_local[:, 0],
-                cs_full[:, 0], bm=bm, bn=bn, compute_dtype=cdtype,
-                acc=sched.acc, interpret=sched.interpret)
-            out = jnp.zeros((n_pad, width), jnp.float32)
-            out = lax.dynamic_update_slice(
-                out, O_local, (lax.axis_index(axis) * rows_local, 0))
-            return lax.psum(out, axis)
-
-        # check_vma off: the Pallas kernel's outputs carry no
-        # varying-axes annotation for the checker to verify
-        return jax.jit(jax.shard_map(
-            body, mesh=mesh,
-            in_specs=(P(axes, None), P(axes, None), P(), P()),
-            out_specs=P(), check_vma=False))
-
-    # the eigensolvers call matmat at a handful of widths, each possibly
-    # hundreds of times — cache one jitted pass per width so the shard_map
-    # (and the interpret-mode kernel on CPU) traces once, not per call
-    _passes: dict = {}
-
-    def fused(V, row_scale, col_scale):
-        if m == 1:  # no collective needed: the kernel IS the whole pass
-            return frm.fused_rbf_matmat(
-                xp, xp, V.astype(jnp.float32), sigma32, row_scale,
-                col_scale, bm=bm, bn=bn, compute_dtype=cdtype,
-                acc=sched.acc, interpret=sched.interpret)
-        width = int(V.shape[1])
-        fn = _passes.get(width)
-        if fn is None:
-            fn = _passes.setdefault(width, _sharded_pass(width))
-        return fn(xp, row_scale[:, None].astype(jnp.float32),
-                  V.astype(jnp.float32),
-                  col_scale[:, None].astype(jnp.float32))
-
-    # pass 1: degrees = S @ 1 with padding masked on both sides
-    deg = fused(jnp.ones((n_pad, 1), jnp.float32), valid, valid)[:, 0]
-    inv_sqrt = lp.masked_inv_sqrt(deg).astype(dtype)
-
     # live HBM-traffic accounting (the dense paths stream n_pad^2 floats
     # per pass; the fused path streams point tiles instead)
-    counters = {"matrix_passes": 1,
-                "bytes_streamed": frm.pass_bytes(n_pad, n_pad, d, 1,
-                                                 bm=bm, bn=bn)}
+    count = _PassCount(lambda width: frm.pass_bytes(
+        n_pad, n_pad, d_pad, width, bm=bm, bn=bn, bd=bd,
+        itemsize=rows.itemsize))
+    fp = _FusedPass(bm=bm, bn=bn, bd=bd, acc=sched.acc,
+                    compute_dtype=jnp.dtype(frm.resolve_compute_dtype(
+                        sched.compute_dtype or compute_dtype)),
+                    interpret=bool(sched.interpret), mesh=mesh, count=count)
 
-    def _bump(width) -> None:
-        counters["matrix_passes"] += 1
-        counters["bytes_streamed"] += frm.pass_bytes(
-            n_pad, n_pad, d, int(width), bm=bm, bn=bn)
+    obs.gauge("fused.d_tiles").set(d_pad // bd)
+    # pass 1: degrees = S @ 1 with padding masked on both sides
+    with obs.span("fit.affinity.degree"):
+        deg = fp.product(xp, sigma32, jnp.ones((n_pad, 1), jnp.float32),
+                         valid, valid)[:, 0]
+        inv_sqrt = jax.block_until_ready(
+            lp.masked_inv_sqrt(deg).astype(dtype))
+    count(1, 1)
 
-    def matmat(V: jax.Array) -> jax.Array:
-        SV = fused(V.astype(jnp.float32), inv_sqrt, inv_sqrt)
-        # debug.callback fires once per *execution* (also inside scans),
-        # so the counters stay honest under jitted eigensolver loops
-        jax.debug.callback(_bump, V.shape[1])
-        return valid[:, None] * V + SV.astype(V.dtype)
+    matmat = jax.tree_util.Partial(fp, xp, sigma32, inv_sqrt, valid)
 
     def dense() -> jax.Array:
         # oracle/eigh-only escape hatch: the one place the matrix exists
         from repro.core import similarity as sim_mod
-        S = sim_mod.rbf_kernel(xp, xp, sigma32) \
+        xf = xp.astype(jnp.float32)
+        S = sim_mod.rbf_kernel(xf, xf, sigma32) \
             * valid[:, None] * valid[None, :]
         return lp.dense_shifted_matrix(jnp.asarray(S, dtype), valid,
                                        inv_sqrt)
 
     # O(n*d) affinity working set vs the dense paths' O(n^2) matrix
-    peak = (n_pad * d + 3 * n_pad) * 4 \
-        + ((bm + bn) * d + bm * bn + bm + bn) * 4  # + VMEM tiles
+    peak = n_pad * d_pad * rows.itemsize + 3 * n_pad * 4 \
+        + spec("fused_rbf_matmat").vmem_model(          # + VMEM tiles
+            sched, n=n_pad, m=n_pad, d=d_pad, b=1, itemsize=rows.itemsize)
 
     def stats():
-        # flush pending debug callbacks so the pass counters are
-        # read-consistent
-        jax.effects_barrier()
-        return dict(counters, affinity_peak_bytes=peak,
+        return dict(count.counters, affinity_peak_bytes=peak,
                     dense_equiv_bytes=n_pad * n_pad * 4,
-                    compute_dtype=jnp.dtype(cdtype).name, tile=bm,
+                    compute_dtype=fp.compute_dtype.name,
+                    row_dtype=rows.name, tile=bm,
                     schedule=sched.to_dict(), schedule_source=sched_src)
 
-    baseline = dict(counters)        # post-build state: the degree pass
+    baseline = dict(count.counters)  # post-build state: the degree pass
 
     def reset():
         # restore the post-build baseline so a reused operator reports
         # per-fit passes instead of accumulating across eigensolves
-        jax.effects_barrier()        # flush in-flight _bump callbacks
-        counters.update(baseline)
+        count.counters.update(baseline)
 
     return NormalizedOperator(
         matmat=matmat, valid=valid, inv_sqrt=inv_sqrt, n=n, n_pad=n_pad,
-        mesh=mesh, schedule=None, dense=dense, stats=stats, reset=reset)
+        mesh=mesh, schedule=None, dense=dense, stats=stats, reset=reset,
+        count_passes=count)
+
+
+def point_dtype(backend, x_dtype, dtype) -> jnp.dtype:
+    """The dtype an affinity ``backend`` takes points in: ``dtype``,
+    unless the backend carries its own rule as a ``point_dtype(x_dtype,
+    dtype)`` attribute."""
+    rule = getattr(backend, "point_dtype", None)
+    return jnp.dtype(dtype) if rule is None else rule(x_dtype, dtype)
+
+
+def _fused_point_dtype(x_dtype, dtype) -> jnp.dtype:
+    """bfloat16 points stay bfloat16 (the kernel multiplies them
+    exactly, ``kernels.fused_rbf_matmat``); others take ``dtype``."""
+    return jnp.dtype(jnp.bfloat16) if jnp.dtype(x_dtype) == jnp.bfloat16 \
+        else jnp.dtype(dtype)
 
 
 @AFFINITIES.register("fused-rbf")
@@ -320,6 +394,9 @@ def fused_rbf_affinity(est, x, sigma, mesh) -> NormalizedOperator:
     return build_fused_rbf_operator(
         x, sigma, mesh, compute_dtype=getattr(est, "compute_dtype", None),
         dtype=est.dtype, schedule=getattr(est, "schedule", None))
+
+
+fused_rbf_affinity.point_dtype = _fused_point_dtype
 
 
 @AFFINITIES.register("ooc-topt")

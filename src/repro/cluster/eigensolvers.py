@@ -51,6 +51,7 @@ def lanczos_solver(est, op, key):
         state = jax.block_until_ready(lz.lanczos(
             op.matvec, op.n_pad, steps, key, dtype=est.dtype,
             host_matmat=getattr(op, "host_matmat", None)))
+    op.record_passes(steps, 1)
     with obs.span("fit.eigensolve.ritz"):
         evals, Z = jax.block_until_ready(
             lz.topk_of_shifted(state, est.k, shift=_SHIFT))
@@ -65,6 +66,7 @@ def block_lanczos_solver(est, op, key):
         state = jax.block_until_ready(lz.block_lanczos(
             op.matmat, op.n_pad, steps, key, block_size=b, dtype=est.dtype,
             host_matmat=getattr(op, "host_matmat", None)))
+    op.record_passes(steps, b)
     with obs.span("fit.eigensolve.ritz"):
         evals, Z = jax.block_until_ready(
             lz.block_topk_of_shifted(state, est.k, shift=_SHIFT))

@@ -27,7 +27,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import obs
 from repro.cluster import serving
-from repro.cluster.affinity import AFFINITIES
+from repro.cluster.affinity import AFFINITIES, point_dtype
 from repro.cluster.assigners import ASSIGNERS
 from repro.cluster.eigensolvers import EIGENSOLVERS
 from repro.cluster.operator import SpectralResult
@@ -223,7 +223,10 @@ class SpectralClustering:
 
     def fit(self, x: jax.Array, checkpointer: Any = None) -> "SpectralClustering":
         """Cluster points (n, d) — or, with ``affinity="precomputed"``, a
-        similarity matrix (n, n).  Returns ``self``."""
+        similarity matrix (n, n).  Returns ``self``.  Points are cast to
+        the dtype the affinity backend takes them in
+        (``affinity.point_dtype``): ``dtype``, except bfloat16 points
+        under ``affinity="fused-rbf"``, which stay bfloat16."""
         if self.affinity == "precomputed":
             return self.fit_affinity(x, checkpointer=checkpointer)
         mesh = self._mesh()
@@ -232,7 +235,9 @@ class SpectralClustering:
                       eigensolver=self.eigensolver, assigner=self.assigner,
                       n=int(x.shape[0])) as sp_fit:
             with obs.span("fit.affinity", backend=self.affinity) as sp_aff:
-                x = jnp.asarray(x, self.dtype)
+                x = jnp.asarray(x)
+                x = x.astype(point_dtype(self._affinity_fn, x.dtype,
+                                         self.dtype))
                 key = jax.random.PRNGKey(self.seed)
                 _k_eig, k_lan, k_km = jax.random.split(key, 3)
                 sigma = jnp.asarray(self.sigma, self.dtype) \
@@ -446,7 +451,9 @@ class SpectralClustering:
                 "cannot save a model fitted from a precomputed similarity "
                 "matrix; transform/predict would have no training points")
         os.makedirs(directory, exist_ok=True)
-        state = {"train_x": self._train_x, "eigvecs": self._eigvecs,
+        # bf16 points are saved widened (exactly): npz has no bfloat16
+        state = {"train_x": jnp.asarray(self._train_x, jnp.float32),
+                 "eigvecs": self._eigvecs,
                  "inv_sqrt": self._inv_sqrt,
                  "eigenvalues": self.eigenvalues_, "centers": self.centers_,
                  "sigma": self.sigma_, "labels": self.labels_,

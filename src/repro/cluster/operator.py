@@ -81,6 +81,11 @@ class NormalizedOperator:
                background threads outlive it; backends must treat it as
                non-final (a reused operator's next matmat restarts
                whatever close released).
+    count_passes: optional callable ``(passes, width)`` counting the
+               backend's executed passes.  A product traced into a
+               compiled loop runs without Python, so the eigensolvers
+               report the passes their loops made through
+               :meth:`record_passes` (no-op for backends without one).
     host_matmat: optional plain-host (numpy (n_pad, b) -> (n_pad, b))
                view of the SAME product, set by streaming backends whose
                matmat wraps host code in ``pure_callback``.  Eigensolvers
@@ -102,6 +107,7 @@ class NormalizedOperator:
     stats: Any = field(default_factory=dict)
     reset: Optional[Callable[[], None]] = None
     close: Optional[Callable[[], None]] = None
+    count_passes: Optional[Callable[[int, int], None]] = None
     host_matmat: Optional[Callable] = None
 
     def __post_init__(self):
@@ -134,6 +140,12 @@ class NormalizedOperator:
         (no-op for backends without one)."""
         if self.reset is not None:
             self.reset()
+
+    def record_passes(self, passes: int, width: int) -> None:
+        """Count ``passes`` products of ``width`` columns that a compiled
+        loop made (no-op for backends without a counter)."""
+        if self.count_passes is not None:
+            self.count_passes(passes, width)
 
     def unpermute(self, values: jax.Array) -> jax.Array:
         """Per-(padded-)row values -> original point order, padding dropped."""

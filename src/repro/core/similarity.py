@@ -59,8 +59,11 @@ def dense_similarity(x: jax.Array, sigma: float | jax.Array) -> jax.Array:
 
 
 def median_sigma(x: jax.Array, sample: int = 1024) -> jax.Array:
-    """Median-distance heuristic for the RBF bandwidth."""
+    """Median-distance heuristic for the RBF bandwidth (float32 from
+    bfloat16 points)."""
     xs = x[: min(sample, x.shape[0])]
+    if xs.dtype == jnp.bfloat16:
+        xs = xs.astype(jnp.float32)
     d2 = pairwise_sq_dists(xs, xs)
     n = d2.shape[0]
     off = d2[jnp.triu_indices(n, k=1)]

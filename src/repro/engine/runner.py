@@ -514,6 +514,7 @@ def _run_fused(plan: JobPlan, reader) -> JobResult:
                   block_steps=block_steps) as sp_eig:
         state = lz.block_lanczos(op.matmat, op.n_pad, block_steps, k_lan,
                                  block_size=b)
+        op.record_passes(block_steps, b)
         evals, Z = lz.block_topk_of_shifted(state, plan.k)
         jax.block_until_ready(Z)
 

@@ -67,24 +67,32 @@ def fused_rbf_matmat(x: jax.Array, y: jax.Array, V: jax.Array, sigma,
                      ) -> jax.Array:
     """diag(row_scale) @ RBF(x, y; sigma) @ diag(col_scale) @ V for any
     (n, d)/(m, d)/(m, b) — the similarity tile is recomputed in-register,
-    never materialized.  Omitted scales default to ones; padded rows get
-    scale 0 so they contribute nothing."""
+    never materialized.  Rows may be float32 or bfloat16 (kept as they
+    are; see ``kernels.fused_rbf_matmat``).  Omitted scales default to
+    ones; padded rows get scale 0 so they contribute nothing, and padded
+    feature columns are zero."""
     from repro.kernels import fused_rbf_matmat as _frm
-    n, m = x.shape[0], y.shape[0]
+    n, m, d = x.shape[0], y.shape[0], x.shape[1]
+    rows = _frm.row_dtype(x)
     s = _resolve("fused_rbf_matmat", schedule, bm=bm, bn=bn,
                  compute_dtype=compute_dtype, interpret=interpret,
-                 n=n, m=m, d=x.shape[1], b=V.shape[1])
+                 n=n, m=m, d=d, b=V.shape[1], itemsize=rows.itemsize)
     rs = jnp.ones((n,), jnp.float32) if row_scale is None \
         else jnp.asarray(row_scale, jnp.float32)
     cs = jnp.ones((m,), jnp.float32) if col_scale is None \
         else jnp.asarray(col_scale, jnp.float32)
+    d_pad = _frm.padded_width(d, s.bd)
     xp, _ = _pad_rows(x, s.bm)
     yp, _ = _pad_rows(y, s.bn)
+    if d_pad != d:
+        xp = jnp.pad(xp, ((0, 0), (0, d_pad - d)))
+        yp = jnp.pad(yp, ((0, 0), (0, d_pad - d)))
     Vp, _ = _pad_rows(V, s.bn)
     rsp, _ = _pad_rows(rs, s.bm)
     csp, _ = _pad_rows(cs, s.bn)
     out = _frm.fused_rbf_matmat(xp, yp, Vp, sigma, rsp, csp, bm=s.bm,
-                                bn=s.bn, compute_dtype=s.compute_dtype,
+                                bn=s.bn, bd=s.bd,
+                                compute_dtype=s.compute_dtype,
                                 acc=s.acc, interpret=s.interpret)
     return out[:n]
 

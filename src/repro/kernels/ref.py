@@ -15,8 +15,10 @@ def rbf_similarity(x: jax.Array, y: jax.Array, sigma) -> jax.Array:
 
 def fused_rbf_matmat(x: jax.Array, y: jax.Array, V: jax.Array, sigma,
                      row_scale: jax.Array, col_scale: jax.Array) -> jax.Array:
-    """diag(row_scale) @ RBF(x, y) @ diag(col_scale) @ V — materialized."""
-    S = rbf_similarity(x, y, sigma)
+    """diag(row_scale) @ RBF(x, y) @ diag(col_scale) @ V — materialized,
+    in float32 from the rows' own values (bf16 rows are widened exactly)."""
+    f32 = jnp.float32
+    S = rbf_similarity(x.astype(f32), y.astype(f32), sigma)
     return row_scale[:, None] * (S @ (col_scale[:, None] * V))
 
 

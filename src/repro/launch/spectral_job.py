@@ -8,6 +8,9 @@ Each pipeline phase is a registry-selected backend of
     PYTHONPATH=src python -m repro.launch.spectral_job --rings 512 --k 2 \\
         --affinity compact --eigensolver lanczos --assigner minibatch
     PYTHONPATH=src python -m repro.launch.spectral_job --graph topo.txt --k 8
+    PYTHONPATH=src python -m repro.launch.spectral_job --points emb.npy \\
+        --k 50 --affinity fused-rbf --eigensolver block-lanczos \\
+        --block-size 64 --lanczos-steps 320
     PYTHONPATH=src python -m repro.launch.spectral_job --blobs 4096 --k 3 \\
         --engine mapreduce --chunk-size 512 --memory-budget 1048576
 """
@@ -23,7 +26,7 @@ import numpy as np
 from repro import compile_cache, obs
 from repro.checkpoint import CheckpointManager
 from repro.cluster import AFFINITIES, ASSIGNERS, EIGENSOLVERS, SpectralClustering
-from repro.data import graph_file, synthetic
+from repro.data import graph_file, points_file, synthetic
 from repro.distrib import mesh_utils
 
 
@@ -32,6 +35,10 @@ def main(argv=None):
     ap.add_argument("--blobs", type=int, default=0, help="n points in k blobs")
     ap.add_argument("--rings", type=int, default=0, help="n points in k rings")
     ap.add_argument("--graph", default=None, help="paper §5.1 topology file")
+    ap.add_argument("--points", default=None, metavar="PATH.npy",
+                    help="(n, d) rows to cluster: float32, or bfloat16 "
+                         "(ml_dtypes), which --affinity fused-rbf keeps "
+                         "bfloat16 on the device")
     ap.add_argument("--k", type=int, default=3)
     ap.add_argument("--affinity", default="triangular",
                     choices=AFFINITIES.names(),
@@ -104,6 +111,12 @@ def main(argv=None):
     ap.add_argument("--metrics-out", default=None, metavar="FILE.json",
                     help="write the metrics registry snapshot as JSON")
     args = ap.parse_args(argv)
+    if args.graph and args.points:
+        ap.error("--graph and --points are two inputs; give one")
+    if (args.eigensolver in ("lanczos", "block-lanczos")
+            and args.lanczos_steps < args.k):
+        ap.error(f"--lanczos-steps {args.lanczos_steps} is below --k "
+                 f"{args.k}: the Krylov space must hold k Ritz vectors")
     compile_cache.enable()
 
     affinity = args.affinity
@@ -154,6 +167,13 @@ def main(argv=None):
                 S = jax.block_until_ready(jnp.asarray(S))
             est.fit_affinity(S, checkpointer=mgr)
             truth = None
+        elif args.points:
+            with obs.span("job.load"):
+                pts = points_file.load_points(args.points)
+            with obs.span("job.to_device"):
+                x = jax.block_until_ready(jnp.asarray(pts))
+            est.fit(x, checkpointer=mgr)
+            truth = None
         else:
             with obs.span("job.data"):
                 if args.rings:
@@ -200,7 +220,8 @@ def main(argv=None):
     if "affinity_fallback" in est.info_:
         print(f"[engine] fallback: {est.info_['affinity_fallback']}")
     elif eng and "bytes_streamed" in eng:  # the fused matrix-free affinity
-        print(f"[fused] compute_dtype={eng['compute_dtype']} "
+        print(f"[fused] rows={eng['row_dtype']} "
+              f"compute_dtype={eng['compute_dtype']} "
               f"passes={eng['matrix_passes']} "
               f"bytes_streamed={eng['bytes_streamed']} "
               f"peak_affinity_bytes={eng['affinity_peak_bytes']} "

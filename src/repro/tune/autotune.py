@@ -30,6 +30,9 @@ from repro.tune.schedule import Schedule, ScheduleError, spec
 # tile-edge ladder: MXU/lane multiples only (every entry legal compiled)
 TILE_LADDER = (128, 256, 512)
 QUICK_TILES = (128, 256)
+# feature-tile ladder of the kernels with a feature grid axis (tiles
+# narrower than the row only; the default rule's tile is always tried)
+D_TILE_LADDER = (256, 512, 1024)
 
 # the standard sweep set: every schedulable kernel with a nominal shape
 # builder (n is the sweep variable; d/b/k are the repo's workhorse sizes)
@@ -54,7 +57,9 @@ def candidates(kernel: str, *, quick: bool = False,
     """Legal schedule candidates for one kernel/shape (default included,
     always first).  Tiles larger than the padded problem edge are skipped
     (they only add padding work); illegal combinations are filtered by the
-    spec's own legality check."""
+    spec's own legality check.  A kernel with a feature grid axis also
+    tries feature tiles narrower than the row (``D_TILE_LADDER``) beside
+    the default rule's."""
     sp = spec(kernel)
     tiles = QUICK_TILES if quick else TILE_LADDER
     n_cap = bucket(int(shape.get("n", tiles[-1])))
@@ -62,29 +67,39 @@ def candidates(kernel: str, *, quick: bool = False,
     bms = sorted({t for t in tiles if t <= max(n_cap, tiles[0])})
     bns = sorted({t for t in tiles if t <= max(m_cap, tiles[0])}) \
         if sp.has_bn else [None]
+    bds = [None]
+    if sp.has_bd and "d" in shape:
+        from repro.kernels.fused_rbf_matmat import default_d_tile
+        d = int(shape["d"])
+        bds = [default_d_tile(d, int(shape.get("itemsize", 4)))]
+        if not quick:
+            bds += [t for t in D_TILE_LADDER if t < d and t not in bds]
     accs = ("inplace",) if (quick or not sp.reduces) \
         else ("inplace", "scratch")
     orders = ("row-major",) if (sp.reduces or not sp.has_bn or quick) \
         else ("row-major", "col-major")
 
     base = sp.default.replace(
-        compute_dtype=compute_dtype if sp.has_compute_dtype else None,
-        interpret=interpret)
+        bd=bds[0], interpret=interpret,
+        compute_dtype=compute_dtype if sp.has_compute_dtype else None)
     out = [base]
     for bm in bms:
         for bn in bns:
-            for acc in accs:
-                for order in orders:
-                    s = base.replace(bm=bm, bn=bn, acc=acc, grid_order=order)
-                    if s in out:
-                        continue
-                    try:
-                        sp.check(s.replace(
-                            interpret=s.interpret if s.interpret is not None
-                            else True), **shape)
-                    except ScheduleError:
-                        continue
-                    out.append(s)
+            for bd in bds:
+                for acc in accs:
+                    for order in orders:
+                        s = base.replace(bm=bm, bn=bn, bd=bd, acc=acc,
+                                         grid_order=order)
+                        if s in out:
+                            continue
+                        try:
+                            sp.check(s.replace(
+                                interpret=s.interpret
+                                if s.interpret is not None else True),
+                                **shape)
+                        except ScheduleError:
+                            continue
+                        out.append(s)
     return out
 
 
@@ -180,7 +195,8 @@ def autotune(kernel: str, n: int, *, d: int = 8, b: int = 8, k: int = 8,
             if default_us is None:
                 default_us = wall_us        # candidate 0 IS the default
             if log:
-                log(f"tune/{kernel}_n{n}: bm={s.bm} bn={s.bn} acc={s.acc} "
+                log(f"tune/{kernel}_n{n}: bm={s.bm} bn={s.bn} bd={s.bd} "
+                    f"acc={s.acc} "
                     f"order={s.grid_order} -> {wall_us:.0f}us")
         best_i = min(range(len(rows)), key=lambda i: rows[i]["wall_us"])
         best = cands[best_i]

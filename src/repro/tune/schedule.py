@@ -33,9 +33,10 @@ from typing import Any, Callable, Optional
 SUBLANE = 8
 LANE = 128
 
-# Per-grid-cell VMEM working-set ceiling.  Physical VMEM is ~16 MiB/core
-# and the Pallas pipeline double-buffers input tiles, so one cell's tiles
-# must fit in about half of it.
+# Per-grid-step VMEM working-set ceiling: half of Mosaic's default scoped
+# VMEM on a v5e (16 MiB), the other half left to the compiler.  The fused
+# kernels' models count their double-buffered input and output tiles and
+# their scratch; the others count one buffer of each tile.
 VMEM_BYTES = 8 * 1024 * 1024
 
 GRID_ORDERS = ("row-major", "col-major")
@@ -58,6 +59,9 @@ class Schedule:
     what it names.
 
     bm / bn:        row / column (reduction-side) tile edges.
+    bd:             feature tile of the kernels with a feature grid axis
+                    (``fused_rbf_matmat``): None = the default rule
+                    (``kernels.fused_rbf_matmat.default_d_tile``).
     compute_dtype:  MXU product precision ("float32" | "bfloat16") for the
                     kernels that expose it; accumulation stays f32.
     grid_order:     "row-major" (default: last grid dim fastest) or
@@ -72,6 +76,7 @@ class Schedule:
     """
     bm: Optional[int] = None
     bn: Optional[int] = None
+    bd: Optional[int] = None
     compute_dtype: Optional[str] = None
     grid_order: str = "row-major"
     acc: str = "inplace"
@@ -138,8 +143,9 @@ class KernelSpec:
     ``shape_dims`` names the shape keywords the models take (and, prefixed
     subset ``bucket_dims``, the ones that key the schedule cache — batch
     width ``b`` is deliberately NOT bucketed so one tuned schedule serves
-    every matmat width).  All byte models are f32-per-element: the bf16
-    compute_dtype cast happens in-register, after the VMEM load.
+    every matmat width).  Byte models bill points at the rows' own
+    ``itemsize`` (4 unless a model takes it; the bf16 compute_dtype cast
+    happens in-register, after the VMEM load) and everything else at f32.
     """
     name: str
     default: "Schedule"
@@ -148,6 +154,7 @@ class KernelSpec:
     reduces: bool                     # output revisited across grid dim 1
     has_bn: bool = True
     has_compute_dtype: bool = False
+    has_bd: bool = False              # a feature (d) grid axis
     # models: fn(schedule, **shape) -> bytes / flops
     vmem_model: Optional[Callable[..., int]] = None
     flops_model: Optional[Callable[..., int]] = None
@@ -168,6 +175,14 @@ class KernelSpec:
         elif s.bn is not None and s.bn != self.default.bn:
             raise ScheduleError(f"{self.name} has no bn tile (1-D grid); "
                                 f"got bn={s.bn}")
+        if s.bd is not None:
+            if not self.has_bd:
+                raise ScheduleError(f"{self.name} has no feature tile bd "
+                                    f"(the whole row is one tile); got "
+                                    f"bd={s.bd}")
+            if s.bd != shape.get("d"):      # a whole row is always legal
+                _check_tile("bd", s.bd, lane=True, interpret=interp,
+                            kernel=self.name)
         if s.grid_order not in GRID_ORDERS:
             raise ScheduleError(f"{self.name}: grid_order must be one of "
                                 f"{GRID_ORDERS}, got {s.grid_order!r}")
@@ -193,8 +208,9 @@ class KernelSpec:
             need = self.vmem_model(s, **shape)
             if need > VMEM_BYTES:
                 raise ScheduleError(
-                    f"{self.name}: schedule bm={s.bm} bn={s.bn} needs "
-                    f"{need} bytes of VMEM per grid cell at shape {shape}, "
+                    f"{self.name}: schedule bm={s.bm} bn={s.bn} bd={s.bd} "
+                    f"needs {need} bytes of VMEM per grid cell at shape "
+                    f"{shape}, "
                     f"over the {VMEM_BYTES} budget (tiles are "
                     f"double-buffered); shrink the tiles")
         return s
@@ -202,7 +218,8 @@ class KernelSpec:
 
 # -- per-kernel VMEM / FLOPs / bytes models ---------------------------------
 # Shapes use the kernels' own letters: n/m point counts, d feature dim,
-# b block width, k centers.  f32 = 4 bytes everywhere (see KernelSpec).
+# b block width, k centers, itemsize the bytes of one point coordinate.
+# f32 = 4 bytes for everything else (see KernelSpec).
 
 def _rbf_vmem(s, *, n, m, d):
     return (s.bm * d + s.bn * d + s.bm * s.bn) * 4
@@ -217,19 +234,31 @@ def _rbf_bytes(s, *, n, m, d):
     return cells * (s.bm + s.bn) * d * 4 + n * m * 4
 
 
-def _fused_vmem(s, *, n, m, d, b=8):
+def _fused_d_tile(s, d):
+    """The feature tile a fused schedule runs: ``s.bd``, else the whole
+    row (the Nystrom twin, and a fit schedule before resolution)."""
+    return d if s.bd is None else min(s.bd, d)
+
+
+def _fused_vmem(s, *, n, m, d, b=8, itemsize=4):
+    bd = _fused_d_tile(s, d)
     acc = s.bm * b if s.acc == "scratch" else 0
-    return (s.bm * d + s.bn * d + s.bn * b + s.bm * s.bn
-            + s.bm * b + s.bm + s.bn + acc) * 4
+    tiles_in = (s.bm + s.bn) * bd * itemsize \
+        + (s.bn * b + 2 * s.bm + 2 * s.bn) * 4   # V, scale and norm columns
+    out = s.bm * b * 4
+    gram = s.bm * s.bn * 4 if s.bd is not None else 0   # fit kernel only
+    # double-buffered inputs and output, the Gram scratch, the RBF tile
+    return 2 * (tiles_in + out) + gram + s.bm * s.bn * 4 + acc * 4
 
 
-def _fused_flops(s, *, n, m, d, b=8):
+def _fused_flops(s, *, n, m, d, b=8, itemsize=4):
     return n * m * (2 * d + 4 + 2 * b)
 
 
-def _fused_bytes(s, *, n, m, d, b=8):
+def _fused_bytes(s, *, n, m, d, b=8, itemsize=4):
     from repro.kernels.fused_rbf_matmat import pass_bytes
-    return pass_bytes(n, m, d, b, bm=s.bm, bn=s.bn)
+    return pass_bytes(n, m, d, b, bm=s.bm, bn=s.bn, bd=s.bd,
+                      itemsize=itemsize)
 
 
 def _matmat_vmem(s, *, n, m, b=8):
@@ -277,8 +306,9 @@ _register(KernelSpec(
 _register(KernelSpec(
     name="fused_rbf_matmat",
     default=Schedule(bm=128, bn=128),
-    shape_dims=("n", "m", "d", "b"), bucket_dims=("n", "m", "d"),
-    reduces=True, has_compute_dtype=True,
+    shape_dims=("n", "m", "d", "b", "itemsize"),
+    bucket_dims=("n", "m", "d"),
+    reduces=True, has_compute_dtype=True, has_bd=True,
     vmem_model=_fused_vmem, flops_model=_fused_flops,
     bytes_model=_fused_bytes))
 
@@ -341,8 +371,21 @@ def validate_spec(value: Any) -> Any:
     return value
 
 
+def _default_bd(sp: KernelSpec, bd: Optional[int],
+                shape: dict) -> Optional[int]:
+    if not sp.has_bd:
+        return None
+    if bd is not None:
+        return bd
+    if "d" not in shape:
+        return None
+    from repro.kernels.fused_rbf_matmat import default_d_tile
+    return default_d_tile(int(shape["d"]), int(shape.get("itemsize", 4)))
+
+
 def resolve(kernel: str, schedule: Any = None, *, bm: Optional[int] = None,
-            bn: Optional[int] = None, compute_dtype: Any = None,
+            bn: Optional[int] = None, bd: Optional[int] = None,
+            compute_dtype: Any = None,
             interpret: Optional[bool] = None,
             **shape) -> tuple["Schedule", str]:
     """Turn a user-facing schedule value + call-site keywords into one
@@ -352,7 +395,9 @@ def resolve(kernel: str, schedule: Any = None, *, bm: Optional[int] = None,
     the call-site keywords — the pre-schedule behavior, bit-for-bit),
     "explicit" (caller passed a Schedule/dict), "cache" ("auto" hit the
     persistent cache) or "auto-default" ("auto" missed — the default
-    schedule runs, and the miss is visible in the cache stats).
+    schedule runs, and the miss is visible in the cache stats).  A kernel
+    with a feature tile gets ``bd`` from the call site, else from the
+    default rule for the shape's ``d``.
     """
     sp = spec(kernel)
     if isinstance(compute_dtype, str):
@@ -364,6 +409,7 @@ def resolve(kernel: str, schedule: Any = None, *, bm: Optional[int] = None,
     fallback = Schedule(
         bm=bm if bm is not None else sp.default.bm,
         bn=(bn if bn is not None else sp.default.bn) if sp.has_bn else None,
+        bd=_default_bd(sp, bd, shape),
         compute_dtype=compute_dtype if sp.has_compute_dtype else None,
         interpret=interpret)
 
@@ -392,9 +438,12 @@ def resolve(kernel: str, schedule: Any = None, *, bm: Optional[int] = None,
         bm=s.bm if s.bm is not None else fallback.bm,
         bn=(s.bn if s.bn is not None else fallback.bn) if sp.has_bn
         else s.bn,
+        bd=s.bd if s.bd is not None else fallback.bd,
         compute_dtype=s.compute_dtype if s.compute_dtype is not None
         else fallback.compute_dtype,
         interpret=s.interpret if s.interpret is not None else interpret)
+    if s.bd is not None and s.bd >= shape.get("d", s.bd + 1):
+        s = s.replace(bd=int(shape["d"]))       # one tile: the whole row
     if s.interpret is None:
         from repro.kernels.block_matvec import interpret_default
         s = s.replace(interpret=interpret_default())

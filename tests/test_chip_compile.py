@@ -12,7 +12,9 @@ one process at a time may load the TPU compiler library, and the test
 workers each import this file.
 """
 import functools
+import importlib.util
 import os
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +27,7 @@ from repro.kernels import (
     kmeans_assign,
     rbf_similarity,
 )
-from repro.tune.schedule import KERNELS
+from repro.tune.schedule import KERNELS, VMEM_BYTES, resolve
 
 N = 262_144      # the fused fit's n (configs/spectral_paper.PRODUCTION_N)
 D = 8            # blob width of the fit and serve phases
@@ -33,6 +35,22 @@ B = 8            # block-Lanczos width, and k for the serve embedding
 TILE = 256       # fused_rbf_matmat.default_tile at this n
 QUERIES = 1024   # ClusterServer batch_rows in the serve phase
 CHUNK = 4096     # the out-of-core phase's --chunk-size (one map tile)
+EMBED_N = 65_536  # the embedding cell's rows (mteb-embed-d4096)
+EMBED_D = 4_096   # their width, in bf16
+EMBED_B = 64      # its block-Lanczos width
+
+
+def _trace_patterns():
+    """The benchmark's patterns for the fused kernel's device events
+    (``perfbench/trace_reduce.KERNELS["fused_rbf"]``), read from the file
+    so that this test needs no package path."""
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "perfbench", "trace_reduce.py")
+    spec = importlib.util.spec_from_file_location("perfbench_trace", path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod        # its dataclass looks itself up
+    spec.loader.exec_module(mod)
+    return mod.KERNELS["fused_rbf"]
 
 
 @pytest.fixture(scope="module")
@@ -81,6 +99,27 @@ def test_fused_rbf_matmat_compiles(one_chip, compute_dtype, acc):
     hlo = _compile(lambda x, V, s, r: fn(x, x, V, s, r, r), one_chip,
                    ((N, D), f32), ((N, B), f32), ((), f32), ((N,), f32))
     assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("b", [1, EMBED_B])
+def test_d_tiled_fused_rbf_matmat_compiles_for_embeddings(one_chip, b):
+    """bf16 rows of width 4,096 at the schedule layer's default tiles:
+    the VMEM model admits them, Mosaic compiles them, and the lowered
+    kernel carries a name the benchmark's trace reduction finds."""
+    shape = dict(n=EMBED_N, m=EMBED_N, d=EMBED_D, b=b, itemsize=2)
+    tile = frm.default_tile(EMBED_N, EMBED_D, 2)
+    sched, _ = resolve("fused_rbf_matmat", None, bm=tile, bn=tile,
+                       interpret=False, **shape)
+    assert KERNELS["fused_rbf_matmat"].vmem_model(sched, **shape) \
+        <= VMEM_BYTES
+    fn = functools.partial(frm.fused_rbf_matmat, bm=sched.bm, bn=sched.bn,
+                           bd=sched.bd, acc=sched.acc, interpret=False)
+    f32 = jnp.float32
+    hlo = _compile(lambda x, V, s, r: fn(x, x, V, s, r, r), one_chip,
+                   ((EMBED_N, EMBED_D), jnp.bfloat16), ((EMBED_N, b), f32),
+                   ((), f32), ((EMBED_N,), f32))
+    assert "tpu_custom_call" in hlo
+    assert any(p in hlo for p in _trace_patterns())
 
 
 def test_fused_nystrom_matmat_compiles(one_chip):
