@@ -6,8 +6,9 @@ Every backend has the signature
 
 where ``est`` is the :class:`~repro.cluster.SpectralClustering` estimator
 (carrying k, sparsify_t, dtype, ...), ``x`` is (n, d) points — or, for
-``precomputed``, the (n, n) similarity matrix itself — and ``sigma`` the RBF
-bandwidth (ignored by ``precomputed``).
+``precomputed``, the (n, n) similarity matrix itself, and for ``graph`` a
+:class:`~repro.data.graph_file.SparseAdjacency` — and ``sigma`` the RBF
+bandwidth (ignored by both).
 
 Backends:
   dense       full row-block similarity (beyond-paper "full" mode): every
@@ -16,7 +17,11 @@ Backends:
   triangular  the paper's balanced upper-triangle block schedule (Alg. 4.2),
               wide row-block storage.
   compact     same schedule, compact per-device tile stacks (perf S1).
-  precomputed caller supplies S directly (paper §5 topology graphs).
+  precomputed caller supplies a dense S directly.
+  graph       a graph's adjacency as its nonzeros, row by row
+              (``graph_file.SparseAdjacency``, paper §5 topology graphs):
+              degrees and passes over the nonzeros, never an (n, n)
+              matrix.
   knn-topt    dense similarity then top-t row sparsification lifted into the
               distributed path (paper step 1 "and then sparse it"), keeping
               the graph symmetric via max(S, S^T).
@@ -132,6 +137,38 @@ def precomputed_affinity(est, S, sigma, mesh) -> NormalizedOperator:
             f"precomputed affinity expects a square (n, n) similarity "
             f"matrix, got shape {tuple(S.shape)}")
     return operator_from_dense(S, int(S.shape[0]), mesh)
+
+
+@AFFINITIES.register("graph")
+def graph_affinity(est, adj, sigma, mesh) -> NormalizedOperator:
+    """A graph's adjacency as its nonzeros, kept sparse.
+
+    ``adj`` is a :class:`~repro.data.graph_file.SparseAdjacency`, on the
+    host or already on the device.  The degrees are its rows' sums, and
+    every pass reads the nonzeros alone
+    (:func:`~repro.core.laplacian.make_sparse_operator`); the (n, n)
+    matrix exists only if ``eigh`` asks for it (``dense``).  On a mesh of
+    several devices the nonzeros are replicated.  Each fit counts once
+    in ``affinity.graph_fits``."""
+    from repro import obs
+
+    n = adj.n
+    n_pad = mesh_utils.pad_to_multiple(n, mesh_utils.mesh_size(mesh))
+    cols = jnp.asarray(adj.cols)
+    w = jnp.asarray(adj.weights, est.dtype)
+    if n_pad != n:          # rows of padding: no nonzeros
+        cols = jnp.pad(cols, ((0, n_pad - n), (0, 0)))
+        w = jnp.pad(w, ((0, n_pad - n), (0, 0)))
+    if mesh_utils.mesh_size(mesh) > 1:
+        cols, w = jax.device_put((cols, w), mesh_utils.replicated(mesh))
+    valid = (jnp.arange(n_pad) < n).astype(est.dtype)
+    matmat, inv_sqrt = lp.make_sparse_operator(cols, w, valid)
+    obs.counter("affinity.graph_fits").inc()
+    return NormalizedOperator(
+        matmat=matmat, valid=valid, inv_sqrt=inv_sqrt, n=n, n_pad=n_pad,
+        mesh=mesh, schedule=None,
+        dense=lambda: lp.dense_shifted_matrix(lp.sparse_to_dense(cols, w),
+                                              valid, inv_sqrt))
 
 
 @AFFINITIES.register("knn-topt")

@@ -49,9 +49,11 @@ class SpectralClustering:
     k:              number of clusters (and embedding dimensions).
     affinity:       name in :data:`~repro.cluster.AFFINITIES`
                     ("dense" | "triangular" | "compact" | "precomputed"
-                    | "knn-topt" | "ooc-topt" | "fused-rbf").  With
-                    "precomputed", ``fit(S)`` treats its argument as the
-                    (n, n) similarity matrix; "ooc-topt" builds the graph
+                    | "graph" | "knn-topt" | "ooc-topt" | "fused-rbf").
+                    With "precomputed", ``fit(S)`` treats its argument as
+                    the dense (n, n) similarity matrix; with "graph", as
+                    a ``graph_file.SparseAdjacency`` (``fit_graph``);
+                    "ooc-topt" builds the graph
                     out-of-core through ``repro.engine``; "fused-rbf"
                     never materializes the similarity at all (O(n*d)
                     affinity memory, see ``compute_dtype``).
@@ -223,12 +225,15 @@ class SpectralClustering:
 
     def fit(self, x: jax.Array, checkpointer: Any = None) -> "SpectralClustering":
         """Cluster points (n, d) — or, with ``affinity="precomputed"``, a
-        similarity matrix (n, n).  Returns ``self``.  Points are cast to
+        similarity matrix (n, n), and with ``affinity="graph"`` a
+        graph's nonzero list.  Returns ``self``.  Points are cast to
         the dtype the affinity backend takes them in
         (``affinity.point_dtype``): ``dtype``, except bfloat16 points
         under ``affinity="fused-rbf"``, which stay bfloat16."""
         if self.affinity == "precomputed":
             return self.fit_affinity(x, checkpointer=checkpointer)
+        if self.affinity == "graph":
+            return self.fit_graph(x, checkpointer=checkpointer)
         mesh = self._mesh()
         phases: dict = {}
         with obs.span("fit", affinity=self.affinity,
@@ -255,22 +260,43 @@ class SpectralClustering:
 
     def fit_affinity(self, S: jax.Array,
                      checkpointer: Any = None) -> "SpectralClustering":
-        """Cluster from a precomputed (n, n) similarity/adjacency matrix
-        (the paper's §5 graph dataset), regardless of ``self.affinity``."""
+        """Cluster from a precomputed dense (n, n) similarity/adjacency
+        matrix, regardless of ``self.affinity``."""
+        return self._fit_given("precomputed", S, int(S.shape[0]),
+                               checkpointer)
+
+    def fit_graph(self, adj, checkpointer: Any = None
+                  ) -> "SpectralClustering":
+        """Cluster a graph's vertices (the paper's §5 graph dataset) from
+        its adjacency's nonzero list, a
+        :class:`~repro.data.graph_file.SparseAdjacency` on the host or
+        the device, regardless of ``self.affinity``: the ``graph``
+        affinity, which never builds the (n, n) matrix."""
+        from repro.data.graph_file import SparseAdjacency
+        if not isinstance(adj, SparseAdjacency):
+            raise ValueError(
+                f"a graph fit takes a graph_file.SparseAdjacency "
+                f"(adjacency_sparse), got {type(adj).__name__}")
+        return self._fit_given("graph", adj, adj.n, checkpointer)
+
+    def _fit_given(self, backend: str, arg, n: int,
+                   checkpointer: Any) -> "SpectralClustering":
+        """A fit whose affinity is given, not computed from points: no
+        sigma, no training points to extend to new ones."""
         mesh = self._mesh()
         phases: dict = {}
-        with obs.span("fit", affinity="precomputed",
+        with obs.span("fit", affinity=backend,
                       eigensolver=self.eigensolver, assigner=self.assigner,
-                      n=int(S.shape[0])) as sp_fit:
-            with obs.span("fit.affinity", backend="precomputed") as sp_aff:
+                      n=n) as sp_fit:
+            with obs.span("fit.affinity", backend=backend) as sp_aff:
                 key = jax.random.PRNGKey(self.seed)
                 _k_eig, k_lan, k_km = jax.random.split(key, 3)
-                op = AFFINITIES.get("precomputed")(self, S, None, mesh)
+                op = AFFINITIES.get(backend)(self, arg, None, mesh)
                 jax.block_until_ready((op.inv_sqrt, op.valid))
             phases["affinity"] = sp_aff
             self._finish(op, jnp.asarray(0.0, self.dtype), k_lan, k_km,
                          mesh, checkpointer, train_x=None,
-                         affinity_used="precomputed", phases=phases)
+                         affinity_used=backend, phases=phases)
         self._record_obs(sp_fit, phases)
         return self
 

@@ -1,7 +1,7 @@
 """The common product of every affinity backend.
 
-All affinity backends — dense, triangular, compact, precomputed, knn-topt —
-reduce to the same object: the *shifted normalized operator*
+All affinity backends — dense, triangular, compact, precomputed, graph,
+knn-topt — reduce to the same object: the *shifted normalized operator*
 
     A v = valid * v + D^{-1/2} S D^{-1/2} v
 

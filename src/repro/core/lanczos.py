@@ -376,10 +376,23 @@ def tridiagonal(state: LanczosState) -> jax.Array:
     return T
 
 
+def _small_eigh(T: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """``eigh`` of the (m, m) tridiagonal, ascending: on the host in
+    float64 when ``T`` is concrete.  The TPU's Jacobi eigh stops at a
+    relative tolerance of 1e-6, which left the Ritz pairs of the paper
+    graph's 48 steps off their Rayleigh-Ritz conditions by up to 3.2e-5
+    on a TPU v5e (PERF.md, section 6); m is a few dozen, so the host's
+    solve costs microseconds.  Traced, it stays ``jnp.linalg.eigh``."""
+    if isinstance(T, jax.core.Tracer):
+        return jnp.linalg.eigh(T)
+    evals, evecs = np.linalg.eigh(np.asarray(T, np.float64))
+    return jnp.asarray(evals, T.dtype), jnp.asarray(evecs, T.dtype)
+
+
 def ritz_pairs(state: LanczosState) -> tuple[jax.Array, jax.Array]:
     """Ritz values (ascending) and vectors (n, m) of the operator."""
     T = tridiagonal(state)
-    evals, evecs = jnp.linalg.eigh(T)           # ascending
+    evals, evecs = _small_eigh(T)               # ascending
     m = state.alpha.shape[0]
     ritz_vecs = matmul(state.V[:m].T, evecs)    # (n, m)
     return evals, ritz_vecs

@@ -11,6 +11,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro.core.similarity import UpperSim, sym_matmat, sym_matvec
 from repro.precision import matmul
@@ -52,6 +53,56 @@ def make_dense_operator(S: jax.Array, valid: jax.Array):
     deg = matmul(S, valid)  # padded cols are zero already
     inv_sqrt = masked_inv_sqrt(deg)
     return jax.tree_util.Partial(_dense_matmat, S, inv_sqrt, valid), inv_sqrt
+
+
+# A TPU v5e gathers rows of 8 lanes about three times faster than
+# single values (PERF.md, section 6), so a narrower block is gathered 8
+# wide.  XLA would shrink a zero-padded gather back to single values;
+# the optimization barrier keeps it wide.
+_GATHER_LANES = 8
+
+
+def _sparse_matmat(cols: jax.Array, nw: jax.Array, valid: jax.Array,
+                   V: jax.Array) -> jax.Array:
+    b = V.shape[1]
+    U = V
+    if b < _GATHER_LANES:
+        U = lax.optimization_barrier(
+            jnp.pad(V, ((0, 0), (0, _GATHER_LANES - b))))
+    NV = jnp.sum(nw[:, :, None] * U[cols], axis=1)[:, :b]
+    return valid[:, None] * V + NV
+
+
+def make_sparse_operator(cols: jax.Array, w: jax.Array, valid: jax.Array):
+    """:func:`make_dense_operator` for a similarity given by its nonzeros
+    row by row: ``S[i, cols[i, s]] = w[i, s]`` for the (n_pad, width)
+    arrays, each position at most once (spare slots carry weight 0).
+
+    The degrees are the rows' sums, and the normalization is folded into
+    the weights once: a pass of ``matmat`` is a gather of ``V``'s rows, a
+    multiply and a row sum over the nonzeros, and never reads an
+    (n_pad, n_pad) matrix.  Returns ``(matmat, inv_sqrt)``, ``matmat`` a
+    :class:`jax.tree_util.Partial` over ``(cols, D^-1/2 S D^-1/2
+    weights, valid)``: one compiled Lanczos loop per shape, as for the
+    dense operator."""
+    inv_sqrt, nw = _sparse_scales(cols, w)
+    return jax.tree_util.Partial(_sparse_matmat, cols, nw, valid), inv_sqrt
+
+
+@jax.jit
+def _sparse_scales(cols, w):
+    # one program, not a dozen eager dispatches: on a TPU v5e those were
+    # most of the graph's affinity time (PERF.md, section 6)
+    inv_sqrt = masked_inv_sqrt(jnp.sum(w, axis=1))
+    return inv_sqrt, w * (inv_sqrt[:, None] * inv_sqrt[cols])
+
+
+def sparse_to_dense(cols: jax.Array, w: jax.Array) -> jax.Array:
+    """The (n_pad, n_pad) matrix of :func:`make_sparse_operator`'s
+    nonzeros, on the device."""
+    n_pad = cols.shape[0]
+    rows = jnp.broadcast_to(jnp.arange(n_pad)[:, None], cols.shape)
+    return jnp.zeros((n_pad, n_pad), w.dtype).at[rows, cols].add(w)
 
 
 def dense_shifted_matrix(S: jax.Array, valid: jax.Array,

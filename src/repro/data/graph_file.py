@@ -4,9 +4,11 @@
     v <id> <label>
     e <src> <dst> <weight>
 
-The parsed graph feeds ``SpectralClustering(affinity="precomputed")``
-(adjacency-weight similarity) — the paper clusters graph vertices
-directly.
+The parsed graph feeds ``SpectralClustering.fit_graph`` as the nonzero
+list of its adjacency (:func:`adjacency_sparse`): the paper clusters
+graph vertices directly, and the (n, n) matrix is never built.
+:func:`adjacency_dense` builds that matrix for callers who want
+``affinity="precomputed"``.
 
 The parser streams the file in ~1 MiB line batches and converts each batch
 to integers with one numpy tokenize/reshape instead of per-line Python
@@ -16,8 +18,11 @@ consumers (the out-of-core engine) that never want the whole edge array.
 """
 from __future__ import annotations
 
-from typing import Iterator, Optional
+import dataclasses
+import functools
+from typing import Any, Iterator, Optional
 
+import jax
 import numpy as np
 
 _READ_HINT = 1 << 20  # ~1 MiB of lines per batch
@@ -133,3 +138,60 @@ def adjacency_dense(n: int, edges: np.ndarray, dtype=np.float32) -> np.ndarray:
     A[edges[:, 1], edges[:, 0]] = edges[:, 2]
     np.fill_diagonal(A, 1.0)
     return A
+
+
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=("cols", "weights"), meta_fields=("nnz",))
+@dataclasses.dataclass(frozen=True)
+class SparseAdjacency:
+    """The nonzeros of a graph's symmetric adjacency with unit
+    self-loops, row by row (ELL): row ``i``'s ``s``-th nonzero is
+    ``A[i, cols[i, s]] = weights[i, s]``, columns ascending.  Every row
+    has ``width`` slots, the fullest row's count rounded up to a
+    multiple of 8 (the chip's sublanes); a row's spare slots carry
+    weight 0 (and its own column), so graphs of one size whose largest
+    degrees are close share one compiled program.  ``nnz`` counts the
+    graph's nonzeros.  A pytree: ``jax.device_put`` moves the two arrays
+    and keeps ``nnz``."""
+    cols: Any
+    weights: Any
+    nnz: int
+
+    @property
+    def n(self) -> int:
+        return int(self.cols.shape[0])
+
+    @property
+    def width(self) -> int:
+        return int(self.cols.shape[1])
+
+
+def adjacency_sparse(n: int, edges: np.ndarray) -> SparseAdjacency:
+    """:func:`adjacency_dense`'s matrix as its nonzeros, built without
+    it.  The same semantics: both directions of every edge, a repeated
+    pair once with the weight the dense assignment leaves (the last one
+    written), and the diagonal 1.0, a self-edge's weight included."""
+    edges = np.asarray(edges).reshape(-1, 3)
+    src, dst = edges[:, 0].astype(np.int64), edges[:, 1].astype(np.int64)
+    # adjacency_dense writes every (src, dst), then every (dst, src):
+    # in that order, the last write of each position is the one kept
+    rows = np.concatenate([src, dst])
+    cols = np.concatenate([dst, src])
+    w = np.concatenate([edges[:, 2], edges[:, 2]]).astype(np.float32)
+    off = rows != cols
+    rows = np.concatenate([rows[off], np.arange(n)])
+    cols = np.concatenate([cols[off], np.arange(n)])
+    w = np.concatenate([w[off], np.ones(n, np.float32)])
+    # np.unique keeps each position's first occurrence in the reversed
+    # order (its last write) and sorts by row, then column
+    _, first = np.unique((rows * n + cols)[::-1], return_index=True)
+    keep = len(rows) - 1 - first
+    rows, cols, w = rows[keep], cols[keep], w[keep]
+    count = np.bincount(rows, minlength=n)
+    width = -(-int(count.max(initial=1)) // 8) * 8
+    slot = np.arange(len(rows)) - (np.cumsum(count) - count)[rows]
+    ell_cols = np.repeat(np.arange(n, dtype=np.int32)[:, None], width, 1)
+    ell_w = np.zeros((n, width), np.float32)
+    ell_cols[rows, slot] = cols
+    ell_w[rows, slot] = w
+    return SparseAdjacency(cols=ell_cols, weights=ell_w, nnz=len(rows))

@@ -42,7 +42,7 @@ def main(argv=None):
     ap.add_argument("--k", type=int, default=3)
     ap.add_argument("--affinity", default="triangular",
                     choices=AFFINITIES.names(),
-                    help="phase-1 backend (forced to 'precomputed' by --graph)")
+                    help="phase-1 backend (forced to 'graph' by --graph)")
     ap.add_argument("--eigensolver", default="lanczos",
                     choices=EIGENSOLVERS.names(), help="phase-2 backend")
     ap.add_argument("--assigner", default="lloyd", choices=ASSIGNERS.names(),
@@ -125,7 +125,7 @@ def main(argv=None):
     if args.engine:
         if args.graph:
             ap.error("--engine applies to point datasets; --graph feeds the "
-                     "precomputed affinity directly")
+                     "graph affinity directly")
         affinity = "ooc-topt"
 
     schedule = args.schedule
@@ -141,7 +141,7 @@ def main(argv=None):
     mesh = mesh_utils.local_mesh("rows")
     mgr = CheckpointManager(args.ckpt_dir) if args.ckpt_dir else None
     est = SpectralClustering(
-        k=args.k, affinity="precomputed" if args.graph else affinity,
+        k=args.k, affinity="graph" if args.graph else affinity,
         eigensolver=args.eigensolver, assigner=args.assigner,
         lanczos_steps=args.lanczos_steps, block_size=args.block_size,
         cheb_degree=args.cheb_degree, sparsify_t=args.sparsify_t,
@@ -161,11 +161,12 @@ def main(argv=None):
         if args.graph:
             with obs.span("job.parse"):
                 n, edges = graph_file.parse_topology(args.graph)
-            with obs.span("job.adjacency"):
-                S = graph_file.adjacency_dense(n, edges)
+            with obs.span("job.adjacency") as sp:
+                adj = graph_file.adjacency_sparse(n, edges)
+                sp.set(nnz=adj.nnz, nnz_padded=adj.weights.size)
             with obs.span("job.to_device"):
-                S = jax.block_until_ready(jnp.asarray(S))
-            est.fit_affinity(S, checkpointer=mgr)
+                adj = jax.block_until_ready(jax.device_put(adj))
+            est.fit_graph(adj, checkpointer=mgr)
             truth = None
         elif args.points:
             with obs.span("job.load"):

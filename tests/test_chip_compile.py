@@ -38,6 +38,8 @@ CHUNK = 4096     # the out-of-core phase's --chunk-size (one map tile)
 EMBED_N = 65_536  # the embedding cell's rows (mteb-embed-d4096)
 EMBED_D = 4_096   # their width, in bf16
 EMBED_B = 64      # its block-Lanczos width
+GRAPH_N = 10_029  # the paper graph's vertices (paper-graph-10k)
+GRAPH_WIDTH = 16  # its ELL width: a largest degree of 9 to 16
 
 
 def _trace_patterns():
@@ -158,3 +160,24 @@ def test_kmeans_assign_compiles_at_schedule_default(one_chip):
     f32 = jnp.float32
     hlo = _compile(fn, one_chip, ((N, D), f32), ((B, D), f32))
     assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("b", [1, 8])
+def test_graph_pass_gathers_rows_of_eight_lanes(one_chip, b):
+    """The sparse graph pass at the paper graph's size gathers rows of at
+    least 8 lanes, even for a single Lanczos vector: XLA would shrink a
+    zero-padded gather to single values, which the chip gathers about
+    three times slower, and the optimization barrier keeps it wide."""
+    import re
+
+    from repro.core import laplacian as lp
+    f32 = jnp.float32
+    hlo = _compile(lp._sparse_matmat, one_chip,
+                   ((GRAPH_N, GRAPH_WIDTH), jnp.int32),
+                   ((GRAPH_N, GRAPH_WIDTH), f32), ((GRAPH_N,), f32),
+                   ((GRAPH_N, b), f32))
+    shapes = re.findall(r"= f32\[([\d,]+)\][^ ]* gather\(", hlo)
+    assert shapes, "the pass holds no gather"
+    for shape in shapes:
+        assert [int(d) for d in shape.split(",")] == \
+            [GRAPH_N, GRAPH_WIDTH, max(b, 8)], shape

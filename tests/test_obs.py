@@ -468,27 +468,46 @@ def test_spectral_job_nests_its_phases(source, tmp_path):
 def test_second_graph_job_compiles_nothing(tmp_path):
     """The graph job's three loops (the Lanczos recurrence, k-means++ and
     Lloyd) compile once per process: a second job of the same shapes
-    traces, lowers and loads no program, and gives the same answers."""
+    traces, lowers and loads no program, and gives the same answers; a
+    third, on another graph whose rows fill the same width, compiles
+    nothing either."""
     from repro.data import graph_file
     from repro.launch import spectral_job
 
+    def job(edges, name):
+        path = str(tmp_path / name)
+        graph_file.write_topology(path, 90, edges)
+        return spectral_job.main(["--graph", path, "--k", "3"])
+
+    def compiled_nothing(edges):
+        spans, _ = _tree(obs.spans())
+        for name in ("job", "fit.affinity", "fit.eigensolve.krylov",
+                     "fit.assign.seed", "fit.assign.lloyd"):
+            assert spans[name].attrs.get("jit_programs", 0) == 0, \
+                (name, spans[name].attrs.get("jit_funs"))
+        # both directions of every edge and 90 self-loops, in rows of
+        # one width
+        adj = graph_file.adjacency_sparse(90, edges)
+        assert spans["job.adjacency"].attrs == {
+            "nnz": 2 * len(edges) + 90, "nnz_padded": 90 * adj.width}
+        return adj.width
+
     edges, _ = synthetic.synthetic_graph(90, 220, k=3, seed=5)
-    path = str(tmp_path / "topo.txt")
-    graph_file.write_topology(path, 90, edges)
-    argv = ["--graph", path, "--k", "3"]
-    first = spectral_job.main(argv)
+    first = job(edges, "topo.txt")
     obs.reset()
-    second = spectral_job.main(argv)
-    spans, _ = _tree(obs.spans())
-    for name in ("job", "fit.eigensolve.krylov", "fit.assign.seed",
-                 "fit.assign.lloyd"):
-        assert spans[name].attrs.get("jit_programs", 0) == 0, \
-            (name, spans[name].attrs.get("jit_funs"))
+    second = job(edges, "topo.txt")
+    width = compiled_nothing(edges)
     for got, want in ((second.eigenvalues_, first.eigenvalues_),
                       (second._eigvecs, first._eigvecs),
                       (second.labels_, first.labels_),
                       (second.centers_, first.centers_)):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert obs.snapshot()["affinity.graph_fits"]["value"] == 1
+    assert second.info_["affinity"] == "graph"
+    obs.reset()
+    other, _ = synthetic.synthetic_graph(90, 250, k=3, seed=6)
+    job(other, "other.txt")
+    assert compiled_nothing(other) == width
 
 
 # -- serving summarize --------------------------------------------------------
