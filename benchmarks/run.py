@@ -100,14 +100,14 @@ def table1_phases(n: int = 4096, k: int = 8):
     us_sim, S = _timeit(sim_fn, x)
     row("table1/similarity_m1", us_sim, f"n={n}")
 
-    mv = lp.make_dense_shifted_operator(S)
-    lan_fn = jax.jit(lambda s: lz.run(mv, s, 8))
-    state0 = lz.init_state(n, 64, jax.random.PRNGKey(0))
+    mm = lp.make_dense_shifted_matmat(S)
+    lan_fn = jax.jit(lambda s: lz.block_run(mm, s, 8))
+    state0 = lz.init_block_state(n, 64, jax.random.PRNGKey(0), 1)
     us_lan8, state = _timeit(lan_fn, state0)
     us_lan = us_lan8 / 8 * 64          # 64 iterations total
     row("table1/lanczos_m1", us_lan, "64 iters")
 
-    evals, Z = lz.topk_of_shifted(lz.run(mv, state0, 64), k)
+    evals, Z = lz.block_topk_of_shifted(lz.block_run(mm, state0, 64), k)
     Y = km.normalize_rows(Z)
     c0 = km.kmeans_plusplus_init(Y, k, jax.random.PRNGKey(1))
     km_fn = jax.jit(lambda y, c: km.lloyd_step(
@@ -162,13 +162,14 @@ def rings_quality(n: int = 400):
 def lanczos_residual(n: int = 512):
     pts, _ = synthetic.blobs(n, 4, seed=3)
     S = sim.dense_similarity(jnp.asarray(pts), 1.0)
-    mv = lp.make_dense_shifted_operator(S)
+    mm = lp.make_dense_shifted_matmat(S)
     for steps in (8, 16, 32, 64):
         t0 = time.perf_counter()
-        state = lz.lanczos(mv, n, steps, jax.random.PRNGKey(0))
-        vals, vecs = lz.topk_of_shifted(state, 4)
+        state = lz.block_lanczos(mm, n, steps, jax.random.PRNGKey(0),
+                                 block_size=1)
+        vals, vecs = lz.block_topk_of_shifted(state, 4)
         us = (time.perf_counter() - t0) * 1e6
-        res = float(jnp.max(lz.residuals(mv, vals, vecs, shift=2.0)))
+        res = float(jnp.max(lz.residuals(mm, vals, vecs, shift=2.0)))
         row(f"lanczos/steps{steps}", us, f"max_residual={res:.2e}")
 
 

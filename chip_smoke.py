@@ -200,7 +200,7 @@ def phase_graph(state: dict) -> None:
     _, k_lan, _ = jax.random.split(jax.random.PRNGKey(est.seed), 3)
     v0 = np.asarray(jax.random.normal(k_lan, (est.info_["n_pad"],),
                                       jnp.float32), np.float64)
-    steps = est.info_["lanczos_steps"]
+    steps = est.info_["block_steps"]
     (comps, ref), t_ref = timed(lambda: (
         graph_components(GRAPH_N, edges),
         graph_lanczos_reference(GRAPH_N, edges, v0, steps, K)))

@@ -37,8 +37,8 @@ Backends:
               with f32 accumulation.
 
 Every backend returns a NormalizedOperator with a NATIVE matmat — one
-pass over its similarity storage per (n_pad, b) block — and lets the
-operator derive the width-1 matvec view (see operator.py).
+pass over its similarity storage per (n_pad, b) block, of any width b
+(see operator.py).
 """
 from __future__ import annotations
 

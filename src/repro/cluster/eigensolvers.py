@@ -12,16 +12,16 @@ operator's (possibly permuted) row order.  Every backend reports
 (one ``matmat`` of any width = one pass), the distributed cost unit of
 the paper's §4.3 hot spot.  The Lanczos backends split their time into
 the spans ``fit.eigensolve.krylov`` (the recurrence) and
-``fit.eigensolve.ritz`` (the tridiagonal eigenproblem and top-k), each
-ending when its device work has.
+``fit.eigensolve.ritz`` (the tridiagonal eigenproblem, solved on the
+host in float64, and top-k), each ending when its device work has.
 
 Backends:
-  lanczos        shifted single-vector Lanczos with full
-                 reorthogonalization — the paper's Alg. 4.3, distributed
-                 through ``op.matvec``; one matrix pass per step.
-  block-lanczos  the block-tridiagonal recurrence through ``op.matmat``:
-                 the same Krylov dimension in ~1/b the matrix passes
-                 (each pass amortized over the b-wide block).
+  block-lanczos  shifted block Lanczos with full reorthogonalization
+                 through ``op.matmat``: the same Krylov dimension in ~1/b
+                 the matrix passes (each pass amortized over the b-wide
+                 block).
+  lanczos        the paper's Alg. 4.3: ``block-lanczos`` at b = 1, one
+                 matrix pass per step.
   chebdav        block Chebyshev–Davidson (Pang & Yang 2022): degree-d
                  Chebyshev filtering of the current Ritz block between
                  Rayleigh–Ritz steps; ``est.block_size`` and
@@ -45,19 +45,6 @@ _SHIFT = 2.0  # A = shift*I - L_sym; see core.laplacian docstring
 
 
 @EIGENSOLVERS.register("lanczos")
-def lanczos_solver(est, op, key):
-    steps = est.num_lanczos_steps(op.n)
-    with obs.span("fit.eigensolve.krylov"):
-        state = jax.block_until_ready(lz.lanczos(
-            op.matvec, op.n_pad, steps, key, dtype=est.dtype,
-            host_matmat=getattr(op, "host_matmat", None)))
-    op.record_passes(steps, 1)
-    with obs.span("fit.eigensolve.ritz"):
-        evals, Z = jax.block_until_ready(
-            lz.topk_of_shifted(state, est.k, shift=_SHIFT))
-    return evals, Z, {"lanczos_steps": steps, "matrix_passes": steps}
-
-
 @EIGENSOLVERS.register("block-lanczos")
 def block_lanczos_solver(est, op, key):
     b = est.num_block_size(op.n)       # same n as the step count below,
@@ -65,7 +52,7 @@ def block_lanczos_solver(est, op, key):
     with obs.span("fit.eigensolve.krylov"):
         state = jax.block_until_ready(lz.block_lanczos(
             op.matmat, op.n_pad, steps, key, block_size=b, dtype=est.dtype,
-            host_matmat=getattr(op, "host_matmat", None)))
+            host_matmat=op.host_matmat))
     op.record_passes(steps, b)
     with obs.span("fit.eigensolve.ritz"):
         evals, Z = jax.block_until_ready(

@@ -67,7 +67,8 @@ class SpectralClustering:
                     the solver runs ceil(steps / block_size) block steps
                     (same subspace, ~1/block_size the matrix passes).
     block_size:     block width b for "block-lanczos" / "chebdav"
-                    (None = 8 for block-lanczos, max(2, k) for chebdav).
+                    (None = 8 for block-lanczos, max(2, k) for chebdav);
+                    "lanczos" is "block-lanczos" at b = 1.
     cheb_degree:    Chebyshev filter degree for "chebdav".
     sparsify_t:     top-t per row for the "knn-topt" / "ooc-topt"
                     affinities (None = max(k + 2, 10)).
@@ -202,6 +203,8 @@ class SpectralClustering:
         return int(min(m, n - 1))
 
     def num_block_size(self, n: int | None = None) -> int:
+        if self.eigensolver == "lanczos":
+            return 1
         if self.block_size is not None:
             if self.block_size <= 0:
                 raise ValueError(

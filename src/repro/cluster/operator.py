@@ -32,29 +32,19 @@ class SpectralResult:
     info: dict = field(default_factory=dict)
 
 
-def _width1_matvec(matmat: Callable, v: jax.Array) -> jax.Array:
-    return matmat(v[:, None])[:, 0]
-
-
 @dataclass
 class NormalizedOperator:
     """Shifted normalized-similarity operator plus its padding/permutation
     bookkeeping.
 
-    matmat:    (n_pad, b) -> (n_pad, b) replicated; ``A V`` as above — the
-               CANONICAL product.  Every in-tree affinity backend supplies
-               a native matmat (one pass over the similarity per block);
-               when a third-party backend supplies only ``matvec``, a
-               column-loop fallback is derived (correct, but it pays one
-               matrix pass per column — see API.md's migration note).
-    matvec:    (n_pad,) -> (n_pad,) replicated; derived width-1 view of
-               ``matmat`` unless the backend supplied its own.
-               A ``matmat`` given as a ``jax.tree_util.Partial`` of a
-               module-level function over arrays (the dense operator of
-               ``core.laplacian.make_dense_operator``) is data, and so is
-               the derived ``matvec``: the Lanczos eigensolvers then reuse
-               one compiled loop across fits of the same shapes.  A plain
-               closure is traced again on every fit.
+    matmat:    (n_pad, b) -> (n_pad, b) replicated; ``A V`` as above, one
+               pass over the similarity per block of any width b, 1
+               included.  A ``matmat`` given as a ``jax.tree_util.Partial``
+               of a module-level function over arrays (the dense operator
+               of ``core.laplacian.make_dense_operator``) is data: the
+               Lanczos eigensolvers then reuse one compiled loop across
+               fits of the same shapes.  A plain closure is traced again
+               on every fit.
     valid:     (n_pad,) 1/0 mask — 0 on padding rows.
     inv_sqrt:  (n_pad,) D^{-1/2} of the (padded) similarity; kept so the
                estimator can Nystrom-extend the embedding to new points.
@@ -100,8 +90,7 @@ class NormalizedOperator:
     n: int
     n_pad: int
     mesh: Any
-    matmat: Optional[Callable[[jax.Array], jax.Array]] = None
-    matvec: Optional[Callable[[jax.Array], jax.Array]] = None
+    matmat: Callable[[jax.Array], jax.Array]
     schedule: Any = None
     dense: Optional[Callable[[], jax.Array]] = None
     stats: Any = field(default_factory=dict)
@@ -109,28 +98,6 @@ class NormalizedOperator:
     close: Optional[Callable[[], None]] = None
     count_passes: Optional[Callable[[int, int], None]] = None
     host_matmat: Optional[Callable] = None
-
-    def __post_init__(self):
-        if self.matmat is None and self.matvec is None:
-            raise ValueError(
-                "NormalizedOperator needs matmat (preferred) or matvec")
-        if self.matmat is None:
-            # Third-party matvec-only backend: column loop.  ``lax.map``
-            # keeps one column in flight (a vmap batch would defeat
-            # streaming backends) without unrolling b calls per trace.
-            mv = self.matvec
-
-            def matmat(V: jax.Array) -> jax.Array:
-                return jax.lax.map(mv, V.T).T
-
-            self.matmat = matmat
-        if self.matvec is None:
-            mm = self.matmat
-            if isinstance(mm, jax.tree_util.Partial):
-                # stays data, so the eigensolver's compiled loop is reused
-                self.matvec = jax.tree_util.Partial(_width1_matvec, mm)
-            else:
-                self.matvec = lambda v: _width1_matvec(mm, v)
 
     def stats_snapshot(self) -> dict:
         return dict(self.stats() if callable(self.stats) else self.stats)

@@ -1,6 +1,7 @@
 # The paper's primary contribution: parallel spectral clustering
 # (similarity -> Lanczos eigenvectors -> k-means), distributed over a
-# device mesh via shard_map. See DESIGN.md for the Hadoop -> TPU mapping.
+# device mesh via shard_map: the paper's Hadoop workers become the mesh's
+# devices, each holding a row block of the similarity.
 #
 # The public entry point is repro.cluster.SpectralClustering (pluggable
 # affinity/eigensolver/assigner backends); the functions re-exported here

@@ -1,35 +1,34 @@
-"""Phase 2 of the paper: (block) Lanczos for the k smallest eigenvectors
+"""Phase 2 of the paper: block Lanczos for the k smallest eigenvectors
 (Alg. 4.3).
 
-The mat-vec ``L @ v`` is the distributed hot spot — the caller passes a
-``matvec``/``matmat`` operator (row-sharded symmetric operator from
+The product ``L @ V`` is the distributed hot spot: the caller passes a
+``matmat`` operator (row-sharded symmetric operator from
 ``core.similarity`` / ``core.laplacian``), and the recurrence itself runs
-on replicated vectors/blocks, exactly the paper's "move the vector to the
-data" split.  An operator given as a ``jax.tree_util.Partial`` over
-arrays runs through one compiled loop per shape; a closure is traced
-again on every call (:func:`block_run`).
+on replicated blocks, exactly the paper's "move the vector to the data"
+split.  An operator given as a ``jax.tree_util.Partial`` over arrays runs
+through one compiled loop per shape; a closure is traced again on every
+call (:func:`block_run`).
 
-The canonical recurrence is **block** Lanczos: a block-tridiagonal
-three-term recurrence on ``b`` vectors at once, so every eigensolver step
-costs ONE pass over the matrix (one ``matmat``) amortized across the whole
-block, instead of one pass per vector — the key trick of CPU-GPU spectral
-clustering implementations (Jin & JaJa 2018).  The classic single-vector
-Lanczos below is the ``b = 1`` view of the same step body.
+The recurrence is block-tridiagonal on ``b`` vectors at once, so every
+step costs ONE pass over the matrix (one ``matmat``) amortized across the
+whole block, instead of one pass per vector (Jin & JaJa 2018).  The
+paper's single-vector Lanczos is the same recurrence at ``b = 1``.
 
-Deviations from the paper (correctness-driven, DESIGN.md §2):
-  * full reorthogonalization (CGS2) against the whole basis — plain
+Deviations from the paper, both for correctness:
+  * full reorthogonalization (CGS2) against the whole basis: plain
     Lanczos loses orthogonality in finite precision and returns wrong
     small eigenvectors;
-  * the iteration runs on the *shifted* operator A = 2I - L_sym supplied
-    by ``laplacian.make_shifted_operator``, so extremal (largest) Ritz
-    pairs of A are the smallest of L_sym.
+  * the iteration runs on the *shifted* operator A = 2I - L_sym
+    (``core.laplacian``), so extremal (largest) Ritz pairs of A are the
+    smallest of L_sym.
 
-Both states are explicit pytrees so the launcher can checkpoint/restore
+The state is an explicit pytree so the launcher can checkpoint/restore
 the iteration mid-run (fault tolerance; the paper gets this from Hadoop
 task re-execution).
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Callable
 
@@ -40,10 +39,6 @@ from jax import lax
 
 from repro.precision import matmul
 
-
-# ---------------------------------------------------------------------------
-# Block Lanczos: the canonical recurrence
-# ---------------------------------------------------------------------------
 
 @jax.tree_util.register_pytree_node_class
 @dataclass
@@ -70,9 +65,8 @@ class BlockLanczosState:
 
 def _qr_pos(U: jax.Array, eps: float = 1e-8) -> tuple[jax.Array, jax.Array]:
     """Reduced QR with non-negative R diagonal; (near-)dependent columns
-    are zeroed instead of admitting junk directions into the basis (the
-    block analogue of the scalar ``beta < 1e-8 -> v_next = 0`` guard: the
-    dead direction decouples from T and lands at the spectrum floor)."""
+    are zeroed instead of admitting junk directions into the basis: a
+    dead direction decouples from T and lands at the spectrum floor."""
     Q, R = jnp.linalg.qr(U)
     d = jnp.diagonal(R)
     sgn = jnp.where(d < 0, -1.0, 1.0).astype(U.dtype)
@@ -86,10 +80,19 @@ def init_block_state(n: int, num_steps: int, key: jax.Array, block_size: int,
                      V0: jax.Array | None = None,
                      dtype=jnp.float32) -> BlockLanczosState:
     """Random (or caller-supplied) orthonormal (b, n) start block."""
-    b = block_size
     if V0 is None:
-        V0 = jax.random.normal(key, (b, n), dtype)
-    Q, _ = _qr_pos(V0.T.astype(dtype))
+        V0 = jax.random.normal(key, (block_size, n), dtype)
+    return _start_state(V0.astype(dtype), num_steps)
+
+
+# One program for the start block's QR and the zeroed state: run eagerly,
+# its dozen dispatches cost the paper graph's eigensolve about 11 ms a job
+# on a TPU v5e (PERF.md, section 6).
+@functools.partial(jax.jit, static_argnums=1)
+def _start_state(V0: jax.Array, num_steps: int) -> BlockLanczosState:
+    b, n = V0.shape
+    dtype = V0.dtype
+    Q, _ = _qr_pos(V0.T)
     V = jnp.zeros(((num_steps + 1) * b, n), dtype).at[:b].set(Q.T)
     return BlockLanczosState(
         step=jnp.zeros((), jnp.int32),
@@ -249,38 +252,41 @@ def block_lanczos(matmat: Callable, n: int, num_steps: int, key: jax.Array,
     return block_run(matmat, state, num_steps)
 
 
-def block_tridiagonal(state: BlockLanczosState) -> jax.Array:
-    """Dense block-tridiagonal T_(sb x sb) from (A, B) — s*b is small,
-    eigh on it is cheap."""
-    s, b, _ = state.A.shape
-    T = jnp.zeros((s * b, s * b), state.A.dtype)
-    for j in range(s):
-        T = lax.dynamic_update_slice(T, state.A[j], (j * b, j * b))
-        if j + 1 < s:
-            T = lax.dynamic_update_slice(T, state.B[j + 1], ((j + 1) * b, j * b))
-            T = lax.dynamic_update_slice(T, state.B[j + 1].T, (j * b, (j + 1) * b))
-    return T
+def _ritz_vectors(V: jax.Array, evecs: jax.Array) -> jax.Array:
+    """The Ritz vectors (n, s*b): the basis rows' combinations ``evecs``."""
+    return matmul(V[: evecs.shape[0]].T, evecs)
 
 
 def block_ritz_pairs(state: BlockLanczosState) -> tuple[jax.Array, jax.Array]:
-    """Ritz values (ascending) and vectors (n, s*b) of the operator."""
-    T = block_tridiagonal(state)
-    evals, evecs = jnp.linalg.eigh(T)            # ascending
-    s, b, _ = state.A.shape
-    ritz_vecs = matmul(state.V[: s * b].T, evecs)   # (n, s*b)
-    return evals, ritz_vecs
+    """Ritz values (ascending) and vectors (n, s*b) of the operator.
+
+    The block tridiagonal T is assembled and solved on the host in
+    float64: the TPU's Jacobi ``eigh`` stops at a relative tolerance of
+    1e-6, which left the paper graph's Ritz pairs off their Rayleigh-Ritz
+    conditions by up to 3.2e-5 on a TPU v5e (PERF.md, section 6).  T is
+    a few hundred rows at most, so the host's solve costs milliseconds;
+    the Ritz vectors are one product on the device."""
+    A = np.asarray(state.A, np.float64)
+    B = np.asarray(state.B, np.float64)
+    s, b, _ = A.shape
+    T = np.zeros((s * b, s * b))
+    for j in range(s):
+        T[j * b:(j + 1) * b, j * b:(j + 1) * b] = A[j]
+        if j + 1 < s:
+            T[(j + 1) * b:(j + 2) * b, j * b:(j + 1) * b] = B[j + 1]
+            T[j * b:(j + 1) * b, (j + 1) * b:(j + 2) * b] = B[j + 1].T
+    evals, evecs = np.linalg.eigh(T)             # ascending
+    dtype = state.V.dtype
+    return (jnp.asarray(evals, dtype),
+            _ritz_vectors(state.V, jnp.asarray(evecs, dtype)))
 
 
 def block_topk_of_shifted(state: BlockLanczosState, k: int,
                           shift: float = 2.0) -> tuple[jax.Array, jax.Array]:
     """k smallest eigenpairs of L given block Lanczos ran on
-    A = shift*I - L.  Returns (eigvals ascending (k,), eigvecs (n, k))."""
+    A = shift*I - L.  Returns (eigvals ascending (k,), eigvecs (n, k),
+    unit columns)."""
     evals_A, vecs = block_ritz_pairs(state)
-    return _topk_from_ritz(evals_A, vecs, k, shift)
-
-
-def _topk_from_ritz(evals_A: jax.Array, vecs: jax.Array, k: int,
-                    shift: float) -> tuple[jax.Array, jax.Array]:
     # largest of A  <->  smallest of L
     topk = vecs[:, -k:][:, ::-1]
     vals_L = (shift - evals_A[-k:])[::-1]
@@ -289,130 +295,8 @@ def _topk_from_ritz(evals_A: jax.Array, vecs: jax.Array, k: int,
     return vals_L, topk
 
 
-# ---------------------------------------------------------------------------
-# Single-vector Lanczos: the b = 1 view of the block recurrence
-# ---------------------------------------------------------------------------
-
-@jax.tree_util.register_pytree_node_class
-@dataclass
-class LanczosState:
-    step: jax.Array    # scalar int32: number of completed iterations
-    V: jax.Array       # (m+1, n) basis rows; rows > step are zero
-    alpha: jax.Array   # (m,)
-    beta: jax.Array    # (m+1,); beta[0] == 0
-
-    def tree_flatten(self):
-        return (self.step, self.V, self.alpha, self.beta), None
-
-    @staticmethod
-    def tree_unflatten(aux, children):
-        return LanczosState(*children)
-
-
-def _as_block(state: LanczosState) -> BlockLanczosState:
-    return BlockLanczosState(
-        step=state.step, V=state.V,
-        A=state.alpha[:, None, None], B=state.beta[:, None, None],
-        block_size=1)
-
-
-def _from_block(bstate: BlockLanczosState) -> LanczosState:
-    return LanczosState(step=bstate.step, V=bstate.V,
-                        alpha=bstate.A[:, 0, 0], beta=bstate.B[:, 0, 0])
-
-
-def init_state(n: int, num_steps: int, key: jax.Array,
-               v0: jax.Array | None = None, dtype=jnp.float32) -> LanczosState:
-    if v0 is None:
-        v0 = jax.random.normal(key, (n,), dtype)
-    v0 = v0 / jnp.linalg.norm(v0)
-    V = jnp.zeros((num_steps + 1, n), dtype).at[0].set(v0)
-    return LanczosState(
-        step=jnp.zeros((), jnp.int32),
-        V=V,
-        alpha=jnp.zeros((num_steps,), dtype),
-        beta=jnp.zeros((num_steps + 1,), dtype),
-    )
-
-
-def _width1_matmat(matvec: Callable, V: jax.Array) -> jax.Array:
-    return matvec(V[:, 0])[:, None]
-
-
-def run(matvec: Callable, state: LanczosState, num_iters: int) -> LanczosState:
-    """Advance the recurrence ``num_iters`` steps (checkpoint-friendly) —
-    the width-1 view of :func:`block_run`, synchronized for the same
-    host-callback reason.  As there, a ``matvec`` given as a
-    :class:`jax.tree_util.Partial` of a module-level function over arrays
-    (``NormalizedOperator.matvec`` of a dense operator) reuses one
-    compiled loop across fits; a plain closure is traced again on every
-    call."""
-    if isinstance(matvec, jax.tree_util.Partial):
-        matmat = jax.tree_util.Partial(_width1_matmat, matvec)
-    else:
-        def matmat(V):
-            return _width1_matmat(matvec, V)
-    return _from_block(_advance(matmat, _as_block(state), num_iters))
-
-
-def lanczos(matvec: Callable, n: int, num_steps: int, key: jax.Array,
-            dtype=jnp.float32, v0: jax.Array | None = None,
-            host_matmat: Callable | None = None) -> LanczosState:
-    state = init_state(n, num_steps, key, v0=v0, dtype=dtype)
-    if host_matmat is not None:
-        # width-1 host-streaming drive (same deadlock avoidance as
-        # block_run_host; the host pass sees an (n, 1) block)
-        out = block_run_host(host_matmat, _as_block(state), num_steps)
-        return _from_block(out)
-    return run(matvec, state, num_steps)
-
-
-def tridiagonal(state: LanczosState) -> jax.Array:
-    """Dense T_mm from (alpha, beta) — m is small, eigh on it is cheap."""
-    m = state.alpha.shape[0]
-    T = jnp.diag(state.alpha)
-    off = state.beta[1:m]
-    T = T + jnp.diag(off, 1) + jnp.diag(off, -1)
-    return T
-
-
-def _small_eigh(T: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """``eigh`` of the (m, m) tridiagonal, ascending: on the host in
-    float64 when ``T`` is concrete.  The TPU's Jacobi eigh stops at a
-    relative tolerance of 1e-6, which left the Ritz pairs of the paper
-    graph's 48 steps off their Rayleigh-Ritz conditions by up to 3.2e-5
-    on a TPU v5e (PERF.md, section 6); m is a few dozen, so the host's
-    solve costs microseconds.  Traced, it stays ``jnp.linalg.eigh``."""
-    if isinstance(T, jax.core.Tracer):
-        return jnp.linalg.eigh(T)
-    evals, evecs = np.linalg.eigh(np.asarray(T, np.float64))
-    return jnp.asarray(evals, T.dtype), jnp.asarray(evecs, T.dtype)
-
-
-def ritz_pairs(state: LanczosState) -> tuple[jax.Array, jax.Array]:
-    """Ritz values (ascending) and vectors (n, m) of the operator."""
-    T = tridiagonal(state)
-    evals, evecs = _small_eigh(T)               # ascending
-    m = state.alpha.shape[0]
-    ritz_vecs = matmul(state.V[:m].T, evecs)    # (n, m)
-    return evals, ritz_vecs
-
-
-def topk_of_shifted(state: LanczosState, k: int,
-                    shift: float = 2.0) -> tuple[jax.Array, jax.Array]:
-    """k smallest eigenpairs of L given Lanczos ran on A = shift*I - L.
-
-    Returns (eigvals_of_L ascending (k,), eigvecs (n, k), unit columns).
-    """
-    evals_A, vecs = ritz_pairs(state)
-    return _topk_from_ritz(evals_A, vecs, k, shift)
-
-
-def residuals(matvec: Callable, vals: jax.Array, vecs: jax.Array,
+def residuals(matmat: Callable, vals: jax.Array, vecs: jax.Array,
               shift: float | None = None) -> jax.Array:
     """||Op v - lambda v|| per Ritz pair (convergence diagnostics)."""
-    def one(v, lam):
-        Av = matvec(v)
-        lam_op = (shift - lam) if shift is not None else lam
-        return jnp.linalg.norm(Av - lam_op * v)
-    return jax.vmap(one, in_axes=(1, 0))(vecs, vals)
+    lam_op = (shift - vals) if shift is not None else vals
+    return jnp.linalg.norm(matmat(vecs) - vecs * lam_op[None, :], axis=0)

@@ -3,7 +3,8 @@
 L_sym = I - D^{-1/2} S D^{-1/2}.  Lanczos converges to *extremal*
 eigenvalues, so to get the k smallest of L_sym (spectrum in [0, 2]) we run
 it on the shifted operator A = 2I - L_sym = I + D^{-1/2} S D^{-1/2}, whose
-largest eigenpairs are exactly L_sym's smallest (DESIGN.md §2).
+largest eigenpairs are exactly L_sym's smallest: the iteration then needs
+no shift-and-invert solve, only products with S.
 """
 from __future__ import annotations
 
@@ -42,8 +43,7 @@ def make_dense_operator(S: jax.Array, valid: jax.Array):
     1/0 mask.  Returns ``(matmat, inv_sqrt)``: the canonical multi-vector
     product (one pass of S per (n_pad, b) block — with S row-sharded and
     the block replicated, ``S @ .`` is the one collective) plus D^{-1/2}
-    for out-of-sample extension.  The width-1 matvec view is derived by
-    :class:`~repro.cluster.operator.NormalizedOperator`.
+    for out-of-sample extension.
 
     ``matmat`` is a :class:`jax.tree_util.Partial` of a module-level
     function over ``(S, inv_sqrt, valid)``: data, not a closure, so the
@@ -150,19 +150,6 @@ def make_shifted_matmat(
     return matmat
 
 
-def make_shifted_operator(
-    upper: UpperSim, deg: jax.Array
-) -> Callable[[jax.Array], jax.Array]:
-    """Width-1 matvec view of :func:`make_shifted_matmat` (kept for
-    single-vector consumers like the dry-run lowering harness)."""
-    matmat = make_shifted_matmat(upper, deg)
-
-    def matvec(v: jax.Array) -> jax.Array:
-        return matmat(v[:, None])[:, 0]
-
-    return matvec
-
-
 def make_dense_shifted_matmat(
     S: jax.Array, deg: jax.Array | None = None
 ) -> Callable[[jax.Array], jax.Array]:
@@ -174,14 +161,3 @@ def make_dense_shifted_matmat(
         return V + inv_sqrt[:, None] * matmul(S, inv_sqrt[:, None] * V)
 
     return matmat
-
-
-def make_dense_shifted_operator(
-    S: jax.Array, deg: jax.Array | None = None
-) -> Callable[[jax.Array], jax.Array]:
-    matmat = make_dense_shifted_matmat(S, deg)
-
-    def matvec(v: jax.Array) -> jax.Array:
-        return matmat(v[:, None])[:, 0]
-
-    return matvec

@@ -273,10 +273,6 @@ class ShardedCSRGraph:
                       for c in range(min(depth, nshards))}
         return Y
 
-    def matvec(self, v: np.ndarray) -> np.ndarray:
-        """S @ v — the width-1 view of :meth:`matmat`."""
-        return self.matmat(np.asarray(v)[:, None])[:, 0]
-
     def to_dense(self) -> np.ndarray:
         """Dense S — test/oracle path only; defeats the engine if used at
         scale."""
@@ -294,7 +290,7 @@ def make_normalized_operator(graph: ShardedCSRGraph, dtype=jnp.float32,
                              mesh=None, pad_to: int | None = None
                              ) -> NormalizedOperator:
     """Wrap the sharded graph as the estimator's common operator interface:
-    ``A v = valid*v + D^{-1/2} S D^{-1/2} v`` with the S-matvec streaming
+    ``A V = valid*V + D^{-1/2} S D^{-1/2} V`` with the S product streaming
     shards through a host callback.
 
     Two views of the same product are exposed: the traced ``matmat``

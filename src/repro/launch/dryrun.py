@@ -192,6 +192,12 @@ def lower_lm_cell(arch: str, shape_name: str, multi_pod: bool,
     return lowered, mesh, model
 
 
+def _block_state_abs(n_pad: int, steps: int, b: int):
+    """Shapes of a block-Lanczos state, nothing allocated."""
+    return jax.eval_shape(
+        lambda: lz.init_block_state(n_pad, steps, jax.random.PRNGKey(0), b))
+
+
 def lower_spectral_cell(phase: str, multi_pod: bool, n: int | None = None):
     """Dry-run the paper pipeline's three phases on the flat mesh."""
     from repro.configs import spectral_paper
@@ -226,9 +232,7 @@ def lower_spectral_cell(phase: str, multi_pod: bool, n: int | None = None):
         tiles_abs = jax.ShapeDtypeStruct(
             (m_dev * (2 * m_dev + 1), sched.b, sched.b), jnp.float32)
         diag_abs = jax.ShapeDtypeStruct((n_pad,), jnp.float32)
-        st_abs = jax.tree.map(
-            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype),
-            lz.init_state(n_pad, 32, jax.random.PRNGKey(0)))
+        st_abs = _block_state_abs(n_pad, 32, 1)
         t_shard = NamedSharding(mesh, P("rows", None, None))
 
         def fn(tiles, diag, state):
@@ -237,22 +241,21 @@ def lower_spectral_cell(phase: str, multi_pod: bool, n: int | None = None):
             deg = sim.sym_matvec_compact(up, diag)
             inv = jnp.where(deg > 0, 1.0 / jnp.sqrt(jnp.maximum(deg, 1e-12)), 0.0)
 
-            def mv(v):
-                return diag * v + inv * sim.sym_matvec_compact(up, inv * v)
+            def mm(V):
+                return diag[:, None] * V + inv[:, None] * sim.sym_matmat_compact(
+                    up, inv[:, None] * V)
 
-            return lz.run(mv, state, 1)
+            return lz.block_run(mm, state, 1)
 
         lowered = jax.jit(fn, in_shardings=(t_shard, None, None),
                           donate_argnums=(2,)).lower(tiles_abs, diag_abs, st_abs)
     elif phase == "lanczos_materialized":
         # paper-faithful alternative: Lanczos against the fully materialized
         # mirrored S (the Hadoop way: both triangles stored in HBase);
-        # compare against the sym_matvec path that never mirrors
+        # compare against the sym_matmat path that never mirrors
         from jax.sharding import NamedSharding, PartitionSpec as P
         S_abs = jax.ShapeDtypeStruct((n_pad, n_pad), jnp.float32)
-        st_abs = jax.tree.map(
-            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype),
-            lz.init_state(n_pad, 32, jax.random.PRNGKey(0)))
+        st_abs = _block_state_abs(n_pad, 32, 1)
         s_shard = NamedSharding(mesh, P("rows", None))
 
         def fn(S, state):
@@ -260,44 +263,24 @@ def lower_spectral_cell(phase: str, multi_pod: bool, n: int | None = None):
             deg = matmul(S, valid)
             inv = jnp.where(deg > 0, 1.0 / jnp.sqrt(jnp.maximum(deg, 1e-12)), 0.0)
 
-            def mv(v):
-                return valid * v + inv * matmul(S, inv * v)
+            def mm(V):
+                return valid[:, None] * V + inv[:, None] * matmul(
+                    S, inv[:, None] * V)
 
-            return lz.run(mv, state, 1)
+            return lz.block_run(mm, state, 1)
 
         lowered = jax.jit(fn, in_shardings=(s_shard, None),
                           donate_argnums=(1,)).lower(S_abs, st_abs)
-    elif phase == "lanczos":
-        # one Lanczos iteration against row-sharded upper blocks
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        U_abs = jax.ShapeDtypeStruct((n_pad, n_pad), jnp.float32)
-        diag_abs = jax.ShapeDtypeStruct((n_pad,), jnp.float32)
-        st_abs = jax.tree.map(
-            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype),
-            lz.init_state(n_pad, 32, jax.random.PRNGKey(0)))
-        u_shard = NamedSharding(mesh, P("rows", None))
-
-        def fn(U, diag, state):
-            up = sim.UpperSim(U=U, diag=diag, schedule=sched, mesh=mesh,
-                              axis=("rows",))
-            from repro.core import laplacian as lp
-            deg = lp.degrees(up)
-            mv = lp.make_shifted_operator(up, deg)
-            return lz.run(mv, state, 1)
-
-        lowered = jax.jit(fn, in_shardings=(u_shard, None, None),
-                          donate_argnums=(2,)).lower(U_abs, diag_abs, st_abs)
-    elif phase == "block_lanczos":
-        # one BLOCK Lanczos step against row-sharded upper blocks: the
+    elif phase in ("lanczos", "block_lanczos"):
+        # one Lanczos step against row-sharded upper blocks: the
         # (n_pad, b) block stays replicated, each device streams its row
         # block of U once per step — b vectors advanced per matrix pass
+        # (b = 1 for the paper's single-vector recurrence)
         from jax.sharding import NamedSharding, PartitionSpec as P
-        blk = 8
+        blk, steps = (1, 32) if phase == "lanczos" else (8, 8)
         U_abs = jax.ShapeDtypeStruct((n_pad, n_pad), jnp.float32)
         diag_abs = jax.ShapeDtypeStruct((n_pad,), jnp.float32)
-        st_abs = jax.tree.map(
-            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype),
-            lz.init_block_state(n_pad, 8, jax.random.PRNGKey(0), blk))
+        st_abs = _block_state_abs(n_pad, steps, blk)
         u_shard = NamedSharding(mesh, P("rows", None))
 
         def fn(U, diag, state):

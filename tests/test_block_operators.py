@@ -1,7 +1,7 @@
 """The block-operator contract across the refactored stack.
 
-* property: ``op.matmat(V)`` equals column-stacked ``op.matvec(v_i)`` for
-  EVERY registered affinity backend (the interface every eigensolver now
+* property: ``op.matmat(V)`` equals its column-stacked width-1 products
+  for EVERY registered affinity backend (the interface every eigensolver
   leans on);
 * block Lanczos: oracle agreement, pass accounting, resumable state;
 * Chebyshev-Davidson: eigenvalue agreement with the exact ``eigh`` oracle
@@ -24,7 +24,7 @@ from repro.core import (chebdav as cd, lanczos as lz, laplacian as lp,
 from repro.data import synthetic
 from repro.distrib import mesh_utils
 
-# every affinity must satisfy the matmat == stacked-matvec law
+# every affinity must satisfy the matmat == stacked width-1 matmat law
 BACKENDS = ("dense", "triangular", "compact", "precomputed", "knn-topt",
             "ooc-topt", "fused-rbf")
 
@@ -62,34 +62,36 @@ def test_matmat_equals_stacked_matvec(backend_idx, width, seed):
     op = _operator(BACKENDS[backend_idx])
     V = jax.random.normal(jax.random.PRNGKey(seed), (op.n_pad, width))
     got = np.asarray(op.matmat(V))
-    want = np.stack([np.asarray(op.matvec(V[:, j]))
-                     for j in range(width)], axis=1)
+    want = np.concatenate([np.asarray(op.matmat(V[:, j:j + 1]))
+                           for j in range(width)], axis=1)
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
     assert got.shape == (op.n_pad, width)
 
 
-def test_matvec_only_backend_gets_matmat_fallback():
-    """Third-party backends that still supply only matvec keep working:
-    the operator derives a column-loop matmat (API.md migration note)."""
+def test_materialize_assembles_matmat_through_identity_blocks():
+    """An operator without ``dense`` materializes A through ``matmat``
+    applied to identity column blocks, the last one narrower."""
     from repro.cluster.operator import NormalizedOperator
     n = 12
     A = np.random.RandomState(0).randn(n, n).astype(np.float32)
     A = A + A.T
+    widths = []
+
+    def matmat(V):
+        widths.append(V.shape[1])
+        return jnp.asarray(A) @ V
+
     op = NormalizedOperator(
-        matvec=lambda v: jnp.asarray(A) @ v,
-        valid=jnp.ones((n,)), inv_sqrt=jnp.ones((n,)), n=n, n_pad=n,
-        mesh=None)
-    V = np.random.RandomState(1).randn(n, 3).astype(np.float32)
-    np.testing.assert_allclose(np.asarray(op.matmat(jnp.asarray(V))), A @ V,
-                               rtol=1e-4, atol=1e-5)
-    # and materialize() assembles A through identity blocks
+        matmat=matmat, valid=jnp.ones((n,)), inv_sqrt=jnp.ones((n,)), n=n,
+        n_pad=n, mesh=None)
     np.testing.assert_allclose(np.asarray(op.materialize(block=5)), A,
                                rtol=1e-4, atol=1e-5)
+    assert widths == [5, 5, 2]
 
 
 def test_operator_requires_some_product():
     from repro.cluster.operator import NormalizedOperator
-    with pytest.raises(ValueError, match="matmat"):
+    with pytest.raises(TypeError, match="matmat"):
         NormalizedOperator(valid=jnp.ones((4,)), inv_sqrt=jnp.ones((4,)),
                            n=4, n_pad=4, mesh=None)
 
@@ -119,9 +121,11 @@ def test_block_lanczos_matches_eigh(block_size):
     want = (2.0 - evals_A[-3:])[::-1]
     np.testing.assert_allclose(np.asarray(vals), want, atol=1e-4)
     # Ritz vectors are true eigenvectors: small residuals
-    res = lz.residuals(lambda v: matmat(v[:, None])[:, 0],
-                       vals, vecs, shift=2.0)
+    res = lz.residuals(matmat, vals, vecs, shift=2.0)
     assert float(jnp.max(res)) < 1e-3
+    B = np.asarray(state.B)
+    assert np.all(B[0] == 0.0)
+    assert np.all(np.diagonal(B, axis1=1, axis2=2) >= 0.0)   # QR sign-fixed
 
 
 def test_block_lanczos_resumable_checkpoint_state():
@@ -148,19 +152,35 @@ def test_block_basis_orthonormal():
     np.testing.assert_allclose(G, np.eye(s * b), atol=1e-4)
 
 
-def test_scalar_lanczos_is_width1_view():
-    """The scalar recurrence (now the b=1 view of the block step) still
-    reproduces the eigh oracle and stays resumable."""
-    matmat, A, _ = _dense_op(n=72)
-    n = A.shape[0]
-    mv = lambda v: matmat(v[:, None])[:, 0]                   # noqa: E731
-    state = lz.lanczos(mv, n, 40, jax.random.PRNGKey(0))
-    vals, _ = lz.topk_of_shifted(state, 3)
-    evals_A = np.asarray(jnp.linalg.eigh(A)[0])
-    np.testing.assert_allclose(np.asarray(vals),
-                               (2.0 - evals_A[-3:])[::-1], atol=1e-4)
-    assert float(state.beta[0]) == 0.0
-    assert np.all(np.asarray(state.beta) >= 0.0)   # QR sign-fixed
+@pytest.mark.parametrize("data", ["points", "graph"])
+def test_lanczos_is_block_lanczos_at_width_one(data):
+    """The "lanczos" eigensolver is "block-lanczos" at b = 1: the same
+    start block, recurrence and Ritz step, so the same eigenvalues,
+    embedding and labels, bit for bit, on a points fit and a graph fit."""
+    from repro.data import graph_file
+    if data == "points":
+        pts, _ = synthetic.blobs(60, 3, dim=3, spread=0.6, seed=4)
+        x = jnp.asarray(pts)
+        kw = dict(affinity="dense")
+
+        def fit(est):
+            return est.fit(x)
+    else:
+        edges, _ = synthetic.synthetic_graph(120, 260, k=3, seed=4)
+        adj = graph_file.adjacency_sparse(120, edges)
+        kw = {}
+
+        def fit(est):
+            return est.fit_graph(adj)
+    one = fit(SpectralClustering(3, eigensolver="lanczos", sigma=1.0,
+                                 lanczos_steps=24, seed=0, **kw))
+    blk = fit(SpectralClustering(3, eigensolver="block-lanczos",
+                                 block_size=1, sigma=1.0, lanczos_steps=24,
+                                 seed=0, **kw))
+    assert one.info_["matrix_passes"] == blk.info_["matrix_passes"] == 24
+    for attr in ("eigenvalues_", "embedding_", "labels_"):
+        np.testing.assert_array_equal(np.asarray(getattr(one, attr)),
+                                      np.asarray(getattr(blk, attr)))
 
 
 # ---------------------------------------------------------------------------

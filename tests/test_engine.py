@@ -172,7 +172,8 @@ def test_engine_matvec_streams_shards_correctly():
     plan = JobPlan(n=75, chunk_size=20, t=6, k=2, sigma=0.8)
     graph, _ = engine.build_graph(ArrayChunks(pts, 20), plan)
     v = rng.randn(75).astype(np.float32)
-    np.testing.assert_allclose(graph.matvec(v), graph.to_dense() @ v,
+    np.testing.assert_allclose(graph.matmat(v[:, None])[:, 0],
+                               graph.to_dense() @ v,
                                rtol=1e-4, atol=1e-5)
 
 
@@ -262,14 +263,14 @@ def test_operator_padding_matches_unpadded():
     op_pad = engine.make_normalized_operator(graph, pad_to=56)
     assert op_pad.n_pad == 56 and op.n_pad == 50
     v = rng.randn(56).astype(np.float32)
-    got = np.asarray(op_pad.matvec(jnp.asarray(v)))
-    ref = np.asarray(op.matvec(jnp.asarray(v[:50])))
+    got = np.asarray(op_pad.matmat(jnp.asarray(v[:, None])))[:, 0]
+    ref = np.asarray(op.matmat(jnp.asarray(v[:50, None])))[:, 0]
     np.testing.assert_allclose(got[:50], ref, rtol=1e-5, atol=1e-6)
     assert np.all(got[50:] == 0)                  # pad rows stay null
     A = np.asarray(op_pad.dense())
     np.testing.assert_allclose(A[:50, :50], np.asarray(op.dense()),
                                rtol=1e-5, atol=1e-6)
-    # the traced-callback matvec above immortalizes its closure (and so
+    # the traced-callback matmat above immortalizes its closure (and so
     # the graph) in jax's dispatch cache: close the shared prefetch pool
     # explicitly, as every non-test operator consumer does
     op.close()

@@ -88,10 +88,12 @@ def _cases():
         # the per-step program engine.run_job's ooc eigensolve dispatches
         "run_job_block_lanczos_step": (
             lz._block_step_advance, (_block_state(), _rand((64, 4), 7))),
-        "block_ritz_pairs": (lz.block_ritz_pairs, (_block_state(),)),
+        # the Ritz vectors of block_ritz_pairs, at b = 4 and b = 1
+        "block_ritz_pairs": (
+            lz._ritz_vectors, (_block_state().V, _rand((12, 12), 8))),
         "lanczos_ritz_pairs": (
-            lz.ritz_pairs,
-            (lz.init_state(64, 5, jax.random.PRNGKey(1)),)),
+            lz._ritz_vectors,
+            (_block_state(steps=5, b=1).V, _rand((5, 5), 9))),
         "lloyd_step": (
             lambda y, s: km.lloyd_step(y, jnp.ones((64,), jnp.float32), s),
             (y, state)),

@@ -74,8 +74,9 @@ def test_lanczos_matches_eigh(n, k):
     A = jax.random.normal(key, (n, n))
     A = (A + A.T) / 2
 
-    state = lz.lanczos(lambda v: A @ v, n, min(n - 1, 40), key)
-    evals, vecs = lz.ritz_pairs(state)
+    state = lz.block_lanczos(lambda V: A @ V, n, min(n - 1, 40), key,
+                             block_size=1)
+    evals, vecs = lz.block_ritz_pairs(state)
     top = np.asarray(evals[-k:])
     want = np.linalg.eigvalsh(np.asarray(A))[-k:]
     np.testing.assert_allclose(top, want, atol=1e-3, rtol=1e-3)
@@ -88,26 +89,27 @@ def test_lanczos_smallest_of_lsym_via_shift():
     x, _ = synthetic.blobs(90, 3, spread=0.1, seed=2)
     S = sim.dense_similarity(jnp.asarray(x), 1.0)
     L = lp.dense_lsym(S)
-    mv = lp.make_dense_shifted_operator(S)
-    state = lz.lanczos(mv, 90, 60, jax.random.PRNGKey(1))
-    vals, vecs = lz.topk_of_shifted(state, 3)
+    mm = lp.make_dense_shifted_matmat(S)
+    state = lz.block_lanczos(mm, 90, 60, jax.random.PRNGKey(1), block_size=1)
+    vals, vecs = lz.block_topk_of_shifted(state, 3)
     want = np.linalg.eigvalsh(np.asarray(L))[:3]
     np.testing.assert_allclose(np.asarray(vals), want, atol=2e-3)
-    res = lz.residuals(mv, vals, vecs, shift=2.0)
+    res = lz.residuals(mm, vals, vecs, shift=2.0)
     assert float(jnp.max(res)) < 1e-2
 
 
 def test_lanczos_checkpoint_resume_identical():
-    """run(20) == run(10); checkpoint; run(10) — fault-tolerance invariant."""
+    """run(20) == run(10); checkpoint; run(10) at width 1 —
+    fault-tolerance invariant."""
     n = 64
     key = jax.random.PRNGKey(3)
     A = jax.random.normal(key, (n, n))
     A = (A + A.T) / 2
-    mv = lambda v: A @ v
-    full = lz.run(mv, lz.init_state(n, 20, key), 20)
-    half = lz.run(mv, lz.init_state(n, 20, key), 10)
-    resumed = lz.run(mv, half, 10)
-    np.testing.assert_allclose(np.asarray(full.alpha), np.asarray(resumed.alpha),
+    mm = lambda V: A @ V                                      # noqa: E731
+    full = lz.block_run(mm, lz.init_block_state(n, 20, key, 1), 20)
+    half = lz.block_run(mm, lz.init_block_state(n, 20, key, 1), 10)
+    resumed = lz.block_run(mm, half, 10)
+    np.testing.assert_allclose(np.asarray(full.A), np.asarray(resumed.A),
                                atol=1e-5)
     np.testing.assert_allclose(np.asarray(full.V), np.asarray(resumed.V), atol=1e-5)
 
@@ -136,9 +138,6 @@ def test_dense_operators_share_one_compiled_recurrence(width):
         assert isinstance(mm, jax.tree_util.Partial)
         op = NormalizedOperator(matmat=mm, valid=valid, inv_sqrt=inv, n=n,
                                 n_pad=n, mesh=None)
-        if width == 1:
-            assert isinstance(op.matvec, jax.tree_util.Partial)
-            return lz.run(op.matvec, lz.init_state(n, steps, key), steps)
         return lz.block_run(op.matmat, lz.init_block_state(
             n, steps, key, width), steps)
 
@@ -159,31 +158,19 @@ def test_closure_operator_keeps_the_eager_recurrence(width):
     """A plain callable is never handed to the shared jitted loop (its
     cache does not grow), and gives the state the same operator gives as
     data."""
-    from repro.cluster.operator import NormalizedOperator
-
     n, steps = 40 + width, 5 + width
     S = _dense_similarity(n, 3)
     valid = jnp.ones((n,), jnp.float32)
     key = jax.random.PRNGKey(1)
-    mm, inv = lp.make_dense_operator(S, valid)
+    mm, _ = lp.make_dense_operator(S, valid)
 
     before = lz._block_steps_jit._cache_size()
-    if width == 1:
-        state0 = lz.init_state(n, steps, key)
-        eager = lz.run(lambda v: mm(v[:, None])[:, 0], state0, steps)
-        assert lz._block_steps_jit._cache_size() == before
-        op = NormalizedOperator(matmat=mm, valid=valid, inv_sqrt=inv, n=n,
-                                n_pad=n, mesh=None)
-        jitted = lz.run(op.matvec, state0, steps)
-        fields = ("step", "V", "alpha", "beta")
-    else:
-        state0 = lz.init_block_state(n, steps, key, width)
-        eager = lz.block_run(lambda V: mm(V), state0, steps)
-        assert lz._block_steps_jit._cache_size() == before
-        jitted = lz.block_run(mm, state0, steps)
-        fields = ("step", "V", "A", "B")
+    state0 = lz.init_block_state(n, steps, key, width)
+    eager = lz.block_run(lambda V: mm(V), state0, steps)
+    assert lz._block_steps_jit._cache_size() == before
+    jitted = lz.block_run(mm, state0, steps)
     assert lz._block_steps_jit._cache_size() == before + 1
-    for f in fields:
+    for f in ("step", "V", "A", "B"):
         np.testing.assert_array_equal(np.asarray(getattr(eager, f)),
                                       np.asarray(getattr(jitted, f)))
 
