@@ -49,13 +49,15 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.cluster.operator import NormalizedOperator
 from repro.cluster.registry import Registry
-from repro.core import laplacian as lp, similarity as sim
+from repro.core import lanczos as lz, laplacian as lp, similarity as sim
 from repro.distrib import mesh_utils
+from repro.precision import matmul
 
 AFFINITIES = Registry("affinity")
 
@@ -291,15 +293,72 @@ def _sharded_pass(fp: _FusedPass, width: int):
         out_specs=P(), check_vma=False))
 
 
+@functools.partial(jax.jit, static_argnums=2)
+def _fold_columns(key, valid, b: int):
+    """``[1 | R]``: the degree pass's ones column beside (n_pad, b)
+    Gaussian columns, zero on padding rows."""
+    R = jax.random.normal(key, (valid.shape[0], b), jnp.float32)
+    return jnp.concatenate([jnp.ones_like(R[:, :1]), valid[:, None] * R],
+                           axis=1)
+
+
+@jax.jit
+def _fold_qr(P, V):
+    """From ``P = S_m [1 | R]``: D^{-1/2}, and the start block with its
+    triangle, ``D^{1/2} R = Q0 C`` (``lanczos.qr_pos``)."""
+    deg = P[:, 0]
+    inv_sqrt = lp.masked_inv_sqrt(deg)
+    # D^{1/2} = D D^{-1/2}: 0 where the degree is (padding)
+    Q0, C = lz.qr_pos((deg * inv_sqrt)[:, None] * V[:, 1:])
+    return inv_sqrt, Q0, C
+
+
+@jax.jit
+def _fold_product(P, Q0, inv_sqrt, valid, C_inv):
+    """``A Q0 = valid Q0 + D^{-1/2} S_m D^{-1/2} Q0``, where
+    ``D^{-1/2} Q0 = R C^{-1}``: the pass's ``S_m R`` times C^{-1}."""
+    return valid[:, None] * Q0 + inv_sqrt[:, None] * matmul(P[:, 1:], C_inv)
+
+
+def _inverse_triangle(C) -> jax.Array:
+    """C^{-1} in float64 on the host, the columns of C's dropped
+    directions (``qr_pos`` zeroed their rows) zero."""
+    C = np.asarray(C, np.float64)
+    keep = np.diagonal(C) > 0
+    C_inv = np.linalg.inv(C + np.diag(~keep)) * keep[None, :]
+    return jnp.asarray(C_inv, jnp.float32)
+
+
+def _degree_pass(fp: _FusedPass, xp, sigma, valid, start):
+    """The degrees' pass, ``S_m 1`` with ``S_m = diag(valid) S
+    diag(valid)``: returns D^{-1/2} and, when ``start = (key, b)`` asks
+    for it, the start pair ``(Q0, A Q0)`` of a width-b block computed in
+    the same pass, over ``[1 | R]``, from Gaussian columns R drawn from
+    ``key``; else None.  Q0 is ``D^{1/2} R`` made orthonormal."""
+    if start is None:
+        P = fp.product(xp, sigma, jnp.ones((xp.shape[0], 1), jnp.float32),
+                       valid, valid)
+        return lp.masked_inv_sqrt(P[:, 0]), None
+    key, b = start
+    V = _fold_columns(key, valid, b)
+    P = fp.product(xp, sigma, V, valid, valid)
+    inv_sqrt, Q0, C = _fold_qr(P, V)
+    W0 = _fold_product(P, Q0, inv_sqrt, valid, _inverse_triangle(C))
+    return inv_sqrt, (Q0, W0)
+
+
 def build_fused_rbf_operator(x, sigma, mesh, *, compute_dtype=None,
-                             dtype=jnp.float32,
-                             schedule=None) -> NormalizedOperator:
+                             dtype=jnp.float32, schedule=None,
+                             start=None) -> NormalizedOperator:
     """Matrix-free shifted normalized operator over raw points.
 
     Two fused passes, both row-sharded over the mesh with ONE psum each:
     the degree pass (the fused kernel against a ones column, masked to
     valid rows) and then, per matmat call, the normalized product
     ``D^{-1/2} S D^{-1/2} V`` with both scales applied inside the kernel.
+    ``start = (key, b)`` widens the degree pass to b + 1 columns: it
+    then also returns the first block-Lanczos step's product, on the
+    operator's ``start_block`` (:func:`_degree_pass`).
     The (n, n) similarity never exists anywhere — points, scales and the
     (n_pad, b) block are the whole working set.  The points stay on the
     device in their own dtype: bfloat16 rows are not widened (the kernel
@@ -360,11 +419,10 @@ def build_fused_rbf_operator(x, sigma, mesh, *, compute_dtype=None,
     obs.gauge("fused.d_tiles").set(d_pad // bd)
     # pass 1: degrees = S @ 1 with padding masked on both sides
     with obs.span("fit.affinity.degree"):
-        deg = fp.product(xp, sigma32, jnp.ones((n_pad, 1), jnp.float32),
-                         valid, valid)[:, 0]
-        inv_sqrt = jax.block_until_ready(
-            lp.masked_inv_sqrt(deg).astype(dtype))
-    count(1, 1)
+        inv_sqrt, start_block = jax.block_until_ready(
+            _degree_pass(fp, xp, sigma32, valid, start))
+        inv_sqrt = inv_sqrt.astype(dtype)
+    count(1, 1 if start is None else start[1] + 1)
 
     matmat = jax.tree_util.Partial(fp, xp, sigma32, inv_sqrt, valid)
 
@@ -399,7 +457,7 @@ def build_fused_rbf_operator(x, sigma, mesh, *, compute_dtype=None,
     return NormalizedOperator(
         matmat=matmat, valid=valid, inv_sqrt=inv_sqrt, n=n, n_pad=n_pad,
         mesh=mesh, schedule=None, dense=dense, stats=stats, reset=reset,
-        count_passes=count)
+        count_passes=count, start_block=start_block)
 
 
 def point_dtype(backend, x_dtype, dtype) -> jnp.dtype:
@@ -418,7 +476,8 @@ def _fused_point_dtype(x_dtype, dtype) -> jnp.dtype:
 
 
 @AFFINITIES.register("fused-rbf")
-def fused_rbf_affinity(est, x, sigma, mesh) -> NormalizedOperator:
+def fused_rbf_affinity(est, x, sigma, mesh,
+                       start=None) -> NormalizedOperator:
     """Flash-style matrix-free RBF affinity (O(n*d) memory).
 
     The similarity matrix is recomputed tile-by-tile inside a Pallas
@@ -427,13 +486,19 @@ def fused_rbf_affinity(est, x, sigma, mesh) -> NormalizedOperator:
     accumulation always).  Runs problem sizes whose dense similarity
     would not fit in memory at in-memory speed — the in-RAM complement
     of ``ooc-topt``.
+
+    Its degrees cost a full pass, so it takes ``start = (key, b)``
+    (``folds_start``): that pass then also yields the eigensolver's
+    first block product (``build_fused_rbf_operator``).
     """
     return build_fused_rbf_operator(
         x, sigma, mesh, compute_dtype=getattr(est, "compute_dtype", None),
-        dtype=est.dtype, schedule=getattr(est, "schedule", None))
+        dtype=est.dtype, schedule=getattr(est, "schedule", None),
+        start=start)
 
 
 fused_rbf_affinity.point_dtype = _fused_point_dtype
+fused_rbf_affinity.folds_start = True
 
 
 @AFFINITIES.register("ooc-topt")
@@ -454,8 +519,6 @@ def ooc_topt_affinity(est, x, sigma, mesh) -> NormalizedOperator:
     degrades gracefully to the in-memory "knn-topt" affinity — the same
     top-t graph built without the engine — instead of failing the job.
     """
-    import numpy as np
-
     from repro import engine, obs
     from repro.data.chunked import ArrayChunks
 

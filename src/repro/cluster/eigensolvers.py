@@ -29,6 +29,11 @@ Backends:
                  degree.
   eigh           exact dense eigendecomposition of the materialized
                  operator — the oracle, O(n^3), for tests / small n.
+
+The two Lanczos names take a start block from the affinity
+(``op.start_block``; their ``start_width`` attribute asks for one): the
+first step's product then came with the degree pass, and
+``matrix_passes`` counts the other steps.
 """
 from __future__ import annotations
 
@@ -49,16 +54,28 @@ _SHIFT = 2.0  # A = shift*I - L_sym; see core.laplacian docstring
 def block_lanczos_solver(est, op, key):
     b = est.num_block_size(op.n)       # same n as the step count below,
     steps = est.num_block_steps(op.n)  # so width and steps stay consistent
+    start = op.start_block
+    if start is not None and start[0].shape[1] != b:
+        start = None
     with obs.span("fit.eigensolve.krylov"):
         state = jax.block_until_ready(lz.block_lanczos(
             op.matmat, op.n_pad, steps, key, block_size=b, dtype=est.dtype,
-            host_matmat=op.host_matmat))
-    op.record_passes(steps, b)
+            host_matmat=op.host_matmat, start=start))
+    # a folded first step rode on the affinity's degree pass
+    passes = steps if start is None else steps - 1
+    op.record_passes(passes, b)
+    if start is not None:
+        obs.counter("lanczos.start_folded").inc()
     with obs.span("fit.eigensolve.ritz"):
         evals, Z = jax.block_until_ready(
             lz.block_topk_of_shifted(state, est.k, shift=_SHIFT))
     return evals, Z, {"block_size": b, "block_steps": steps,
-                      "matrix_passes": steps}
+                      "matrix_passes": passes}
+
+
+# asks an affinity that can for its first block's product with the
+# degrees (SpectralClustering._start_request)
+block_lanczos_solver.start_width = lambda est, n: est.num_block_size(n)
 
 
 @EIGENSOLVERS.register("chebdav")

@@ -221,6 +221,17 @@ class SpectralClustering:
         b = self.num_block_size(n)
         return max(1, -(-self.num_lanczos_steps(n) // b))
 
+    def _start_request(self, n: int, key) -> dict:
+        """``{"start": (key, b)}`` where the affinity backend can return
+        the eigensolver's first block product with its degrees (its
+        ``folds_start`` attribute) and the eigensolver takes a start block
+        of width b (its ``start_width(est, n)`` attribute); else ``{}``."""
+        width = getattr(self._eigensolver_fn, "start_width", None)
+        if width is None or not getattr(self._affinity_fn, "folds_start",
+                                        False):
+            return {}
+        return {"start": (key, width(self, n))}
+
     def _mesh(self) -> Mesh:
         return self.mesh or mesh_utils.local_mesh("rows")
 
@@ -250,7 +261,9 @@ class SpectralClustering:
                 _k_eig, k_lan, k_km = jax.random.split(key, 3)
                 sigma = jnp.asarray(self.sigma, self.dtype) \
                     if self.sigma is not None else sim.median_sigma(x)
-                op = self._affinity_fn(self, x, sigma, mesh)
+                op = self._affinity_fn(
+                    self, x, sigma, mesh,
+                    **self._start_request(int(x.shape[0]), k_lan))
                 jax.block_until_ready((op.inv_sqrt, op.valid))
             phases["affinity"] = sp_aff
             if checkpointer is not None:

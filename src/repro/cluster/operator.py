@@ -76,6 +76,12 @@ class NormalizedOperator:
                compiled loop runs without Python, so the eigensolvers
                report the passes their loops made through
                :meth:`record_passes` (no-op for backends without one).
+    start_block: optional ``(Q0, W0)``: an orthonormal (n_pad, b) start
+               block and ``A Q0``, computed in the backend's degree pass
+               when the estimator asked for one of width b (a backend
+               with a ``folds_start`` attribute, an eigensolver with a
+               ``start_width`` attribute).  The block-Lanczos solver
+               starts from it and makes one pass fewer.
     host_matmat: optional plain-host (numpy (n_pad, b) -> (n_pad, b))
                view of the SAME product, set by streaming backends whose
                matmat wraps host code in ``pure_callback``.  Eigensolvers
@@ -97,6 +103,7 @@ class NormalizedOperator:
     reset: Optional[Callable[[], None]] = None
     close: Optional[Callable[[], None]] = None
     count_passes: Optional[Callable[[int, int], None]] = None
+    start_block: Optional[tuple] = None
     host_matmat: Optional[Callable] = None
 
     def stats_snapshot(self) -> dict:

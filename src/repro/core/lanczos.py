@@ -63,7 +63,7 @@ class BlockLanczosState:
         return BlockLanczosState(*children, block_size=aux[0])
 
 
-def _qr_pos(U: jax.Array, eps: float = 1e-8) -> tuple[jax.Array, jax.Array]:
+def qr_pos(U: jax.Array, eps: float = 1e-8) -> tuple[jax.Array, jax.Array]:
     """Reduced QR with non-negative R diagonal; (near-)dependent columns
     are zeroed instead of admitting junk directions into the basis: a
     dead direction decouples from T and lands at the spectrum floor."""
@@ -85,14 +85,11 @@ def init_block_state(n: int, num_steps: int, key: jax.Array, block_size: int,
     return _start_state(V0.astype(dtype), num_steps)
 
 
-# One program for the start block's QR and the zeroed state: run eagerly,
-# its dozen dispatches cost the paper graph's eigensolve about 11 ms a job
-# on a TPU v5e (PERF.md, section 6).
-@functools.partial(jax.jit, static_argnums=1)
-def _start_state(V0: jax.Array, num_steps: int) -> BlockLanczosState:
-    b, n = V0.shape
-    dtype = V0.dtype
-    Q, _ = _qr_pos(V0.T)
+def _zero_state(Q: jax.Array, num_steps: int) -> BlockLanczosState:
+    """The state before any step, with the orthonormal (n, b) ``Q`` as
+    its first basis block."""
+    n, b = Q.shape
+    dtype = Q.dtype
     V = jnp.zeros(((num_steps + 1) * b, n), dtype).at[:b].set(Q.T)
     return BlockLanczosState(
         step=jnp.zeros((), jnp.int32),
@@ -101,6 +98,23 @@ def _start_state(V0: jax.Array, num_steps: int) -> BlockLanczosState:
         B=jnp.zeros((num_steps + 1, b, b), dtype),
         block_size=b,
     )
+
+
+# One program for the start block's QR and the zeroed state: run eagerly,
+# its dozen dispatches cost the paper graph's eigensolve about 11 ms a job
+# on a TPU v5e (PERF.md, section 6).
+@functools.partial(jax.jit, static_argnums=1)
+def _start_state(V0: jax.Array, num_steps: int) -> BlockLanczosState:
+    Q, _ = qr_pos(V0.T)
+    return _zero_state(Q, num_steps)
+
+
+@functools.partial(jax.jit, static_argnums=2)
+def _folded_state(Q0: jax.Array, W0: jax.Array,
+                  num_steps: int) -> BlockLanczosState:
+    """The state after the first block step, taken from a start pair
+    ``(Q0, W0 = A Q0)`` whose product came with no pass of its own."""
+    return _block_step_update(_zero_state(Q0, num_steps), W0)
 
 
 def _current_block(state: BlockLanczosState) -> jax.Array:
@@ -135,7 +149,7 @@ def _block_step_update(state: BlockLanczosState,
     for _ in range(2):
         C = matmul(state.V, W) * mask[:, None]
         W = W - matmul(state.V.T, C)
-    Qn, R = _qr_pos(W)
+    Qn, R = qr_pos(W)
     return BlockLanczosState(
         step=j + 1,
         V=lax.dynamic_update_slice(state.V, Qn.T, ((j + 1) * b, 0)),
@@ -244,12 +258,23 @@ def block_run_host(host_matmat: Callable, state: BlockLanczosState,
 def block_lanczos(matmat: Callable, n: int, num_steps: int, key: jax.Array,
                   block_size: int = 8, dtype=jnp.float32,
                   V0: jax.Array | None = None,
-                  host_matmat: Callable | None = None) -> BlockLanczosState:
-    state = init_block_state(n, num_steps, key, block_size, V0=V0,
-                             dtype=dtype)
+                  host_matmat: Callable | None = None,
+                  start: tuple | None = None) -> BlockLanczosState:
+    """``num_steps`` block steps from a random start block, or from
+    ``start = (Q0, W0)``: an orthonormal (n, b) block and ``A Q0``
+    already computed, so the first step makes no pass and the loop runs
+    the other ``num_steps - 1``."""
+    if start is None:
+        state = init_block_state(n, num_steps, key, block_size, V0=V0,
+                                 dtype=dtype)
+        iters = num_steps
+    else:
+        Q0, W0 = start
+        state = _folded_state(Q0.astype(dtype), W0.astype(dtype), num_steps)
+        iters = num_steps - 1
     if host_matmat is not None:
-        return block_run_host(host_matmat, state, num_steps)
-    return block_run(matmat, state, num_steps)
+        return block_run_host(host_matmat, state, iters)
+    return block_run(matmat, state, iters)
 
 
 def _ritz_vectors(V: jax.Array, evecs: jax.Array) -> jax.Array:

@@ -103,11 +103,12 @@ def test_fused_rbf_matmat_compiles(one_chip, compute_dtype, acc):
     assert "tpu_custom_call" in hlo
 
 
-@pytest.mark.parametrize("b", [1, EMBED_B])
+@pytest.mark.parametrize("b", [1, EMBED_B, EMBED_B + 1])
 def test_d_tiled_fused_rbf_matmat_compiles_for_embeddings(one_chip, b):
     """bf16 rows of width 4,096 at the schedule layer's default tiles:
     the VMEM model admits them, Mosaic compiles them, and the lowered
-    kernel carries a name the benchmark's trace reduction finds."""
+    kernel carries a name the benchmark's trace reduction finds.  Width
+    b + 1 is the degree pass that carries the first block step."""
     shape = dict(n=EMBED_N, m=EMBED_N, d=EMBED_D, b=b, itemsize=2)
     tile = frm.default_tile(EMBED_N, EMBED_D, 2)
     sched, _ = resolve("fused_rbf_matmat", None, bm=tile, bn=tile,

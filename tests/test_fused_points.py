@@ -198,8 +198,11 @@ def test_spectral_job_points_matches_reference(tmp_path, capsys):
     assert ari(lab, np.asarray(est.labels_)) == 1.0
     assert ari(labels, np.asarray(est.labels_)) == 1.0
     metrics = json.loads((tmp_path / "m.json").read_text())
-    assert metrics["fused.passes{width=1}"]["value"] == 1
-    assert metrics["fused.passes{width=16}"]["value"] == 4
+    # the degree pass carries the first block step: width 16 + 1
+    assert metrics["fused.passes{width=17}"]["value"] == 1
+    assert metrics["fused.passes{width=16}"]["value"] == 3
+    assert "fused.passes{width=1}" not in metrics
+    assert metrics["lanczos.start_folded"]["value"] == 1
     assert metrics["fused.d_tiles"]["value"] == 4
     names = {e["name"] for e in json.loads(
         (tmp_path / "t.json").read_text())["traceEvents"]}
@@ -228,9 +231,9 @@ def test_fused_lanczos_loop_holds_no_host_callback():
     est.fit(unit_rows(256, 64, 3))
     counts = {k: m["value"] for k, m in obs.snapshot().items()
               if k.startswith("fused.passes")}
-    assert counts == {"fused.passes{width=1}": 1,
-                      "fused.passes{width=4}": 2}
-    assert est.info_["engine"]["matrix_passes"] == 3
+    assert counts == {"fused.passes{width=5}": 1,
+                      "fused.passes{width=4}": 1}
+    assert est.info_["engine"]["matrix_passes"] == 2
 
 
 def test_spectral_job_refuses_krylov_space_below_k(capsys):
